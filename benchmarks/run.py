@@ -191,6 +191,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write machine-readable timings (BENCH_*.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.json:
         # fail on an unwritable path now, not after minutes of timing
         with open(args.json, "a"):

@@ -231,9 +231,11 @@ class SpMV:
         if bucket:
             xs, n = pad_to_bucket(xs)
         if self._vrun is None:
-            body = getattr(self._run, "sweep_body", None) or self._run
-            self._vrun = jax.jit(jax.vmap(
-                lambda x, y0: body({"x": x}, y0), in_axes=(0, None)))
+            consts, apply = eng.sweep_parts(self._run)
+            jitted = jax.jit(jax.vmap(
+                lambda c, x, y0: apply(c, {"x": x}, y0),
+                in_axes=(None, 0, None)))
+            self._vrun = lambda xs, y0: jitted(consts, xs, y0)
         key = (xs.shape[0], np.dtype(xs.dtype).str)
         if key not in self._batched_shapes:
             self._batched_shapes.add(key)
@@ -369,25 +371,26 @@ class PageRank:
                          out_init)
 
     def _step(self):
-        """One full power iteration ``rank -> rank`` as a traceable body:
-        contribution sweep + dangling-mass reduction + damping fold.  Both
-        drivers run exactly this function (the host driver jits it
-        standalone, the resident driver embeds it in a ``fori_loop``), and
-        the dangling mass uses the pinned-order :func:`engine.tree_sum`,
-        so host and resident ranks are bitwise identical."""
-        body = getattr(self._run, "sweep_body", None) or self._run
+        """``(consts, step)``: one full power iteration
+        ``step(consts, rank) -> rank`` as a traceable body — contribution
+        sweep + dangling-mass reduction + damping fold — and the device
+        operands it reads.  Both drivers run exactly this function (the
+        host driver jits it standalone, the resident driver embeds it in a
+        ``fori_loop``), and the dangling mass uses the pinned-order
+        :func:`engine.tree_sum`, so host and resident ranks are bitwise
+        identical."""
+        sweep_consts, apply = eng.sweep_parts(self._run)
         n = self.num_nodes
         damping = self.damping
-        inv = self.inv_deg
-        dangling = self.dangling
-        zero = self._zero_init(jnp.float32)
 
-        def step(rank):
-            contrib = body({"rank": rank, "inv_nneighbor": inv}, zero)
+        def step(c, rank):
+            sc, inv, dangling = c
+            contrib = apply(sc, {"rank": rank, "inv_nneighbor": inv},
+                            jnp.zeros_like(rank))
             dangling_mass = eng.tree_sum(jnp.where(dangling, rank, 0.0))
             return ((1.0 - damping) / n
                     + damping * (contrib + dangling_mass / n))
-        return step
+        return (sweep_consts, self.inv_deg, self.dangling), step
 
     def _make_resident_shard(self):
         """The sharded resident driver (DESIGN.md §10): rank lives
@@ -400,39 +403,34 @@ class PageRank:
         psum of per-shard partial sums."""
         from repro.launch.sharding import row_sharding
         parts = self._shard_parts
-        bodies = eng.shard_sweep_bodies(parts, {})
         widths, s = eng.shard_widths(parts)
         n = self.num_nodes
         damping = self.damping
-        inv = self.inv_deg
-        dangling = self.dangling
 
-        def mk(j):
-            body = bodies[j]
-
-            def f(full_rank, local_prev):
-                contrib = body({"rank": full_rank, "inv_nneighbor": inv},
-                               jnp.zeros_like(local_prev))
-                mass = eng.tree_sum(jnp.where(dangling, full_rank, 0.0))
-                return ((1.0 - damping) / n
-                        + damping * (contrib + mass / n))
-            return f
+        def local_step(apply, c, extra, full_rank, local_prev):
+            inv, dangling = extra
+            contrib = apply(c, {"rank": full_rank, "inv_nneighbor": inv},
+                            jnp.zeros_like(local_prev))
+            mass = eng.tree_sum(jnp.where(dangling, full_rank, 0.0))
+            return ((1.0 - damping) / n
+                    + damping * (contrib + mass / n))
 
         step = eng.make_sharded_fixpoint_step(
             parts, {}, self.mesh, "rank",
-            local_steps=[mk(j) for j in range(len(parts))],
-            with_convergence=False)
+            local_step=local_step,
+            extra=(self.inv_deg, self.dangling), with_convergence=False)
         placement = row_sharding(self.mesh)
 
-        def whole_run(padded0, num_iters):
-            return jax.lax.fori_loop(0, num_iters, lambda _i, p: step(p),
-                                     padded0)
-        jprog = jax.jit(whole_run, donate_argnums=(0,))
+        def whole_run(c, padded0, num_iters):
+            return jax.lax.fori_loop(0, num_iters,
+                                     lambda _i, p: step(c, p), padded0)
+        jprog = jax.jit(whole_run, donate_argnums=(1,))
 
         def prog(rank0, num_iters):
             padded = jax.device_put(eng.pad_rows(rank0, widths, s),
                                     placement)
-            return eng.unpad_rows(jprog(padded, num_iters), widths)
+            return eng.unpad_rows(jprog(step.consts, padded, num_iters),
+                                  widths)
         self._progs["resident_shard"] = prog
         return prog
 
@@ -466,12 +464,16 @@ class PageRank:
         if driver == "resident":
             prog = self._progs.get("resident")
             if prog is None:
-                step = self._step()
+                consts, step = self._step()
 
-                def whole_run(rank0, num_iters):
+                def whole_run(c, rank0, num_iters):
                     return jax.lax.fori_loop(0, num_iters,
-                                             lambda _i, r: step(r), rank0)
-                prog = jax.jit(whole_run, donate_argnums=(0,))
+                                             lambda _i, r: step(c, r), rank0)
+                jprog = jax.jit(whole_run, donate_argnums=(1,))
+
+                def prog(rank0, num_iters):
+                    return jprog(consts, rank0, num_iters)
+                prog._cache_size = jprog._cache_size
                 self._progs["resident"] = prog
             # `rank` was created just above and never escapes: donating it
             # is safe, the loop carry reuses its buffer
@@ -481,7 +483,12 @@ class PageRank:
                              "expected 'resident' or 'host'")
         step = self._progs.get("host")
         if step is None:
-            step = self._progs["host"] = jax.jit(self._step())
+            consts, body = self._step()
+            jstep = jax.jit(body)
+
+            def step(rank):
+                return jstep(consts, rank)
+            self._progs["host"] = step
         for _ in range(iters):
             rank = step(rank)
         return rank

@@ -129,6 +129,29 @@ def _pad_gathered(plan: BlockPlan, g: jnp.ndarray) -> jnp.ndarray:
     return gp.reshape((gp.shape[0] // n, n) + g.shape[1:])
 
 
+def combine_rounded(seed: CodeSeed, vals: dict,
+                    zero: jnp.ndarray) -> jnp.ndarray:
+    """The seed's combine, rounded to its dtype before any reduction
+    reads it.
+
+    XLA-CPU always lets LLVM contract ``a * b + c`` into one fused
+    multiply-add, and which products meet which sums inside one fusion
+    depends on the surrounding program — so two programs that run the
+    same combine and the same pinned reduce tree (fused vs per-class,
+    sharded vs single-device) could round differently.  Passing a float
+    term's bits through an integer XOR with ``zero`` — a runtime operand
+    (always 0) the compiler cannot fold — ends every product at a
+    rounded word, so every bitwise guarantee in this engine holds in
+    every surrounding program.  Integer terms pass through."""
+    term = seed.combine(vals)
+    if not jnp.issubdtype(term.dtype, jnp.floating):
+        return term
+    bits = jnp.dtype(f"int{8 * term.dtype.itemsize}")
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(term, bits) ^ zero.astype(bits),
+        term.dtype)
+
+
 def segmented_reduce(term: jnp.ndarray, seg: jnp.ndarray, op_flag: int,
                      reduce: str, identity: float | None = None
                      ) -> jnp.ndarray:
@@ -279,7 +302,7 @@ def _stage_a_jax(plan: BlockPlan, meta, elem_exec, mutable,
         rank = max((v.ndim for v in vals.values()), default=2)
         for e in seed.elementwise:
             vals[e] = _expand_trailing(elem_exec[e][s], rank)
-        term = seed.combine(vals)
+        term = combine_rounded(seed, vals, meta["zero"])
         red = segmented_reduce(term, meta["seg_ids"][s], launch.op_flag,
                                seed.reduce)
         parts.append(red)
@@ -287,7 +310,7 @@ def _stage_a_jax(plan: BlockPlan, meta, elem_exec, mutable,
 
 
 def _stage_b(plan: BlockPlan, meta, lanes: jnp.ndarray,
-             out_init: jnp.ndarray) -> jnp.ndarray:
+             out_init: jnp.ndarray, depth: int = 0) -> jnp.ndarray:
     """Merged write-back (Fig. 4): one RMW per distinct (block, row) head.
     Head values are re-gathered from the flat (B*N, ...) lane stream in
     row-sorted order, cross-block contributions to one row are combined by
@@ -295,7 +318,8 @@ def _stage_b(plan: BlockPlan, meta, lanes: jnp.ndarray,
     each output row at most once — XLA's unspecified accumulation order for
     duplicate scatter indices can therefore never perturb the result, which
     is what makes fused and per-class launches bitwise-comparable end to
-    end (DESIGN.md §3)."""
+    end (DESIGN.md §3).  ``depth`` is the static tree depth covering the
+    longest run (:func:`head_write_meta`)."""
     hv = lanes.reshape((-1,) + lanes.shape[2:])[meta["head_pos_rowsorted"]]
     seed = plan.seed
     seg = meta["head_row_seg"]
@@ -303,7 +327,7 @@ def _stage_b(plan: BlockPlan, meta, lanes: jnp.ndarray,
     op, _ = REDUCE_OPS[seed.reduce]
     identity = reduce_identity_for(seed.reduce, hv.dtype)
     trailing = ((0, 0),) * (hv.ndim - 1)
-    for k in range(int(meta["head_tree_depth"])):
+    for k in range(depth):
         d = 1 << k
         shifted = jnp.pad(hv[d:], ((0, d),) + trailing,
                           constant_values=identity)
@@ -354,7 +378,7 @@ def dense_head_rows(plan: BlockPlan) -> np.ndarray:
 
 
 def _stage_b_dense(plan: BlockPlan, meta, lanes: jnp.ndarray,
-                   out_init: jnp.ndarray) -> jnp.ndarray:
+                   out_init: jnp.ndarray, depth: int = 0) -> jnp.ndarray:
     """Fused write-back: scatter the whole post-reduce lane stream through
     the dense head-row buffer (non-head lanes land in the discard bucket at
     ``out_len``), avoiding the flat B*N re-gather of :func:`_stage_b`."""
@@ -389,17 +413,69 @@ def reorder_static(plan: BlockPlan, static_data: Mapping[str, np.ndarray]
             for e in seed.elementwise}
 
 
+class Sweep:
+    """One sweep program split into its plan operands and a pure body.
+
+    ``consts`` is a pytree of device arrays staged once at build time
+    (lane metadata, reordered elementwise data, write-back structure,
+    coalesced slice bases); ``apply(consts, mutable, out_init) -> out``
+    is the traceable stage-A/stage-B program over them.  Every jitted
+    caller passes ``consts`` as an ARGUMENT: a device array closed over by
+    a traced function becomes an HLO literal, which at 10^7-10^8 nnz
+    bloats the program toward the serialization limit, makes the compiler
+    constant-fold the plan, and keys the compile cache on the matrix.
+    Calling the object directly (``sweep(mutable, out_init)``) is for
+    eager use."""
+
+    def __init__(self, apply, consts, tree: ir.CodeTree | None = None):
+        self.apply = apply
+        self.consts = consts
+        self.tree = tree
+
+    def __call__(self, mutable, out_init):
+        return self.apply(self.consts, mutable, out_init)
+
+
+def sweep_parts(run):
+    """``(consts, apply)`` of an executor's sweep program — the pieces a
+    jitted driver (resident loop, vmapped batch, timed tuner loop) embeds,
+    passing ``consts`` as an argument.  A callable without a
+    :class:`Sweep` body is wrapped as-is (no operands)."""
+    body = getattr(run, "sweep_body", None)
+    if isinstance(body, Sweep):
+        return body.consts, body.apply
+    return (), lambda _consts, mutable, out_init: run(mutable, out_init)
+
+
+_META_KEYS = ("window_ids", "lane_slot", "lane_offset", "seg_ids",
+              "gather_idx")
+
+
+def _stage_meta(plan: BlockPlan, launches: list[ir.Launch]) -> dict:
+    """Device copies of the per-lane plan arrays the XLA launches read —
+    only those some launch needs, so unused metadata never occupies
+    device memory."""
+    kinds = {launch.gather for launch in launches}
+    keys = {"seg_ids"} if launches else set()
+    if ir.FALLBACK in kinds:
+        keys.add("gather_idx")
+    if kinds & {ir.WINDOW, ir.STREAM}:
+        keys.update(("window_ids", "lane_slot", "lane_offset"))
+    return {k: jnp.asarray(getattr(plan, k)) for k in _META_KEYS
+            if k in keys}
+
+
 @_trace.traced("engine.build_sweeper")
 def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
                  backend: str = "jax", interpret: bool | None = None,
                  fused: bool = True, stage_b: str = "auto",
                  elem_exec: Mapping[str, jnp.ndarray] | None = None,
                  coalesce: bool = False, tree: ir.CodeTree | None = None,
-                 kernel_params: Mapping[str, int] | None = None):
-    """The raw sweep body ``fn(mutable: dict, out_init) -> out`` — the same
-    stage-A/stage-B program :func:`make_executor` jits, without the jit
-    boundary, for embedding inside ``lax.while_loop`` / ``fori_loop``
-    fixpoint drivers (DESIGN.md §7).
+                 kernel_params: Mapping[str, int] | None = None) -> Sweep:
+    """The raw sweep program as a :class:`Sweep` — the same stage-A/
+    stage-B program :func:`make_executor` jits, without the jit boundary,
+    for embedding inside ``lax.while_loop`` / ``fori_loop`` fixpoint
+    drivers (DESIGN.md §7).
 
     The plan is first lowered through the information-code-tree pipeline
     (:func:`repro.core.ir.lower` — fuse/stage-B/coalesce passes per the
@@ -408,11 +484,10 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
 
     All host-side constants (reordered elementwise arrays, lane metadata,
     write-back structure, coalesced slice bases) are staged to the device
-    HERE, once: tracing the returned function inside a resident loop
-    closes over device arrays and re-uploads nothing.  Because the
-    standalone executor is literally ``jax.jit`` of this function, a
-    resident loop iteration is bitwise identical to a standalone executor
-    call.
+    HERE, once, as ``Sweep.consts``: a resident loop passes them into its
+    jitted program and re-uploads nothing.  Because the standalone
+    executor is literally ``jax.jit`` of ``Sweep.apply``, a resident loop
+    iteration is bitwise identical to a standalone executor call.
 
     ``tree`` optionally supplies an ALREADY-LOWERED code tree (its plan
     must be ``plan``) and skips the internal :func:`repro.core.ir.lower`
@@ -431,18 +506,14 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
             f"{tree.backend!r}, emitter asked for {backend!r}")
     if elem_exec is None:
         elem_exec = reorder_static(plan, static_data)
-    meta = {
-        "window_ids": jnp.asarray(plan.window_ids),
-        "lane_slot": jnp.asarray(plan.lane_slot),
-        "lane_offset": jnp.asarray(plan.lane_offset),
-        "seg_ids": jnp.asarray(plan.seg_ids),
-        "gather_idx": jnp.asarray(plan.gather_idx),
-    }
+    elem_exec = dict(elem_exec)
+    wb_meta, depth = {}, 0
     if tree.stage_b == "dense":
-        meta["lane_rows"] = jnp.asarray(dense_head_rows(plan))
+        wb_meta["lane_rows"] = jnp.asarray(dense_head_rows(plan))
         write_back = _stage_b_dense
     elif tree.stage_b == "gather":
-        meta.update(head_write_meta(plan))
+        wb_meta = head_write_meta(plan)
+        depth = wb_meta.pop("head_tree_depth")
         write_back = _stage_b
     else:
         write_back = None            # "fold": segsum stage A+B are one op
@@ -455,13 +526,15 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
                         else jnp.asarray(launch.local_offset, jnp.int32))}
             for i, launch in enumerate(launches)
             if launch.gather == ir.COALESCED}
+        consts = {"meta": {**_stage_meta(plan, launches), **wb_meta,
+                           "zero": jnp.zeros((), jnp.int32)},
+                  "elem": elem_exec, "co": co_meta}
 
-        def run(mutable, out_init):
-            lanes = _stage_a_jax(plan, meta, elem_exec, mutable, launches,
-                                 co_meta)
-            return write_back(plan, meta, lanes, out_init)
-        run.tree = tree
-        return run
+        def run(c, mutable, out_init):
+            lanes = _stage_a_jax(plan, c["meta"], c["elem"], mutable,
+                                 launches, c["co"])
+            return write_back(plan, c["meta"], lanes, out_init, depth)
+        return Sweep(run, consts, tree)
 
     if backend == "segsum":
         # CPU-optimal configuration of the same plan: the Data Transfer
@@ -485,8 +558,10 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
         per_seg[hb, seg[hb, hl]] = plan.head_rows
         lane_rows = per_seg[np.arange(plan.num_blocks)[:, None], seg]
         lane_rows = np.where(plan.valid, lane_rows, plan.out_len)
-        rows_j = jnp.asarray(lane_rows.reshape(-1), jnp.int32)
-        gidx_j = jnp.asarray(plan.gather_idx.reshape(-1), jnp.int32)
+        consts = {
+            "rows": jnp.asarray(lane_rows.reshape(-1), jnp.int32),
+            "gidx": jnp.asarray(plan.gather_idx.reshape(-1), jnp.int32),
+            "elem": elem_exec}
 
         seg_reduce = {"add": jax.ops.segment_sum,
                       "mul": jax.ops.segment_prod,
@@ -495,18 +570,18 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
         from repro.core.seed import REDUCE_OPS
         fold = REDUCE_OPS[seed.reduce][0]
 
-        def run_ss(mutable, out_init):
+        def run_ss(c, mutable, out_init):
             vals = {}
             for g in seed.gathered:
-                vals[g] = jnp.asarray(mutable[g])[gidx_j]
+                vals[g] = jnp.asarray(mutable[g])[c["gidx"]]
             rank = max((v.ndim for v in vals.values()), default=1)
             for e in seed.elementwise:
-                vals[e] = _expand_trailing(elem_exec[e].reshape(-1), rank)
+                vals[e] = _expand_trailing(c["elem"][e].reshape(-1), rank)
             term = seed.combine(vals)
-            red = seg_reduce(term, rows_j, num_segments=plan.out_len + 1)
+            red = seg_reduce(term, c["rows"],
+                             num_segments=plan.out_len + 1)
             return fold(out_init, red[:plan.out_len])
-        run_ss.tree = tree
-        return run_ss
+        return Sweep(run_ss, consts, tree)
 
     if backend == "pallas":
         from repro.kernels import common as kcommon
@@ -514,16 +589,15 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
         # interpret=None platform-resolves: real compile on TPU/GPU,
         # interpret mode only on CPU or by explicit request (DESIGN.md §13)
         interpret = kcommon.resolve_interpret(interpret)
-        stage_a = kops.make_stage_a(plan, meta, elem_exec,
-                                    interpret=interpret,
-                                    launches=tree.launches,
-                                    kernel_params=kernel_params)
+        stage_consts, stage_a = kops.make_stage_a(
+            plan, elem_exec, interpret=interpret, launches=tree.launches,
+            kernel_params=kernel_params)
+        consts = {"meta": wb_meta, "stage_a": stage_consts}
 
-        def run_pl(mutable, out_init):
-            lanes = stage_a(mutable)
-            return write_back(plan, meta, lanes, out_init)
-        run_pl.tree = tree
-        return run_pl
+        def run_pl(c, mutable, out_init):
+            lanes = stage_a(c["stage_a"], mutable)
+            return write_back(plan, c["meta"], lanes, out_init, depth)
+        return Sweep(run_pl, consts, tree)
 
     raise ValueError(f"unknown backend {backend!r}")
 
@@ -540,9 +614,10 @@ def make_executor(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
 
     ``static_data`` holds the seed's *elementwise* (immutable, nnz-aligned)
     arrays in original order; they are reordered once here (Data Transfer)
-    and closed over as device constants.  ``elem_exec`` optionally supplies
-    the already-reordered arrays (:func:`reorder_static`) so multiple
-    executors on one plan share the reorder work.
+    and staged as device operands of the program.  ``elem_exec``
+    optionally supplies the already-reordered arrays
+    (:func:`reorder_static`) so multiple executors on one plan share the
+    reorder work.
 
     ``fused`` (default) collapses the per-class launch list into at most
     two launches (DESIGN.md §3); ``fused=False`` keeps the paper's
@@ -568,12 +643,13 @@ def make_executor(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
     drivers instead: the ``while_loop`` carry double-buffers internally
     with no donation hazard.
 
-    The returned callable exposes the raw traceable body as
-    ``run.sweep_body``, the underlying jitted function as ``run.jitted``
-    (the profiler lowers it to HLO), and the lowered code tree as
-    ``run.tree`` (per-launch cost attribution, DESIGN.md §11).  With
-    tracing enabled each call emits an ``engine.execute`` span —
-    ``first_call=True`` marks the call that paid JIT compilation.
+    The returned callable exposes the :class:`Sweep` as
+    ``run.sweep_body``, the jitted ``(consts, mutable, out_init)`` program
+    as ``run.jitted``, ``run.lower(mutable, out_init)`` (the profiler
+    lowers it to HLO), and the lowered code tree as ``run.tree``
+    (per-launch cost attribution, DESIGN.md §11).  With tracing enabled
+    each call emits an ``engine.execute`` span — ``first_call=True``
+    marks the call that paid JIT compilation.
     """
     if fuse_classes is not None:      # legacy alias of the pre-fused API
         fused = fuse_classes
@@ -581,20 +657,27 @@ def make_executor(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
                         interpret=interpret, fused=fused, stage_b=stage_b,
                         elem_exec=elem_exec, coalesce=coalesce, tree=tree,
                         kernel_params=kernel_params)
-    jitted = jax.jit(body, donate_argnums=(1,) if donate else ())
+    return _executor(body, donate, backend=backend)
+
+
+def _executor(body: Sweep, donate: bool, **span_attrs):
+    """Jit a :class:`Sweep` with its operands passed as arguments and wrap
+    it in the traced ``run(mutable, out_init)`` call contract."""
+    jitted = jax.jit(body.apply, donate_argnums=(2,) if donate else ())
 
     def run(mutable, out_init):
         if not _trace.enabled():
-            return jitted(mutable, out_init)
+            return jitted(body.consts, mutable, out_init)
         first = not run._called
         run._called = True
-        with _trace.span("engine.execute", backend=backend,
-                         first_call=first):
-            return jitted(mutable, out_init)
+        with _trace.span("engine.execute", first_call=first, **span_attrs):
+            return jitted(body.consts, mutable, out_init)
     run._called = False
     run.sweep_body = body
     run.jitted = jitted
-    run.tree = getattr(body, "tree", None)
+    run.lower = lambda mutable, out_init: jitted.lower(body.consts, mutable,
+                                                       out_init)
+    run.tree = body.tree
     return run
 
 
@@ -605,10 +688,7 @@ def make_executor(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
 # unpad on exit, all inside one jit), so a sharded executor is a drop-in
 # replacement for a single-device one — same oracle checks, same tuner
 # measurement harness, bitwise-equal outputs.
-try:
-    from jax import shard_map as _shard_map
-except ImportError:        # older jax: pre-stabilization location
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax.sharding import NamedSharding as _NS
 from jax.sharding import PartitionSpec as _PS
 
 
@@ -669,20 +749,27 @@ def unpad_rows(padded: jnp.ndarray, widths: list[int]) -> jnp.ndarray:
                            axis=0)
 
 
-def shard_sweep_bodies(parts, static_data):
-    """One sweep body per shard (empty shards -> identity).  Elementwise
-    arrays stay FULL-LENGTH: each shard's sliced ``flat_perm`` holds
-    global nnz positions, so the per-shard Data Transfer reorders the
-    same full arrays the parent would (the parent's own ``elem_exec``
-    cannot be shared — it is already block-reordered)."""
+def shard_sweep_bodies(parts, static_data) -> list[Sweep | None]:
+    """One :class:`Sweep` per shard (``None`` for an empty shard, which
+    runs as the identity).  Elementwise arrays stay FULL-LENGTH: each
+    shard's sliced ``flat_perm`` holds global nnz positions, so the
+    per-shard Data Transfer reorders the same full arrays the parent
+    would (the parent's own ``elem_exec`` cannot be shared — it is
+    already block-reordered)."""
     bodies = []
     for p in parts:
         if p.num_blocks == 0 or p.tree.plan.head_pos.size == 0:
-            bodies.append(lambda mutable, out_init: out_init)
+            bodies.append(None)
             continue
         bodies.append(make_sweeper(p.tree.plan, static_data,
                                    backend=p.tree.backend, tree=p.tree))
     return bodies
+
+
+def _replicated(tree, mesh):
+    """Place a pytree of plan operands on every mesh device once, so a
+    sharded call never re-broadcasts them."""
+    return jax.device_put(tree, _NS(mesh, _PS()))
 
 
 def _pad_to(y: jnp.ndarray, s: int) -> jnp.ndarray:
@@ -699,96 +786,94 @@ def make_sharded_executor(parts, static_data, mesh, *,
     through GLOBAL indices); ``out_init`` is row-sharded.  Device ``i``
     selects its shard's program with ``lax.switch(axis_index)`` — every
     branch pads its rows to the common width S so the switch is
-    shape-legal.  Bitwise: each output row runs the parent's identical
-    block program and per-row combine tree (ir.partition_plan), so the
-    result equals single-device execution bit for bit."""
+    shape-legal.  Every shard's plan operands are replicated onto every
+    device and passed into the program as arguments.  Bitwise: each
+    output row runs the parent's identical block program and per-row
+    combine tree (ir.partition_plan), so the result equals single-device
+    execution bit for bit."""
     axis = _check_parts(parts, mesh)
     widths, s = shard_widths(parts)
     k = len(parts)
     bodies = shard_sweep_bodies(parts, static_data)
+    consts = _replicated([b.consts if b else () for b in bodies], mesh)
 
-    def device_fn(mutable, block):          # block: (1, S, ...) local
+    def device_fn(c, mutable, block):       # block: (1, S, ...) local
         def branch(j):
-            def f(mut, blk):
-                if widths[j] == 0:
+            def f(c, mut, blk):
+                if bodies[j] is None:
                     return blk
-                y = bodies[j](mut, blk[0, :widths[j]])
+                y = bodies[j].apply(c[j], mut, blk[0, :widths[j]])
                 return _pad_to(y, s)[None]
             return f
         i = jax.lax.axis_index(axis)
         return jax.lax.switch(i, [branch(j) for j in range(k)],
-                              mutable, block)
+                              c, mutable, block)
 
-    def run_full(mutable, out_init):
-        mut_spec = jax.tree.map(lambda _: _PS(), mutable)
+    def run_full(c, mutable, out_init):
         padded = pad_rows(out_init, widths, s)
-        y = _shard_map(device_fn, mesh=mesh,
-                       in_specs=(mut_spec, _PS(axis)),
-                       out_specs=_PS(axis))(mutable, padded)
+        y = jax.shard_map(device_fn, mesh=mesh,
+                          in_specs=(_PS(), _PS(), _PS(axis)),
+                          out_specs=_PS(axis))(c, mutable, padded)
         return unpad_rows(y, widths)
 
-    jitted = jax.jit(run_full, donate_argnums=(1,) if donate else ())
-
-    def run(mutable, out_init):
-        if not _trace.enabled():
-            return jitted(mutable, out_init)
-        first = not run._called
-        run._called = True
-        with _trace.span("engine.execute", backend=parts[0].tree.backend,
-                         shards=k, first_call=first):
-            return jitted(mutable, out_init)
-    run._called = False
-    run.sweep_body = run_full
-    run.jitted = jitted
+    run = _executor(Sweep(run_full, consts), donate,
+                    backend=parts[0].tree.backend, shards=k)
     run.parts = parts
     run.mesh = mesh
     return run
 
 
 def make_sharded_fixpoint_step(parts, static_data, mesh, state_key: str,
-                               *, local_steps=None,
+                               *, local_step=None, extra=(),
                                with_convergence: bool = True):
-    """The sharded resident sweep ``step(padded_state) -> ...`` for
-    fixpoint drivers (DESIGN.md §7/§10): state lives row-sharded as the
-    padded ``(k, S, ...)`` stack, each sweep ``all_gather``s the shard
-    pieces into the full dense input vector, runs the local subtree on
-    the shard's own rows (fold semantics: ``out_init`` is the shard's
+    """The sharded resident sweep ``step(consts, padded_state) -> ...``
+    for fixpoint drivers (DESIGN.md §7/§10), with its replicated plan
+    operands on ``step.consts``: state lives row-sharded as the padded
+    ``(k, S, ...)`` stack, each sweep ``all_gather``s the shard pieces
+    into the full dense input vector, runs the local subtree on the
+    shard's own rows (fold semantics: ``out_init`` is the shard's
     previous rows), and re-pads.  With ``with_convergence`` the step
     also returns replicated device-side ``(changed, healthy)`` scalars —
     ``psum`` of the per-shard ``array_equal`` / ``state_healthy``
     verdicts, so convergence needs no host round-trip and no full-state
     rebuild outside the loop.
 
-    ``local_steps`` optionally overrides the per-shard body: a list of
-    ``f_j(full_state, local_rows) -> new_local_rows`` (PageRank's damping
-    fold wraps the contribution sweep this way)."""
+    ``local_step`` optionally overrides the per-shard body:
+    ``f(apply_j, consts_j, extra, full_state, local_rows) ->
+    new_local_rows`` (PageRank's damping fold wraps the contribution
+    sweep this way); ``extra`` is a pytree of further device operands
+    it reads, replicated and passed in like the plan's."""
     axis = _check_parts(parts, mesh)
     widths, s = shard_widths(parts)
     k = len(parts)
     reduce = parts[0].tree.plan.seed.reduce
-    if local_steps is None:
-        bodies = shard_sweep_bodies(parts, static_data)
-        local_steps = [
-            (lambda j: lambda full, local:
-             bodies[j]({state_key: full}, local))(j) for j in range(k)]
+    bodies = shard_sweep_bodies(parts, static_data)
+    if local_step is None:
+        def local_step(apply, c, _extra, full, local):
+            return apply(c, {state_key: full}, local)
+    consts = _replicated(([b.consts if b else () for b in bodies], extra),
+                         mesh)
 
-    def device_fn(block):                    # (1, S, ...) local rows
+    def device_fn(c, block):                 # (1, S, ...) local rows
+        shard_consts, ext = c
         pieces = jax.lax.all_gather(block[0], axis)       # (k, S, ...)
         full = unpad_rows(pieces, widths)                 # (n, ...)
 
         def branch(j):
-            def f(blk):
-                if widths[j] == 0:
+            def f(c, blk):
+                if bodies[j] is None:
                     return blk
-                new = local_steps[j](full, blk[0, :widths[j]])
+                new = local_step(bodies[j].apply, c[j], ext, full,
+                                 blk[0, :widths[j]])
                 return _pad_to(new, s)[None]
             return f
         i = jax.lax.axis_index(axis)
-        new = jax.lax.switch(i, [branch(j) for j in range(k)], block)
+        new = jax.lax.switch(i, [branch(j) for j in range(k)],
+                             shard_consts, block)
         if not with_convergence:
             return new
-        # ISSUE/DESIGN §10: device-side convergence via psum of the
-        # per-shard verdicts — both scalars replicate across the axis
+        # device-side convergence via psum of the per-shard verdicts —
+        # both scalars replicate across the axis
         changed_here = jnp.logical_not(jnp.array_equal(new, block))
         changed = jax.lax.psum(changed_here.astype(jnp.int32), axis) > 0
         sick_here = jnp.logical_not(state_healthy(new, reduce))
@@ -797,11 +882,12 @@ def make_sharded_fixpoint_step(parts, static_data, mesh, state_key: str,
 
     out_specs = (_PS(axis), _PS(), _PS()) if with_convergence \
         else _PS(axis)
-    mapped = _shard_map(device_fn, mesh=mesh, in_specs=_PS(axis),
-                        out_specs=out_specs)
 
-    def step(padded_state):
-        return mapped(padded_state)
+    def step(c, padded_state):
+        return jax.shard_map(device_fn, mesh=mesh,
+                             in_specs=(_PS(), _PS(axis)),
+                             out_specs=out_specs)(c, padded_state)
+    step.consts = consts
     step.widths = widths
     step.padded_width = s
     step.axis = axis

@@ -190,14 +190,15 @@ def _autotune_build(seed: CodeSeed, access, num_nodes, static_data,
     cache_extra = ""
     if driver == "resident":
         def measure_wrap(run):
-            body = getattr(run, "sweep_body", None) or run
+            consts, apply = eng.sweep_parts(run)
 
-            def whole_run(mutable, _out_init):
+            def whole_run(c, state):
                 return jax.lax.fori_loop(
                     0, _TUNE_RUN_SWEEPS,
-                    lambda _i, s: body({state_key: s}, s),
-                    mutable[state_key])
-            return jax.jit(whole_run)
+                    lambda _i, s: apply(c, {state_key: s}, s), state)
+            jitted = jax.jit(whole_run)
+            return lambda mutable, _out_init: jitted(consts,
+                                                     mutable[state_key])
         cache_extra = f"measure=resident_run:{_TUNE_RUN_SWEEPS}"
     plan, run, result = autotune(
         seed, access, num_nodes, num_nodes, static_data,
@@ -296,12 +297,12 @@ class _FixpointApp:
         return self._run({self._state_key: state}, state)
 
     def _step_body(self):
-        """The raw traceable sweep ``state -> state`` — the executor's own
-        body when available (``make_executor`` attaches it), else the
-        jitted executor itself (jit-of-jit inlines under the loop trace)."""
-        body = getattr(self._run, "sweep_body", None) or self._run
+        """``(consts, step)``: the executor's plan operands and the raw
+        traceable sweep ``step(consts, state) -> state`` over them (see
+        :func:`engine.sweep_parts`)."""
+        consts, apply = eng.sweep_parts(self._run)
         key = self._state_key
-        return lambda s: body({key: s}, s)
+        return consts, lambda c, s: apply(c, {key: s}, s)
 
     def _resident_converge(self, batched: bool):
         """The jitted whole-convergence program (built once per driver
@@ -315,12 +316,12 @@ class _FixpointApp:
         all-sources-converged semantics of the host driver."""
         fn = self._resident.get(batched)
         if fn is None:
-            step = self._step_body()
+            consts, step = self._step_body()
             if batched:
-                step = jax.vmap(step)
+                step = jax.vmap(step, in_axes=(None, 0))
             reduce = self.plan.seed.reduce
 
-            def converge(state, max_sweeps):
+            def converge(c, state, max_sweeps):
                 def cond(carry):
                     _state, count, changed, healthy = carry
                     return jnp.logical_and(
@@ -329,7 +330,7 @@ class _FixpointApp:
 
                 def body(carry):
                     state, count, _changed, _healthy = carry
-                    new = step(state)
+                    new = step(c, state)
                     return (new, count + jnp.int32(1),
                             jnp.logical_not(jnp.array_equal(new, state)),
                             eng.state_healthy(new, reduce))
@@ -345,7 +346,11 @@ class _FixpointApp:
                     cond, body, init)
                 return final, count, changed, healthy
 
-            fn = jax.jit(converge)
+            jfn = jax.jit(converge)
+
+            def fn(state, max_sweeps):
+                return jfn(consts, state, max_sweeps)
+            fn.jitted, fn.consts = jfn, consts
             self._resident[batched] = fn
         return fn
 
@@ -367,7 +372,7 @@ class _FixpointApp:
             reduce = self.plan.seed.reduce
             placement = row_sharding(self.mesh)
 
-            def converge(padded, max_sweeps):
+            def converge(c, padded, max_sweeps):
                 def cond(carry):
                     _state, count, changed, healthy = carry
                     return jnp.logical_and(
@@ -376,7 +381,7 @@ class _FixpointApp:
 
                 def body(carry):
                     state, count, _changed, _healthy = carry
-                    new, changed, healthy = step(state)
+                    new, changed, healthy = step(c, state)
                     return (new, count + jnp.int32(1), changed, healthy)
 
                 # pad lanes are constant zeros (pad_rows), so the initial
@@ -392,7 +397,8 @@ class _FixpointApp:
             def fn(state, max_sweeps):
                 padded = jax.device_put(
                     eng.pad_rows(state, widths, s), placement)
-                final, count, changed, healthy = jfn(padded, max_sweeps)
+                final, count, changed, healthy = jfn(step.consts, padded,
+                                                     max_sweeps)
                 return eng.unpad_rows(final, widths), count, changed, healthy
 
             self._resident["shard"] = fn

@@ -276,6 +276,11 @@ def choose_stage_b(tree: CodeTree, stage_b: str = "auto") -> CodeTree:
 # and a handful of blocks cannot amortize it.  A launch that is
 # coalescible IN FULL is always converted (no split, no new launch).
 MIN_COALESCE_RUN = 4
+# At most this many coalesced runs are carved out of one launch (the
+# longest ones).  Irregular inputs interleave short coalescible runs with
+# gather blocks; carving every one of them makes the launch count — and so
+# the program size and its compile time — grow with nnz.
+MAX_COALESCE_RUNS = 8
 
 
 def coalesce_gathers(tree: CodeTree,
@@ -291,7 +296,10 @@ def coalesce_gathers(tree: CodeTree,
     plus a static in-tile permutation (``None`` when the run is exactly
     ``base + iota`` — then the slice IS the lane vector).  Launches are
     split at eligibility boundaries into maximal runs, keeping exec-order
-    contiguity; ineligible remainders keep their original idiom.
+    contiguity; ineligible remainders keep their original idiom.  At
+    most :data:`MAX_COALESCE_RUNS` runs per launch are carved out — the
+    longest — so a launch splits into at most ``2 * MAX_COALESCE_RUNS +
+    1`` launches whatever the input size.
 
     Legality / bitwise argument: the slice covers ``[base, base + N)`` of
     the same padded dense view the window path reads, every lane's value
@@ -331,7 +339,9 @@ def coalesce_gathers(tree: CodeTree,
 
 def _split_launch(launch: Launch, runs: ft.GatherRunFeatures,
                   gidx: np.ndarray, min_run_blocks: int) -> list[Launch]:
-    """Split one launch into maximal coalescible / residual sub-ranges."""
+    """Split one launch into maximal coalescible / residual sub-ranges
+    (the :data:`MAX_COALESCE_RUNS` longest coalescible runs of at least
+    ``min_run_blocks`` blocks)."""
     n_blocks = launch.num_blocks
     elig = runs.coalescible
     if elig.all():
@@ -340,9 +350,14 @@ def _split_launch(launch: Launch, runs: ft.GatherRunFeatures,
     bounds = np.flatnonzero(np.diff(elig.astype(np.int8))) + 1
     edges = np.concatenate([[0], bounds, [n_blocks]])
     keep = elig.copy()
+    runs_found = []
     for lo, hi in zip(edges[:-1], edges[1:]):
         if elig[lo] and (hi - lo) < min_run_blocks:
             keep[lo:hi] = False     # too short to carve out
+        elif elig[lo]:
+            runs_found.append((hi - lo, lo, hi))
+    for _, lo, hi in sorted(runs_found, reverse=True)[MAX_COALESCE_RUNS:]:
+        keep[lo:hi] = False         # past the run budget: shortest first
     if not keep.any():
         return [launch]
     bounds = np.flatnonzero(np.diff(keep.astype(np.int8))) + 1

@@ -1,12 +1,11 @@
 """Shared in-kernel building blocks for the Intelligent-Unroll Pallas kernels.
 
 TPU adaptation of the paper's instruction groups:
-  * ``permute_onehot`` — the paper's ``permutation + select`` pair (Fig. 6).
-    On TPU a static per-lane permutation is expressed as a small one-hot
-    matmul so it runs on the MXU; the select masks fold into the one-hot
-    (lane j's row has its single 1 at ``slot[j] * N + offset[j]``).
+  * ``permute_tiles`` — the paper's ``permutation + select`` pair (Fig. 6):
+    an in-register lane permute of each window tile, merged by a select
+    chain on the lane's window slot.
   * ``segmented_reduce_lanes`` — the paper's log-step shuffle-reduce (§5,
-    Fig. 5): ``op_flag`` static steps of masked shift-combine; masks are
+    Fig. 5): ``op_flag`` static steps of masked lane-rotate-combine; masks are
     derived on the fly from segment-id compares (cheaper than the paper's
     stored M mask vectors — a beyond-paper micro-optimization, VPU compares
     are free relative to the metadata loads they replace).
@@ -20,6 +19,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.seed import reduce_identity_for
 
@@ -55,38 +55,64 @@ def resolve_interpret(interpret: bool | None) -> bool:
     return jax.default_backend() not in ("tpu", "gpu")
 
 
+def permute_tiles(tiles, slot: jnp.ndarray, offset: jnp.ndarray
+                  ) -> jnp.ndarray:
+    """Gather-replacement permute (paper Fig. 6: permutation + select):
+    ``M`` window tiles of shape ``(1, N, ...)`` -> the ``(1, N, ...)`` lane
+    vector whose lane ``j`` is word ``offset[j]`` of tile ``slot[j]``.
+
+    ``slot``/``offset`` are ``(1, N)`` int32.  Each tile is permuted
+    inside the lane axis (``take_along_axis`` — an in-register lane
+    shuffle on TPU, no memory gather) and the ``M`` permuted tiles are
+    merged by a select chain on ``slot``.  Every lane returns the selected
+    word bit for bit, for every dtype (no arithmetic touches the payload,
+    so ``±inf`` identities and large int32 words survive); a lane whose
+    slot names no tile reads 0.
+
+    Rank rule: trailing axes ride along unchanged — every lane selects a
+    whole ``(...,)`` value row (SpMM fetches rows of B), and the 2-D lane
+    metadata broadcasts over them."""
+    ndim = tiles[0].ndim
+    idx = jnp.broadcast_to(expand_trailing(offset.astype(jnp.int32), ndim),
+                           tiles[0].shape)
+    slot = expand_trailing(slot.astype(jnp.int32), ndim)
+    out = jnp.zeros(tiles[0].shape, tiles[0].dtype)
+    for w, tile in enumerate(tiles):
+        out = jnp.where(slot == w, _lane_gather(tile, idx), out)
+    return out
+
+
+def _lane_gather(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``out[r, j, ...] = x[r, idx[r, j, ...], ...]`` with in-bounds
+    indices.  The 2-D case is spelled as the batched lane gather Mosaic
+    lowers to ``tpu.dynamic_gather`` (``jnp.take_along_axis`` picks
+    another form when the leading dim is 1)."""
+    if x.ndim != 2:
+        return jnp.take_along_axis(x, idx, axis=1)
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
+        operand_batching_dims=(0,), start_indices_batching_dims=(0,))
+    return jax.lax.gather(x, idx[..., None], dnums, slice_sizes=(1, 1),
+                          mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
 def permute_onehot(windows: jnp.ndarray, slot: jnp.ndarray,
                    offset: jnp.ndarray) -> jnp.ndarray:
-    """Gather-replacement permute: windows (M, N, ...) -> (N, ...) per-lane
-    values.
+    """Stacked-window form of :func:`permute_tiles`: windows ``(M, N, ...)``
+    -> ``(N, ...)``, equivalent to ``concat(windows)[slot * N + offset]``."""
+    tiles = [windows[w:w + 1] for w in range(windows.shape[0])]
+    return permute_tiles(tiles, slot, offset)[0]
 
-    ``slot``/``offset`` are (1, N) int32.  Implemented as
-    ``one_hot(slot * N + offset) @ concat(windows)`` — an (N, M*N) x (M*N,)
-    matmul that maps onto the MXU.  Equivalent to
-    ``concat(windows)[slot * N + offset]``.
 
-    Implemented as a masked select-sum rather than a literal
-    ``one_hot @ flat`` matmul: the semiring payloads carry non-finite
-    identities (``±inf`` for float min/max) and int32 words that float32
-    cannot represent, and the matmul form computes ``0 · inf = NaN`` /
-    rounds large ints.  Exactly one mask bit is set per lane, so the sum
-    returns the selected word bit for bit for every dtype, and the
-    mask+sum still vectorizes on the VPU (one-hot generation is shared
-    with the matmul form; only the combine differs).
-
-    Rank rule: trailing axes of ``windows`` ride along unchanged — every
-    lane selects a whole ``(...,)`` value row (SpMM fetches rows of B), so
-    the one-hot mask broadcasts over them.
-    """
-    m, n = windows.shape[:2]
-    trailing = windows.shape[2:]
-    sel = (slot.astype(jnp.int32) * n + offset.astype(jnp.int32)).reshape(n)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (n, m * n), 1)
-    onehot = cols == sel[:, None]                         # (N, M*N)
-    flat = windows.reshape((m * n,) + trailing)
-    mask = expand_trailing(onehot, 2 + len(trailing))     # (N, M*N, 1...)
-    return jnp.where(mask, flat[None],
-                     jnp.zeros((), flat.dtype)).sum(axis=1)
+def shift_lanes(a: jnp.ndarray, d: int, fill) -> jnp.ndarray:
+    """``out[:, j] = a[:, j + d]`` for ``j < N - d``, ``fill`` beyond —
+    a lane rotation (``pltpu.roll``) plus a lane mask, the form Mosaic
+    lowers without unaligned lane slices."""
+    n = a.shape[1]
+    rolled = pltpu.roll(a, n - d, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape[:2], 1)
+    keep = expand_trailing(lane < n - d, a.ndim)
+    return jnp.where(keep, rolled, jnp.asarray(fill, a.dtype))
 
 
 def segmented_reduce_lanes(term: jnp.ndarray, seg: jnp.ndarray,
@@ -102,13 +128,12 @@ def segmented_reduce_lanes(term: jnp.ndarray, seg: jnp.ndarray,
         total = full(term, axis=1, keepdims=True)
         lane = jax.lax.broadcasted_iota(jnp.int32, term.shape[:2], 1)
         return jnp.where(expand_trailing(lane == 0, term.ndim), total, term)
-    trailing = ((0, 0),) * (term.ndim - 2)
     for k in range(op_flag):
         d = 1 << k
-        shifted = jnp.pad(term[:, d:], ((0, 0), (0, d)) + trailing,
-                          constant_values=identity)
-        seg_shift = jnp.pad(seg[:, d:], ((0, 0), (0, d)),
-                            constant_values=SEG_PAD)
+        if d >= term.shape[1]:
+            break               # every shifted lane is pad: a no-op step
+        shifted = shift_lanes(term, d, identity)
+        seg_shift = shift_lanes(seg, d, SEG_PAD)
         mask = expand_trailing(seg == seg_shift, term.ndim)
         term = jnp.where(mask, op(term, shifted), term)
     return term

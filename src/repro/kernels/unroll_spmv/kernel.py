@@ -12,8 +12,9 @@ grid step the kernel
      fused mode ``ls`` is the section-wide max: slots beyond a block's own
      window count repeat the last valid window id (legal DMA, never
      selected by the lane permutation).
-  2. applies the static per-lane permutation + select via a one-hot MXU
-     matmul (paper Fig. 6: permutation + select instructions),
+  2. applies the static per-lane permutation + select: an in-register
+     lane gather per window tile merged by a select chain on the lane's
+     slot (paper Fig. 6: permutation + select instructions),
   3. evaluates the seed's combine expression on the lane vectors,
   4. runs ``op_flag`` masked shift-reduce steps (paper Fig. 5) so each
      segment head lane holds the segment total.  In fused ``mixed`` mode a
@@ -26,7 +27,7 @@ Outputs the (1, N, ...) post-reduce lane vector; the merged write-back
 
 Rank polymorphism (DESIGN.md §13): gathered views may carry trailing lane
 axes — ``(W, N, D)`` for SpMM rows of B — which ride through the window
-DMAs, the one-hot permute and the shift ladder unchanged; lane metadata
+DMAs, the lane permute and the shift ladder unchanged; lane metadata
 (slot/offset/segment) stays 2-D and broadcasts, the same
 ``_expand_trailing`` rule the XLA emitter applies.
 
@@ -36,19 +37,24 @@ Three lowering forms share the ladder body:
     block per grid step; ``meta_prefetch`` widens the metadata DMA tiles).
     This is also the portable ``interpret=True`` CI form.
   * ``coalesced_stage_a`` — the dense-slice form for
-    ``ir.coalesce_gathers`` launches: per block ONE unaligned
-    ``pl.load``/``pl.ds`` slice of ``lane_width`` elements from the flat
-    padded view plus a static in-tile permute — no per-element gather at
-    all (the paper's gather→vector-load rewrite, §6).  ``rows_per_step``
-    blocks share one grid step.
+    ``ir.coalesce_gathers`` launches: per block one unaligned slice of
+    ``lane_width`` elements, built from the two aligned lane tiles that
+    hold it (rotated by ``start % N`` and merged by a lane mask), plus a
+    static in-tile permute — no per-element gather at all (the paper's
+    gather→vector-load rewrite, §6).  ``rows_per_step`` blocks share one
+    grid step.
   * ``gpu_stage_a`` — Triton form: no scalar prefetch exists there, so
     window tiles are fetched with in-kernel dynamic ``pl.ds`` loads from
     the full view; ``rows_per_step`` rows per program.
 
 VMEM budget per step: (ls * n_gathered + n_elementwise + 4) lane tiles of
-N*prod(trailing) words — a few KB at N=128 scalar lanes; BlockSpecs keep
-everything lane-tile aligned (last dims N x trailing, MXU/VPU native).
-The coalesced form additionally keeps the flat gathered view resident.
+N*prod(trailing) words — a few KB at N=128 scalar lanes (the coalesced
+form: two tiles per gathered array and row).  Nothing of the size of the
+gathered array is held in VMEM.  Every per-block operand is laid out with
+the block axis in front of two whole dims (``(W, 1, N, ...)`` views,
+``(Bc // p, p, N)`` metadata), so each block's last two dims equal the
+array's, which Mosaic's (8, 128) tiling rule accepts at any ``p``.
+Scalar-prefetched operands are chunked to fit SMEM (``PREFETCH_WORDS``).
 """
 from __future__ import annotations
 
@@ -73,18 +79,55 @@ def _largest_divisor(b: int, r: int) -> int:
     return r
 
 
+# Scalar-prefetched operands live in SMEM (1 MiB on v5e, shared with the
+# kernel's own scalars).  A launch whose per-block prefetch words exceed
+# this budget runs as a sequence of equal chunks of whole grid steps (one
+# ``pallas_call`` inside a ``lax.map``) plus one tail call — blocks are
+# independent, so no block's reduction tree changes.
+PREFETCH_WORDS = 1 << 15
+
+
+def _chunk_blocks(bc: int, words_per_block: int, step: int) -> int:
+    """Blocks per chunk: a multiple of ``step`` whose prefetch fits
+    :data:`PREFETCH_WORDS` (>= ``step``), or ``bc`` when all of it does."""
+    c = max(step, PREFETCH_WORDS // max(words_per_block, 1) // step * step)
+    return bc if c >= bc else c
+
+
+def _chunked(call, bc: int, chunk: int, per_block: list, widths: list):
+    """Run ``call(grid_blocks, base, *prefetch) -> (grid_blocks, ...)``
+    over ``[0, bc)`` in chunks of ``chunk`` blocks.  ``per_block`` are the
+    flat scalar-prefetch arrays (``widths[i]`` words per block); each call
+    sees only its chunk's slice of them, plus its first block ``base``."""
+    def run(n_blocks, base):
+        pre = [jax.lax.dynamic_slice(a, (base * w,), (n_blocks * w,))
+               for a, w in zip(per_block, widths)]
+        return call(n_blocks, jnp.reshape(base, (1,)).astype(jnp.int32),
+                    *pre)
+    n_full, tail = divmod(bc, chunk)
+    if n_full == 1 and tail == 0:
+        return run(bc, jnp.int32(0))
+    parts = []
+    if n_full:
+        full = jax.lax.map(lambda i: run(chunk, i * chunk),
+                           jnp.arange(n_full, dtype=jnp.int32))
+        parts.append(full.reshape((n_full * chunk,) + full.shape[2:]))
+    if tail:
+        parts.append(run(tail, jnp.int32(n_full * chunk)))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+
+
 def _combine_lanes(win_vals: dict, elem_vals: dict, combine: Callable,
                    seg: jnp.ndarray, op: int, mixed, reduce: str):
-    """Shared ladder tail: broadcast elementwise lanes up to the gathered
-    rank (§8), combine, shift-reduce, and resolve the fused-mixed
-    native-reduction select.  ``mixed`` is the per-block flag value (a
-    traced scalar) or None."""
+    """Shared ladder tail on ``(1, N, ...)`` lane vectors: broadcast
+    elementwise lanes up to the gathered rank (§8), combine, shift-reduce,
+    and resolve the fused-mixed native-reduction select.  ``mixed`` is the
+    per-block flag value (a traced scalar) or None."""
     vals = dict(win_vals)
-    rank = max((v.ndim for v in vals.values()), default=1)
+    rank = max((v.ndim for v in vals.values()), default=2)
     for e, v in elem_vals.items():
         vals[e] = common.expand_trailing(v, rank)
     term = combine(vals)
-    term = term.reshape((1,) + term.shape)
     red = common.segmented_reduce_lanes(term, seg, op, reduce)
     if mixed is not None:
         native = common.segmented_reduce_lanes(term, seg,
@@ -93,14 +136,28 @@ def _combine_lanes(win_vals: dict, elem_vals: dict, combine: Callable,
     return red
 
 
+def _rows(a: jnp.ndarray, p: int) -> jnp.ndarray:
+    """``(Bc, N, ...) -> (Bc // p, p, N, ...)``: per-block lane rows laid
+    out so a ``(None, p, N, ...)`` block's last two dims are whole array
+    dims — legal for any ``p`` under Mosaic's (8, 128) tiling rule."""
+    return a.reshape((a.shape[0] // p, p) + a.shape[1:])
+
+
+def _tiles(view: jnp.ndarray) -> jnp.ndarray:
+    """``(W, N, ...) -> (W, 1, N, ...)``: one lane tile per leading index,
+    fetched as a ``(None, 1, N, ...)`` block (whole last two dims)."""
+    return view.reshape((view.shape[0], 1) + view.shape[1:])
+
+
 # ------------------------------------------------------- TPU window form
-def _stage_a_body(win_ref, flag_ref, *refs, combine: Callable,
+def _stage_a_body(base_ref, win_ref, flag_ref, *refs, combine: Callable,
                   gathered: tuple, elementwise: tuple, ls: int, op: int,
                   stream: bool, mixed: bool, reduce: str, out_dtype,
                   meta_prefetch: int):
     """Kernel body. ``refs`` layout:
     [g0_win0..g0_win{ls-1}, g1_win0.., ...] + [elem...] +
     [slot, offset, seg] + [out]."""
+    del base_ref                 # consumed by the index maps only
     n_g = len(gathered)
     n_e = len(elementwise)
     win_refs = refs[: n_g * ls]
@@ -121,12 +178,9 @@ def _stage_a_body(win_ref, flag_ref, *refs, combine: Callable,
     vals = {}
     for gi, g in enumerate(gathered):
         tiles = [win_refs[gi * ls + k][...] for k in range(ls)]
-        if stream:
-            vals[g] = tiles[0][0]
-        else:
-            windows = jnp.concatenate(tiles, axis=0)   # (ls, N, ...)
-            vals[g] = common.permute_onehot(windows, slot, off)
-    elem_vals = {e: elem_refs[ei][...][0] for ei, e in enumerate(elementwise)}
+        vals[g] = tiles[0] if stream else common.permute_tiles(tiles, slot,
+                                                               off)
+    elem_vals = {e: elem_refs[ei][...] for ei, e in enumerate(elementwise)}
     flag = flag_ref[pl.program_id(0)] if mixed else None
     red = _combine_lanes(vals, elem_vals, combine, seg, op, flag, reduce)
     out_ref[...] = red.astype(out_dtype)
@@ -176,75 +230,93 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
                              ls=ls, op=op, stream=stream, mixed=mixed,
                              reduce=reduce, out_dtype=out_dtype,
                              meta_prefetch=p)
+    z = len(out_trailing)
 
     in_specs = []
     operands = []
     for g in gathered:
-        view = gathered_views[g]
-        tshape = view.shape[2:]
+        view = _tiles(gathered_views[g])
+        tz = view.ndim - 3
         for k in range(ls):
-            def im(b, w, f, k=k, z=len(tshape)):
-                return (w[b, k], 0) + (0,) * z
-            in_specs.append(pl.BlockSpec((1, n) + tshape, im))
+            def im(b, base, w, f, k=k, tz=tz):
+                return (w[b * ls + k], 0, 0) + (0,) * tz
+            in_specs.append(pl.BlockSpec((None,) + view.shape[1:], im))
             operands.append(view)
     for e in elementwise:
-        in_specs.append(pl.BlockSpec((1, n), lambda b, w, f: (b, 0)))
-        operands.append(elem_blocks[e])
+        in_specs.append(pl.BlockSpec((None, 1, n),
+                                     lambda b, base, w, f: (base[0] + b, 0,
+                                                            0)))
+        operands.append(_rows(elem_blocks[e], 1))
     for meta in (slot, off, seg):
-        in_specs.append(
-            pl.BlockSpec((p, n), lambda b, w, f, p=p: (b // p, 0)))
-        operands.append(meta)
+        in_specs.append(pl.BlockSpec(
+            (None, p, n),
+            lambda b, base, w, f, p=p: ((base[0] + b) // p, 0, 0)))
+        operands.append(_rows(meta, p))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bc,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, n) + out_trailing,
-            lambda b, w, f: (b, 0) + (0,) * len(out_trailing)),
-    )
-    fn = pl.pallas_call(
-        body, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bc, n) + out_trailing, out_dtype),
-        interpret=interpret,
-    )
-    return fn(win_ids, full_flags, *operands)
+    def call(n_blocks, base, win, flags):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_blocks,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (None, 1, n) + out_trailing,
+                lambda b, base, w, f: (b, 0, 0) + (0,) * z),
+        )
+        out = pl.pallas_call(
+            body, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_blocks, 1, n) + out_trailing,
+                                           out_dtype),
+            interpret=interpret,
+        )(base, win, flags, *operands)
+        return out.reshape((n_blocks, n) + out_trailing)
+
+    chunk = _chunk_blocks(bc, ls + 1, p)
+    return _chunked(call, bc, chunk,
+                    [jnp.asarray(win_ids, jnp.int32).reshape(-1),
+                     jnp.asarray(full_flags, jnp.int32)], [ls, 1])
 
 
 # -------------------------------------------------- dense-slice (coalesced)
-def _coalesced_body(start_ref, flag_ref, *refs, combine: Callable,
+def _coalesced_body(base_ref, start_ref, flag_ref, *refs, combine: Callable,
                     gathered: tuple, elementwise: tuple, op: int,
                     mixed: bool, reduce: str, out_dtype, has_off: bool,
                     rows: int, n: int):
-    """``refs`` layout: [flat_g...] + [elem...] + [off?, seg] + [out].
-    Per row: ONE unaligned dense ``pl.ds`` slice of N words from the flat
-    padded view (the paper's vector load), then a static in-tile permute
-    when the run is strided (``local_offset``), then the shared ladder."""
+    """``refs`` layout: [lo/hi tile pair per row, per gathered array] +
+    [elem...] + [off?, seg] + [out].  Per row: the two aligned lane tiles
+    that hold the slice ``[st, st + N)`` are rotated by ``st % N`` and
+    merged by a lane mask — the paper's unaligned vector load, built from
+    aligned DMAs — then a static in-tile permute when the run is strided
+    (``local_offset``), then the shared ladder."""
+    del base_ref                 # consumed by the index maps only
     n_g = len(gathered)
     n_e = len(elementwise)
-    flat_refs = refs[:n_g]
-    elem_refs = refs[n_g: n_g + n_e]
-    off_ref = refs[n_g + n_e] if has_off else None
-    seg_ref = refs[n_g + n_e + int(has_off)]
-    out_ref = refs[-1]
+    tile_refs = refs[:2 * rows * n_g]
+    rest = refs[2 * rows * n_g:]
+    elem_refs = rest[:n_e]
+    off_ref = rest[n_e] if has_off else None
+    seg_ref = rest[n_e + int(has_off)]
+    out_ref = rest[-1]
     zero_slot = jnp.zeros((1, n), jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
     for i in range(rows):
         b = pl.program_id(0) * rows + i
-        st = start_ref[b]
+        rem = start_ref[b] % n
+        shift = (n - rem) % n
         vals = {}
         for gi, g in enumerate(gathered):
-            fr = flat_refs[gi]
-            tile = fr[(pl.ds(st, n),) + (slice(None),) * (fr.ndim - 1)]
+            lo = tile_refs[(gi * rows + i) * 2][...]
+            hi = tile_refs[(gi * rows + i) * 2 + 1][...]
+            keep = common.expand_trailing(lane < n - rem, lo.ndim)
+            tile = jnp.where(keep, pltpu.roll(lo, shift, 1),
+                             pltpu.roll(hi, shift, 1))
             if has_off:
-                # strided run: permute inside the loaded tile (one-hot
-                # select — static metadata, no memory gather)
-                vals[g] = common.permute_onehot(
-                    common.expand_trailing(tile, fr.ndim)
-                    .reshape((1, n) + fr.shape[1:]),
-                    zero_slot, off_ref[i:i + 1])
-            else:
-                vals[g] = tile                  # identity run: slice IS it
-        elem_vals = {e: elem_refs[ei][i] for ei, e in enumerate(elementwise)}
+                # strided run: permute inside the loaded tile (static
+                # metadata, no memory gather)
+                tile = common.permute_tiles([tile], zero_slot,
+                                            off_ref[i:i + 1])
+            vals[g] = tile
+        elem_vals = {e: elem_refs[ei][i:i + 1]
+                     for ei, e in enumerate(elementwise)}
         seg = seg_ref[i:i + 1]
         flag = flag_ref[b] if mixed else None
         red = _combine_lanes(vals, elem_vals, combine, seg, op, flag,
@@ -252,7 +324,7 @@ def _coalesced_body(start_ref, flag_ref, *refs, combine: Callable,
         out_ref[i:i + 1] = red.astype(out_dtype)
 
 
-def coalesced_stage_a(starts: jnp.ndarray, flat_views: dict,
+def coalesced_stage_a(starts: jnp.ndarray, gathered_views: dict,
                       elem_blocks: dict, local_off: jnp.ndarray | None,
                       seg: jnp.ndarray, *, combine: Callable,
                       gathered: tuple, elementwise: tuple, op: int,
@@ -262,16 +334,20 @@ def coalesced_stage_a(starts: jnp.ndarray, flat_views: dict,
                       rows_per_step: int = 1) -> jnp.ndarray:
     """Stage A for one COALESCED launch (``ir.coalesce_gathers``).
 
-    starts      (Bc,) int32 clamped slice bases, scalar-prefetched
-    flat_views  g -> (total, ...) flat padded view (``eng._pad_flat``)
-    local_off   (Bc, N) int32 in-tile permute, or None for identity runs
-    rows_per_step  blocks per grid step (upper bound; realized value is
-                   the largest divisor of Bc — a tuned kernel param)
+    starts          (Bc,) int32 clamped slice bases, scalar-prefetched
+    gathered_views  g -> (W, N, ...) lane-tile view — the same padded view
+                    the window form reads (``eng._pad_gathered``)
+    local_off       (Bc, N) int32 in-tile permute, or None for identity runs
+    rows_per_step   blocks per grid step (upper bound; realized value is
+                    the largest divisor of Bc — a tuned kernel param)
 
-    The legality/bitwise argument is the coalesce pass's own (DESIGN.md
-    §8/§13): the slice covers ``[base, base + N)`` of the same padded view
-    the window path reads, and every lane selects the identical word the
-    gather fetched.
+    Nothing of the size of the view is held in VMEM: each row DMAs the two
+    aligned tiles ``st // N`` and ``st // N + 1`` (clamped to the last
+    tile, which only an aligned ``st`` can reach, and then it is masked
+    out).  The legality/bitwise argument is the coalesce pass's own
+    (DESIGN.md §8/§13): the rotated pair covers ``[base, base + N)`` of
+    the same padded view the window path reads, and every lane selects
+    the identical word the gather fetched.
     """
     interpret = common.resolve_interpret(interpret)
     bc, n = seg.shape
@@ -285,39 +361,51 @@ def coalesced_stage_a(starts: jnp.ndarray, flat_views: dict,
                              op=op, mixed=mixed, reduce=reduce,
                              out_dtype=out_dtype, has_off=has_off,
                              rows=r, n=n)
+    z = len(out_trailing)
     in_specs = []
     operands = []
     for g in gathered:
-        view = flat_views[g]
-        # whole flat view resident (VMEM ceiling documented in §13); the
-        # per-row loads are unaligned N-wide pl.ds slices of it
-        in_specs.append(pl.BlockSpec(
-            view.shape, lambda b, s, f, z=view.ndim: (0,) * z))
-        operands.append(view)
+        view = _tiles(gathered_views[g])
+        last = view.shape[0] - 1
+        tz = view.ndim - 3
+        for i in range(r):
+            for hi in (0, 1):
+                def im(b, base, s, f, i=i, hi=hi, tz=tz, last=last):
+                    t = s[b * r + i] // n + hi
+                    return (jnp.minimum(t, last), 0, 0) + (0,) * tz
+                in_specs.append(pl.BlockSpec((None,) + view.shape[1:], im))
+                operands.append(view)
+    row_map = lambda b, base, s, f: (base[0] // r + b, 0, 0)  # noqa: E731
     for e in elementwise:
-        in_specs.append(
-            pl.BlockSpec((r, n), lambda b, s, f: (b, 0)))
-        operands.append(elem_blocks[e])
+        in_specs.append(pl.BlockSpec((None, r, n), row_map))
+        operands.append(_rows(elem_blocks[e], r))
     if has_off:
-        in_specs.append(pl.BlockSpec((r, n), lambda b, s, f: (b, 0)))
-        operands.append(jnp.asarray(local_off, jnp.int32))
-    in_specs.append(pl.BlockSpec((r, n), lambda b, s, f: (b, 0)))
-    operands.append(seg)
+        in_specs.append(pl.BlockSpec((None, r, n), row_map))
+        operands.append(_rows(jnp.asarray(local_off, jnp.int32), r))
+    in_specs.append(pl.BlockSpec((None, r, n), row_map))
+    operands.append(_rows(seg, r))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bc // r,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (r, n) + out_trailing,
-            lambda b, s, f: (b, 0) + (0,) * len(out_trailing)),
-    )
-    fn = pl.pallas_call(
-        body, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bc, n) + out_trailing, out_dtype),
-        interpret=interpret,
-    )
-    return fn(jnp.asarray(starts, jnp.int32), full_flags, *operands)
+    def call(n_blocks, base, st, flags):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_blocks // r,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (None, r, n) + out_trailing,
+                lambda b, base, s, f: (b, 0, 0) + (0,) * z),
+        )
+        out = pl.pallas_call(
+            body, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (n_blocks // r, r, n) + out_trailing, out_dtype),
+            interpret=interpret,
+        )(base, st, flags, *operands)
+        return out.reshape((n_blocks, n) + out_trailing)
+
+    chunk = _chunk_blocks(bc, 2, r)
+    return _chunked(call, bc, chunk,
+                    [jnp.asarray(starts, jnp.int32),
+                     jnp.asarray(full_flags, jnp.int32)], [1, 1])
 
 
 # --------------------------------------------------------- GPU (Triton)
@@ -341,13 +429,10 @@ def _gpu_body(*refs, combine: Callable, gathered: tuple,
             rest = (slice(None),) * (view.ndim - 1)
             tiles = [view[(pl.ds(win_ref[i, k], 1),) + rest]
                      for k in range(ls)]
-            if stream:
-                vals[g] = tiles[0][0]
-            else:
-                windows = jnp.concatenate(tiles, axis=0)
-                vals[g] = common.permute_onehot(
-                    windows, slot_ref[i:i + 1], off_ref[i:i + 1])
-        elem_vals = {e: elem_refs[ei][i] for ei, e in enumerate(elementwise)}
+            vals[g] = tiles[0] if stream else common.permute_tiles(
+                tiles, slot_ref[i:i + 1], off_ref[i:i + 1])
+        elem_vals = {e: elem_refs[ei][i:i + 1]
+                     for ei, e in enumerate(elementwise)}
         flag = flag_ref[i] if mixed else None
         red = _combine_lanes(vals, elem_vals, combine, seg_ref[i:i + 1],
                              op, flag, reduce)

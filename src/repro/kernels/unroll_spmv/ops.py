@@ -16,8 +16,9 @@ one-``pallas_call``-per-pattern-class list (§6.3 applies the rewrite
 only when the flags indicate a benefit).
 
 COALESCED launches (``ir.coalesce_gathers``, DESIGN.md §8) lower to the
-dense-slice kernel: one unaligned ``pl.ds`` vector load per block plus a
-static in-tile permute — no per-element gather.  Trailing lane axes (§8
+dense-slice kernel: one unaligned vector load per block (two aligned
+tile DMAs + a lane rotate) plus a static in-tile permute — no
+per-element gather.  Trailing lane axes (§8
 rank rules) flow through every form, so SpMM and the graph apps run on
 this emitter unchanged.
 
@@ -39,7 +40,7 @@ from repro.kernels import common
 from repro.kernels.unroll_spmv.kernel import class_stage_a, coalesced_stage_a
 
 
-def _term_struct(seed, mutable, elem_exec):
+def _term_struct(seed, mutable, elem_dtypes):
     """Shape/dtype of the seed's combine expression for these inputs — the
     kernel's lane/output structure: dtype (int32 for the graph semirings;
     the old hard-coded float32 silently corrupted large int values) AND
@@ -50,15 +51,19 @@ def _term_struct(seed, mutable, elem_exec):
         specs[g] = jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype)
     rank = max((s.ndim for s in specs.values()), default=1)
     for e in seed.elementwise:
-        specs[e] = jax.ShapeDtypeStruct((1,) * rank, elem_exec[e].dtype)
+        specs[e] = jax.ShapeDtypeStruct((1,) * rank, elem_dtypes[e])
     out = jax.eval_shape(seed.combine, specs)
     return out.dtype, out.shape[1:]
 
 
-def make_stage_a(plan: BlockPlan, meta, elem_exec,
+def make_stage_a(plan: BlockPlan, elem_exec,
                  interpret: bool | None = None,
                  launches: list[ir.Launch] | None = None,
                  kernel_params: dict | None = None):
+    """Stage each launch's kernel operands on the device and return
+    ``(consts, stage_a)``: ``stage_a(consts, mutable) -> (B, N, ...)``
+    lanes in exec-block order.  ``consts`` is passed in by the jitted
+    caller, never closed over (see :class:`repro.core.engine.Sweep`)."""
     seed = plan.seed
     interpret = common.resolve_interpret(interpret)
     kp = kernel_params or {}
@@ -66,34 +71,37 @@ def make_stage_a(plan: BlockPlan, meta, elem_exec,
     meta_prefetch = int(kp.get("meta_prefetch") or 1)
     if launches is None:
         launches = ir.lower(plan, backend="pallas").launches
-    # per-launch static metadata, upcast to kernel-friendly int32 once
-    launch_meta = []
+    elem_dtypes = {e: elem_exec[e].dtype for e in seed.elementwise}
+    # per-launch static metadata, upcast to kernel-friendly int32 once;
+    # each launch stages only the operands its form reads
+    consts = []
     for launch in launches:
         s = slice(launch.start, launch.stop)
         mask = launch.full_mask
-        launch_meta.append(dict(
-            win=jnp.asarray(plan.window_ids[s][:, :max(launch.ls_flag, 1)],
-                            jnp.int32),
-            slot=jnp.asarray(plan.lane_slot[s], jnp.int32),
-            off=jnp.asarray(plan.lane_offset[s], jnp.int32),
-            seg=jnp.asarray(plan.seg_ids[s], jnp.int32),
-            gidx=jnp.asarray(plan.gather_idx[s], jnp.int32),
-            starts=(None if launch.slice_starts is None
-                    else jnp.asarray(launch.slice_starts, jnp.int32)),
-            local=(None if launch.local_offset is None
-                   else jnp.asarray(launch.local_offset, jnp.int32)),
-            full=None if mask is None else jnp.asarray(mask, jnp.int32),
-        ))
+        cm = dict(seg=jnp.asarray(plan.seg_ids[s], jnp.int32),
+                  elem={e: elem_exec[e][s] for e in seed.elementwise},
+                  full=None if mask is None else jnp.asarray(mask, jnp.int32))
+        if launch.gather == ir.FALLBACK:
+            cm["gidx"] = jnp.asarray(plan.gather_idx[s], jnp.int32)
+            cm["zero"] = jnp.zeros((), jnp.int32)
+        elif launch.gather == ir.COALESCED:
+            cm["starts"] = jnp.asarray(launch.slice_starts, jnp.int32)
+            cm["local"] = (None if launch.local_offset is None
+                           else jnp.asarray(launch.local_offset, jnp.int32))
+        else:
+            cm["win"] = jnp.asarray(
+                plan.window_ids[s][:, :max(launch.ls_flag, 1)], jnp.int32)
+            cm["slot"] = jnp.asarray(plan.lane_slot[s], jnp.int32)
+            cm["off"] = jnp.asarray(plan.lane_offset[s], jnp.int32)
+        consts.append(cm)
 
-    def stage_a(mutable):
+    def stage_a(consts, mutable):
         views = {g: eng._pad_gathered(plan, jnp.asarray(mutable[g]))
                  for g in seed.gathered}
-        out_dtype, out_trailing = _term_struct(seed, mutable, elem_exec)
-        flat_views = None
+        out_dtype, out_trailing = _term_struct(seed, mutable, elem_dtypes)
         parts = []
-        for launch, cm in zip(launches, launch_meta):
-            s = slice(launch.start, launch.stop)
-            elem_blocks = {e: elem_exec[e][s] for e in seed.elementwise}
+        for launch, cm in zip(launches, consts):
+            elem_blocks = cm["elem"]
             if launch.gather == ir.FALLBACK and seed.gather_index is not None:
                 # native gather path (XLA) + in-XLA segmented reduce
                 vals = {g: jnp.asarray(mutable[g])[cm["gidx"]]
@@ -101,7 +109,7 @@ def make_stage_a(plan: BlockPlan, meta, elem_exec,
                 rank = max((v.ndim for v in vals.values()), default=2)
                 for e in seed.elementwise:
                     vals[e] = eng._expand_trailing(elem_blocks[e], rank)
-                term = seed.combine(vals)
+                term = eng.combine_rounded(seed, vals, cm["zero"])
                 red = eng.segmented_reduce(term, cm["seg"], launch.op_flag,
                                            seed.reduce)
                 if cm["full"] is not None:
@@ -113,12 +121,8 @@ def make_stage_a(plan: BlockPlan, meta, elem_exec,
                 parts.append(red)
                 continue
             if launch.gather == ir.COALESCED:
-                if flat_views is None:
-                    flat_views = {
-                        g: eng._pad_flat(plan, jnp.asarray(mutable[g]))
-                        for g in seed.gathered}
                 parts.append(coalesced_stage_a(
-                    cm["starts"], flat_views, elem_blocks, cm["local"],
+                    cm["starts"], views, elem_blocks, cm["local"],
                     cm["seg"], combine=seed.combine, gathered=seed.gathered,
                     elementwise=seed.elementwise, op=launch.op_flag,
                     reduce=seed.reduce, full_flags=cm["full"],
@@ -137,4 +141,4 @@ def make_stage_a(plan: BlockPlan, meta, elem_exec,
             return jnp.zeros((0, plan.lane_width) + out_trailing, out_dtype)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
-    return stage_a
+    return consts, stage_a

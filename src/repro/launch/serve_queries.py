@@ -67,6 +67,8 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also dump latency summary + health as JSON")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(f"[serve] building {args.app} over {args.graph} "
           f"n={args.nodes} ...")

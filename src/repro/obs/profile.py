@@ -126,10 +126,11 @@ def hlo_cost(run, mutable: dict, out_init) -> dict | None:
     """Optimized-HLO FLOPs/bytes/collectives of the live executor via
     :func:`repro.launch.hlo_analysis.analyze_hlo`.  ``None`` when the
     executor cannot produce HLO text — never raises."""
+    import jax
     from repro.launch.hlo_analysis import analyze_hlo
-    jitted = getattr(run, "jitted", None) or run
+    lower = getattr(run, "lower", None) or jax.jit(run).lower
     try:
-        hlo = jitted.lower(mutable, out_init).compile().as_text()
+        hlo = lower(mutable, out_init).compile().as_text()
         out = analyze_hlo(hlo)
     except Exception:
         return None

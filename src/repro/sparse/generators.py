@@ -31,7 +31,10 @@ class COOMatrix:
 
 
 def _finish(name, r, c, v, shape) -> COOMatrix:
-    order = np.lexsort((c, r))
+    # row-major order; a stable sort of one (row, col) key is the same
+    # permutation as ``np.lexsort((c, r))``, several times faster
+    key = np.asarray(r, np.int64) * max(int(shape[1]), 1) + c
+    order = np.argsort(key, kind="stable")
     return COOMatrix(name, r[order].astype(np.int64),
                      c[order].astype(np.int64),
                      v[order].astype(np.float32), shape)

@@ -27,6 +27,7 @@ import time
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import engine as eng
@@ -118,13 +119,21 @@ def _default_exec_factory(plan, cand: Candidate, static_data, elem_exec):
                              kernel_params=cand.kernel_params)
 
 
-def _outputs_match(got, want) -> bool:
+def _outputs_match(got, want, scale=None) -> bool:
+    """Candidate output vs the oracle.  ``scale`` (add-reductions) is the
+    same reduction over ``|term|``: a float sum's rounding grows with the
+    magnitudes it adds, not with its total, so each row is held to
+    ``rtol`` of its summed magnitudes — a row whose terms cancel would
+    otherwise fail every summation order but the oracle's own."""
     got = np.asarray(got)
     want = np.asarray(want)
     if got.shape != want.shape or got.dtype != want.dtype:
         return False
     if np.issubdtype(got.dtype, np.inexact):
-        return bool(np.allclose(got, want, rtol=1e-4, atol=1e-5))
+        rtol, atol = 1e-4, 1e-5
+        if scale is not None:
+            atol = atol + rtol * np.asarray(scale)
+        return bool(np.allclose(got, want, rtol=rtol, atol=atol))
     return bool(np.array_equal(got, want))
 
 
@@ -385,10 +394,16 @@ def _autotune_impl(seed: CodeSeed, access: dict, out_len: int,
                        for k in sorted(missing)]
         sp_rank.set(ranked=len(ranked))
 
+    scale = None
     if oracle == "reference":
         data = dict(static_data)
         data.update(mutable_example)
         oracle = reference_execute(seed, access, data, out_init)
+        if seed.reduce == "add":
+            abs_seed = dataclasses.replace(
+                seed, combine=lambda v: jnp.abs(seed.combine(v)))
+            scale = reference_execute(abs_seed, access, data,
+                                      jnp.abs(out_init))
 
     # build + warmup + oracle-check every ranked candidate, then time them
     # all round-robin so no candidate is charged for its slot in the loop.
@@ -405,7 +420,7 @@ def _autotune_impl(seed: CodeSeed, access: dict, out_len: int,
                 ok = True
                 if oracle is not None:
                     ok = _outputs_match(run(mutable_example, out_init),
-                                        oracle)
+                                        oracle, scale)
                     if not ok:
                         warnings.warn(
                             f"tuning candidate {cand.label} diverges from "

@@ -124,3 +124,17 @@ def test_empty_and_single_element():
         y = np.asarray(sp.matvec(jnp.ones(8, jnp.float32)))
         assert y[0] == pytest.approx(nnz)
         assert (y[1:] == 0).all()
+
+
+def test_generator_row_major_order_matches_lexsort():
+    """``generators._finish`` sorts by one (row, col) key; the permutation
+    must be the stable two-key ``np.lexsort`` one, duplicates included."""
+    rng = np.random.default_rng(7)
+    r = rng.integers(0, 50, 4000)
+    c = rng.integers(0, 70, 4000)
+    v = rng.standard_normal(4000)
+    m = G._finish("t", r, c, v, (50, 70))
+    order = np.lexsort((c, r))
+    np.testing.assert_array_equal(m.rows, r[order])
+    np.testing.assert_array_equal(m.cols, c[order])
+    np.testing.assert_array_equal(m.vals, v[order].astype(np.float32))

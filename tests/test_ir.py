@@ -120,6 +120,34 @@ def test_coalesce_min_run_split():
     assert len(tree.launches) == n_unco
 
 
+def test_coalesce_run_budget_caps_launch_count(monkeypatch):
+    """Irregular input interleaves many short coalescible runs with gather
+    blocks: only the MAX_COALESCE_RUNS longest per launch are carved out,
+    so the launch count does not grow with nnz, and the result stays
+    bitwise equal to the un-coalesced program."""
+    m = G.power_law(1 << 14, 16)
+    plan = _plan_for(m)
+    base = ir.lower(plan, fused=True)
+    tree = ir.lower(plan, fused=True, coalesce=True)
+    with monkeypatch.context() as mp:
+        mp.setattr(ir, "MAX_COALESCE_RUNS", 1 << 30)
+        unbounded = ir.lower(plan, fused=True, coalesce=True)
+
+    def n_co(t):
+        return sum(launch.gather == ir.COALESCED for launch in t.launches)
+    assert n_co(unbounded) > n_co(tree) > 0
+    assert n_co(tree) <= ir.MAX_COALESCE_RUNS * len(base.launches)
+    assert len(tree.launches) <= ((2 * ir.MAX_COALESCE_RUNS + 1)
+                                  * len(base.launches))
+    _assert_partition(tree.launches, plan.num_blocks)
+    x = {"x": jnp.asarray(np.random.default_rng(0).standard_normal(
+        m.shape[1]).astype(np.float32))}
+    y0 = jnp.zeros(m.shape[0], jnp.float32)
+    ys = [np.asarray(eng.make_executor(plan, {"value": m.vals}, tree=t)(
+        x, y0)) for t in (base, tree)]
+    np.testing.assert_array_equal(ys[0], ys[1])
+
+
 def test_coalesced_fraction_reach():
     """The pass's benchmark-visible reach: full on banded/dense stripes,
     zero on unstructured random."""

@@ -113,11 +113,10 @@ def test_unroll_spmv_stage_a_vs_ref():
                       out_len=m.shape[0], data_len=m.shape[1],
                       cost=CostModel(lane_width=n))
     elem_exec = {"value": eng.reorder_elementwise(plan, np.asarray(m.vals))}
-    meta = {}
-    stage_a = kops.make_stage_a(plan, meta, elem_exec, interpret=True)
+    consts, stage_a = kops.make_stage_a(plan, elem_exec, interpret=True)
     rng = np.random.default_rng(0)
     x = rng.standard_normal(m.shape[1]).astype(np.float32)
-    lanes = np.asarray(stage_a({"x": jnp.asarray(x)}))
+    lanes = np.asarray(stage_a(consts, {"x": jnp.asarray(x)}))
 
     ref = spmv_ref.stage_a_reference(
         plan.gather_idx, plan.seg_ids, {"x": x},

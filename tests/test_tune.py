@@ -136,6 +136,25 @@ def test_every_measured_candidate_matches_oracle():
                                atol=1e-5)
 
 
+def test_oracle_check_accepts_a_cancelling_heavy_row():
+    """A float sum's rounding grows with the magnitudes it adds, not with
+    its total: a heavy row whose terms cancel differs between summation
+    orders by far more than ``rtol * |total|``.  Every correct candidate
+    must still pass the oracle check (power-law rows at 10^6 rows hit
+    this on every candidate)."""
+    rows, cols, vals, out_len, data_len = _coo(5)
+    a = (1000.0 * np.random.default_rng(6).standard_normal(2048)
+         ).astype(np.float32)
+    heavy = np.arange(2048) % data_len
+    rows = np.concatenate([rows, np.zeros(4096, rows.dtype)])
+    cols = np.concatenate([cols, heavy, heavy])
+    vals = np.concatenate([vals, a, -a])
+    (_, _, result), _ = _autotune_spmv(rows, cols, vals, out_len, data_len)
+    assert result.picked_by == "measurement"
+    assert all(m.ok for m in result.measurements), \
+        [m.candidate.label for m in result.measurements if not m.ok]
+
+
 def test_warm_cache_hit_performs_zero_measurements(tmp_path):
     rows, cols, vals, out_len, data_len = _coo(2)
     d = str(tmp_path)
