@@ -1,0 +1,6 @@
+"""Percent of the traced window in which no operation ran on the device,
+in a matvec cell."""
+
+
+def read(ctx):
+    return 100.0 * ctx.trace.idle_share
