@@ -1,0 +1,8 @@
+"""Device milliseconds a matvec spends in the window kernel: own time of
+the ops under the program's ``stage_a.window`` scope in the traced
+window (``bench/scope_reduce.py``), over the matvecs completed."""
+from bench import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.per_call_ms(ctx, "stage_a.window", ctx.completed)

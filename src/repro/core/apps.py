@@ -199,13 +199,14 @@ class SpMV:
 
     def matvec(self, x: jnp.ndarray, y_init: jnp.ndarray | None = None
                ) -> jnp.ndarray:
-        if y_init is None:
-            key = np.dtype(x.dtype).str
-            y_init = self._y0.get(key)
+        with _trace.span("spmv.matvec"):
             if y_init is None:
-                y_init = self._y0[key] = jnp.zeros(self.shape[0],
-                                                   dtype=x.dtype)
-        return self._run({"x": x}, y_init)
+                key = np.dtype(x.dtype).str
+                y_init = self._y0.get(key)
+                if y_init is None:
+                    y_init = self._y0[key] = jnp.zeros(self.shape[0],
+                                                       dtype=x.dtype)
+            return self._run({"x": x}, y_init)
 
     def matvec_many(self, xs, bucket: bool = True) -> jnp.ndarray:
         """Batched matvec: ONE vmapped dispatch over ``S`` stacked input

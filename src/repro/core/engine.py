@@ -57,6 +57,7 @@ double-buffer in place instead of allocating a fresh output per call.
 """
 from __future__ import annotations
 
+import time
 from typing import Mapping
 
 import jax
@@ -68,6 +69,7 @@ from repro.core import ir
 from repro.core.plan import BlockPlan
 from repro.core.seed import (CodeSeed, reduce_identity_for,
                              reference_execute)
+from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
 # lowering helpers re-exported for callers that inspect launch lists
@@ -78,6 +80,18 @@ section_full_mask = ir.section_full_mask
 _FUSE_MIN_CLASSES = ir.FUSE_MIN_CLASSES
 
 _SEG_PAD = -(2 ** 30)
+
+# the device scope (repro.obs.trace) each launch kind's ops run under
+_LAUNCH_SCOPES = {ir.FALLBACK: _trace.SCOPE_FALLBACK,
+                  ir.WINDOW: _trace.SCOPE_WINDOW,
+                  ir.STREAM: _trace.SCOPE_WINDOW,
+                  ir.COALESCED: _trace.SCOPE_COALESCED}
+
+
+def launch_scope(launch: ir.Launch):
+    """``jax.named_scope`` of a launch's kind: its ops' device time is
+    read by that name (DESIGN.md §11)."""
+    return jax.named_scope(_LAUNCH_SCOPES[launch.gather])
 
 
 def _padded_view_len(data_len: int, n: int) -> int:
@@ -297,18 +311,20 @@ def _stage_a_jax(plan: BlockPlan, meta, elem_exec, mutable,
     parts = []
     for i, launch in enumerate(launches):
         s = slice(launch.start, launch.stop)
-        vals = _gather_launch_values(plan, launch, s, meta, mutable,
-                                     co_meta.get(i))
-        rank = max((v.ndim for v in vals.values()), default=2)
-        for e in seed.elementwise:
-            vals[e] = _expand_trailing(elem_exec[e][s], rank)
-        term = combine_rounded(seed, vals, meta["zero"])
-        red = segmented_reduce(term, meta["seg_ids"][s], launch.op_flag,
-                               seed.reduce)
+        with launch_scope(launch):
+            vals = _gather_launch_values(plan, launch, s, meta, mutable,
+                                         co_meta.get(i))
+            rank = max((v.ndim for v in vals.values()), default=2)
+            for e in seed.elementwise:
+                vals[e] = _expand_trailing(elem_exec[e][s], rank)
+            term = combine_rounded(seed, vals, meta["zero"])
+            red = segmented_reduce(term, meta["seg_ids"][s],
+                                   launch.op_flag, seed.reduce)
         parts.append(red)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
+@jax.named_scope(_trace.SCOPE_STAGE_B)
 def _stage_b(plan: BlockPlan, meta, lanes: jnp.ndarray,
              out_init: jnp.ndarray, depth: int = 0) -> jnp.ndarray:
     """Merged write-back (Fig. 4): one RMW per distinct (block, row) head.
@@ -377,6 +393,7 @@ def dense_head_rows(plan: BlockPlan) -> np.ndarray:
     return rows.astype(np.int32)
 
 
+@jax.named_scope(_trace.SCOPE_STAGE_B)
 def _stage_b_dense(plan: BlockPlan, meta, lanes: jnp.ndarray,
                    out_init: jnp.ndarray, depth: int = 0) -> jnp.ndarray:
     """Fused write-back: scatter the whole post-reduce lane stream through
@@ -465,6 +482,21 @@ def _stage_meta(plan: BlockPlan, launches: list[ir.Launch]) -> dict:
             if k in keys}
 
 
+def _record_nnz(trees) -> None:
+    """Set the ``engine.nnz.{window,coalesced,fallback}`` gauges: the
+    valid (unpadded) nonzeros of the trees' launches of each kind.  The
+    segsum form runs every block through its one per-element gather."""
+    nnz = {"window": 0, "coalesced": 0, "fallback": 0}
+    for tree in trees:
+        valid = tree.plan.valid
+        for launch in tree.launches:
+            kind = ("fallback" if tree.backend == "segsum" else
+                    _LAUNCH_SCOPES[launch.gather].rpartition(".")[2])
+            nnz[kind] += int(valid[launch.start:launch.stop].sum())
+    for kind, n in nnz.items():
+        _metrics.set_gauge(f"engine.nnz.{kind}", n)
+
+
 @_trace.traced("engine.build_sweeper")
 def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
                  backend: str = "jax", interpret: bool | None = None,
@@ -481,6 +513,8 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
     (:func:`repro.core.ir.lower` — fuse/stage-B/coalesce passes per the
     ``fused`` / ``stage_b`` / ``coalesce`` toggles); the emitter below
     walks the lowered launch list and makes no lowering decisions itself.
+    Each build is observed in the ``engine.build_seconds`` histogram and
+    sets the ``engine.nnz.*`` gauges.
 
     All host-side constants (reordered elementwise arrays, lane metadata,
     write-back structure, coalesced slice bases) are staged to the device
@@ -494,6 +528,16 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
     — the emission path of the partitioned per-shard subtrees
     (:func:`repro.core.ir.partition_plan`), whose launch lists were
     sliced, not re-lowered."""
+    t0 = time.perf_counter()
+    sweep = _emit_sweeper(plan, static_data, backend, interpret, fused,
+                          stage_b, elem_exec, coalesce, tree, kernel_params)
+    _metrics.observe("engine.build_seconds", time.perf_counter() - t0)
+    _record_nnz([sweep.tree])
+    return sweep
+
+
+def _emit_sweeper(plan, static_data, backend, interpret, fused, stage_b,
+                  elem_exec, coalesce, tree, kernel_params) -> Sweep:
     seed = plan.seed
     if tree is None:
         tree = ir.lower(plan, backend=backend, fused=fused,
@@ -571,16 +615,21 @@ def make_sweeper(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
         fold = REDUCE_OPS[seed.reduce][0]
 
         def run_ss(c, mutable, out_init):
-            vals = {}
-            for g in seed.gathered:
-                vals[g] = jnp.asarray(mutable[g])[c["gidx"]]
-            rank = max((v.ndim for v in vals.values()), default=1)
-            for e in seed.elementwise:
-                vals[e] = _expand_trailing(c["elem"][e].reshape(-1), rank)
-            term = seed.combine(vals)
-            red = seg_reduce(term, c["rows"],
-                             num_segments=plan.out_len + 1)
-            return fold(out_init, red[:plan.out_len])
+            # one per-element gather + segment reduce: the fallback idiom
+            # for every block, its write-back the fold into out_init
+            with jax.named_scope(_trace.SCOPE_FALLBACK):
+                vals = {}
+                for g in seed.gathered:
+                    vals[g] = jnp.asarray(mutable[g])[c["gidx"]]
+                rank = max((v.ndim for v in vals.values()), default=1)
+                for e in seed.elementwise:
+                    vals[e] = _expand_trailing(c["elem"][e].reshape(-1),
+                                               rank)
+                term = seed.combine(vals)
+                red = seg_reduce(term, c["rows"],
+                                 num_segments=plan.out_len + 1)
+            with jax.named_scope(_trace.SCOPE_STAGE_B):
+                return fold(out_init, red[:plan.out_len])
         return Sweep(run_ss, consts, tree)
 
     if backend == "pallas":
@@ -666,7 +715,7 @@ def _executor(body: Sweep, donate: bool, **span_attrs):
     jitted = jax.jit(body.apply, donate_argnums=(2,) if donate else ())
 
     def run(mutable, out_init):
-        if not _trace.enabled():
+        if not _trace.active():
             return jitted(body.consts, mutable, out_init)
         first = not run._called
         run._called = True
@@ -763,6 +812,7 @@ def shard_sweep_bodies(parts, static_data) -> list[Sweep | None]:
             continue
         bodies.append(make_sweeper(p.tree.plan, static_data,
                                    backend=p.tree.backend, tree=p.tree))
+    _record_nnz([p.tree for p in parts])
     return bodies
 
 
@@ -874,10 +924,12 @@ def make_sharded_fixpoint_step(parts, static_data, mesh, state_key: str,
             return new
         # device-side convergence via psum of the per-shard verdicts —
         # both scalars replicate across the axis
-        changed_here = jnp.logical_not(jnp.array_equal(new, block))
-        changed = jax.lax.psum(changed_here.astype(jnp.int32), axis) > 0
-        sick_here = jnp.logical_not(state_healthy(new, reduce))
-        healthy = jax.lax.psum(sick_here.astype(jnp.int32), axis) == 0
+        with jax.named_scope(_trace.SCOPE_FIXPOINT_CHECK):
+            changed_here = jnp.logical_not(jnp.array_equal(new, block))
+            changed = jax.lax.psum(changed_here.astype(jnp.int32),
+                                   axis) > 0
+            sick_here = jnp.logical_not(state_healthy(new, reduce))
+            healthy = jax.lax.psum(sick_here.astype(jnp.int32), axis) == 0
         return new, changed, healthy
 
     out_specs = (_PS(axis), _PS(), _PS()) if with_convergence \

@@ -331,17 +331,20 @@ class _FixpointApp:
                 def body(carry):
                     state, count, _changed, _healthy = carry
                     new = step(c, state)
-                    return (new, count + jnp.int32(1),
-                            jnp.logical_not(jnp.array_equal(new, state)),
-                            eng.state_healthy(new, reduce))
+                    with jax.named_scope(_trace.SCOPE_FIXPOINT_CHECK):
+                        changed = jnp.logical_not(
+                            jnp.array_equal(new, state))
+                        healthy = eng.state_healthy(new, reduce)
+                    return new, count + jnp.int32(1), changed, healthy
 
                 # the health flag rides the carry: a NaN-poisoned state
                 # can never pass the equality check (NaN != NaN), so
                 # without it the loop silently burns max_sweeps.  For
                 # integer states state_healthy folds to a trace-time
                 # constant True — the int apps pay nothing.
-                init = (state, jnp.int32(0), jnp.bool_(True),
-                        eng.state_healthy(state, reduce))
+                with jax.named_scope(_trace.SCOPE_FIXPOINT_CHECK):
+                    healthy = eng.state_healthy(state, reduce)
+                init = (state, jnp.int32(0), jnp.bool_(True), healthy)
                 final, count, changed, healthy = jax.lax.while_loop(
                     cond, body, init)
                 return final, count, changed, healthy
@@ -388,8 +391,9 @@ class _FixpointApp:
                 # health check over the padded block equals the full-state
                 # check: zeros are finite and never the wrong-direction
                 # infinity state_healthy rejects
-                init = (padded, jnp.int32(0), jnp.bool_(True),
-                        eng.state_healthy(padded, reduce))
+                with jax.named_scope(_trace.SCOPE_FIXPOINT_CHECK):
+                    healthy = eng.state_healthy(padded, reduce)
+                init = (padded, jnp.int32(0), jnp.bool_(True), healthy)
                 return jax.lax.while_loop(cond, body, init)
 
             jfn = jax.jit(converge)
@@ -472,20 +476,15 @@ class _FixpointApp:
                 "batched multi-source runs are not supported on a sharded "
                 "app (vmap over shard_map); build without mesh=/shards= "
                 "for run_multi")
-        if driver == "resident" and self._shard_parts:
-            fn = self._resident_converge_sharded()
-            final, count, changed, healthy = fn(
-                state, jnp.asarray(max_sweeps, jnp.int32))
-            self.convergence = self._report(int(count), bool(changed),
-                                            bool(healthy), max_sweeps)
-            return final
         if driver == "resident":
-            fn = self._resident_converge(batched)
+            fn = (self._resident_converge_sharded() if self._shard_parts
+                  else self._resident_converge(batched))
             final, count, changed, healthy = fn(
                 state, jnp.asarray(max_sweeps, jnp.int32))
             # the ONE host sync of the whole run
-            self.convergence = self._report(int(count), bool(changed),
-                                            bool(healthy), max_sweeps)
+            with _trace.span("graphs.converge.sync"):
+                self.convergence = self._report(int(count), bool(changed),
+                                                bool(healthy), max_sweeps)
             return final
         if driver != "host":
             raise ValueError(f"unknown driver {driver!r}; "
@@ -655,10 +654,13 @@ class BFS(_FixpointApp):
 
     def run(self, source: int, max_sweeps: int | None = None) -> np.ndarray:
         """Levels from ``source`` (int32; -1 where unreachable)."""
-        state = self._init_levels(np.asarray([source]))[0]
-        state = self._converge(state, max_sweeps)
-        lv = np.asarray(state)
-        return np.where(lv >= UNREACHED, -1, lv).astype(np.int32)
+        with _trace.span("bfs.run"):
+            with _trace.span("bfs.init"):
+                state = self._init_levels(np.asarray([source]))[0]
+            state = self._converge(state, max_sweeps)
+            with _trace.span("bfs.fetch"):
+                lv = np.asarray(state)
+                return np.where(lv >= UNREACHED, -1, lv).astype(np.int32)
 
     def run_multi(self, sources, max_sweeps: int | None = None,
                   bucket: bool = True) -> np.ndarray:

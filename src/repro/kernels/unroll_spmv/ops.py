@@ -95,48 +95,52 @@ def make_stage_a(plan: BlockPlan, elem_exec,
             cm["off"] = jnp.asarray(plan.lane_offset[s], jnp.int32)
         consts.append(cm)
 
+    def launch_lanes(launch, cm, views, mutable, out_dtype, out_trailing):
+        elem_blocks = cm["elem"]
+        if launch.gather == ir.FALLBACK and seed.gather_index is not None:
+            # native gather path (XLA) + in-XLA segmented reduce
+            vals = {g: jnp.asarray(mutable[g])[cm["gidx"]]
+                    for g in seed.gathered}
+            rank = max((v.ndim for v in vals.values()), default=2)
+            for e in seed.elementwise:
+                vals[e] = eng._expand_trailing(elem_blocks[e], rank)
+            term = eng.combine_rounded(seed, vals, cm["zero"])
+            red = eng.segmented_reduce(term, cm["seg"], launch.op_flag,
+                                       seed.reduce)
+            if cm["full"] is not None:
+                native = eng.segmented_reduce(
+                    term, cm["seg"], eng.ft.FULL_REDUCE, seed.reduce)
+                red = jnp.where(
+                    eng._expand_trailing((cm["full"] != 0)[:, None],
+                                         term.ndim), native, red)
+            return red
+        if launch.gather == ir.COALESCED:
+            return coalesced_stage_a(
+                cm["starts"], views, elem_blocks, cm["local"],
+                cm["seg"], combine=seed.combine, gathered=seed.gathered,
+                elementwise=seed.elementwise, op=launch.op_flag,
+                reduce=seed.reduce, full_flags=cm["full"],
+                out_dtype=out_dtype, out_trailing=out_trailing,
+                interpret=interpret, rows_per_step=rows_per_step)
+        return class_stage_a(
+            cm["win"], views, elem_blocks, cm["slot"], cm["off"],
+            cm["seg"], combine=seed.combine, gathered=seed.gathered,
+            elementwise=seed.elementwise, ls=max(launch.ls_flag, 1),
+            op=launch.op_flag, stream=launch.stream, reduce=seed.reduce,
+            full_flags=cm["full"], out_dtype=out_dtype,
+            out_trailing=out_trailing, interpret=interpret,
+            meta_prefetch=meta_prefetch)
+
     def stage_a(consts, mutable):
         views = {g: eng._pad_gathered(plan, jnp.asarray(mutable[g]))
                  for g in seed.gathered}
         out_dtype, out_trailing = _term_struct(seed, mutable, elem_dtypes)
         parts = []
         for launch, cm in zip(launches, consts):
-            elem_blocks = cm["elem"]
-            if launch.gather == ir.FALLBACK and seed.gather_index is not None:
-                # native gather path (XLA) + in-XLA segmented reduce
-                vals = {g: jnp.asarray(mutable[g])[cm["gidx"]]
-                        for g in seed.gathered}
-                rank = max((v.ndim for v in vals.values()), default=2)
-                for e in seed.elementwise:
-                    vals[e] = eng._expand_trailing(elem_blocks[e], rank)
-                term = eng.combine_rounded(seed, vals, cm["zero"])
-                red = eng.segmented_reduce(term, cm["seg"], launch.op_flag,
-                                           seed.reduce)
-                if cm["full"] is not None:
-                    native = eng.segmented_reduce(
-                        term, cm["seg"], eng.ft.FULL_REDUCE, seed.reduce)
-                    red = jnp.where(
-                        eng._expand_trailing((cm["full"] != 0)[:, None],
-                                             term.ndim), native, red)
-                parts.append(red)
-                continue
-            if launch.gather == ir.COALESCED:
-                parts.append(coalesced_stage_a(
-                    cm["starts"], views, elem_blocks, cm["local"],
-                    cm["seg"], combine=seed.combine, gathered=seed.gathered,
-                    elementwise=seed.elementwise, op=launch.op_flag,
-                    reduce=seed.reduce, full_flags=cm["full"],
-                    out_dtype=out_dtype, out_trailing=out_trailing,
-                    interpret=interpret, rows_per_step=rows_per_step))
-                continue
-            parts.append(class_stage_a(
-                cm["win"], views, elem_blocks, cm["slot"], cm["off"],
-                cm["seg"], combine=seed.combine, gathered=seed.gathered,
-                elementwise=seed.elementwise, ls=max(launch.ls_flag, 1),
-                op=launch.op_flag, stream=launch.stream, reduce=seed.reduce,
-                full_flags=cm["full"], out_dtype=out_dtype,
-                out_trailing=out_trailing, interpret=interpret,
-                meta_prefetch=meta_prefetch))
+            # each launch's ops run under its kind's device scope
+            with eng.launch_scope(launch):
+                parts.append(launch_lanes(launch, cm, views, mutable,
+                                          out_dtype, out_trailing))
         if not parts:      # empty plan (nnz == 0): no launches, no lanes
             return jnp.zeros((0, plan.lane_width) + out_trailing, out_dtype)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)

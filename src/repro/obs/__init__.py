@@ -7,7 +7,8 @@ hot path (<1% overhead, pinned by ``tests/test_obs.py``), so there is no
 "instrumented build" vs "fast build" split to keep in sync.
 
 ``repro.obs`` is a leaf package: it imports only the standard library
-(``obs.profile`` lazily reaches into :mod:`repro.launch.hlo_analysis`),
+and JAX's profiler (``obs.profile`` lazily reaches into
+:mod:`repro.launch.hlo_analysis`),
 so every layer of the pipeline — validate, plan, planio, ir, engine,
 tune, graphs, apps — can depend on it without cycles.
 """

@@ -5,6 +5,9 @@ Absorbs the ad-hoc counters that grew across the pipeline
 the ones the caches and degradation paths never had:
 
 * ``plan.builds`` / ``plan.build_seconds`` — feature-analysis runs
+* ``engine.build_seconds`` — executor builds (IR lowering, reorder,
+  device staging); gauges ``engine.nnz.{window,coalesced,fallback}`` —
+  the valid nonzeros of the last built executor's launches of each kind
 * ``plan_cache.{hit,miss,corrupt,write_failed,store}`` — planio rungs
 * ``tune_cache.{hit,miss,corrupt,write_failed,store}`` — tuner cache
 * ``tune.measurements`` / ``tune.candidate_us`` — measured rounds and

@@ -1,4 +1,5 @@
-"""Nestable, thread-local spans over the lowering pipeline.
+"""Nestable, thread-local spans over the lowering pipeline, on the
+profiler's clock.
 
 A *span* is one timed region with a name, attributes, and a parent — the
 pipeline opens them around plan builds, validation, cache lookups, IR
@@ -8,33 +9,60 @@ build → validate → lower(per-pass) → tune → execute.
 
 Design constraints (DESIGN.md §11):
 
-* **Disabled is free.**  Tracing is off by default; ``span()`` then
-  returns a shared singleton no-op context manager — no object is
-  allocated, no clock is read, no lock is taken.  The pinned perf test
-  holds the instrumented 1M-nnz plan build under 1% overhead.
-* **Thread-local nesting, process-global record.**  Each thread keeps
-  its own open-span stack (the tuner and the serving layer run builds
-  concurrently), finished spans land in one process-wide list so a
-  single export sees every thread.
-* **Two exports.**  :func:`to_chrome_trace` emits Chrome/Perfetto
-  trace-event JSON (``ph: "X"`` complete events, microsecond
-  timestamps); :func:`tree_dump` renders the same records as an
-  indented text tree for terminals and test failures.
+* **Disabled is free.**  With tracing off and no profiler session
+  collecting, ``span()`` returns a shared singleton no-op context
+  manager — no object is allocated, no clock is read, no lock is taken
+  (one C++ flag read asks the profiler).  The pinned perf test holds the
+  instrumented 1M-nnz plan build under 1% overhead.
+* **One clock.**  While a JAX profiler session collects
+  (``jax.profiler.trace(dir)``), every span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so the profile holds the
+  program's host spans beside the device operations, on the device
+  trace's clock.  That profile is the export: open it in TensorBoard's
+  profile plugin or Perfetto.
+* **Thread-local nesting, process-global record.**  With tracing on,
+  each thread keeps its own open-span stack (the tuner and the serving
+  layer run builds concurrently); finished spans land in one
+  process-wide list (:func:`finished_spans`), which degradation events
+  and tests read, and :func:`tree_dump` renders as an indented text tree.
 
-Enable with ``trace.enable()`` or ``REPRO_TRACE=1`` in the environment.
+Enable the records with ``trace.enable()`` or ``REPRO_TRACE=1`` in the
+environment.
+
+**Device scopes.**  The engine wraps each launch kind of a sweep program
+in ``jax.named_scope`` with one of the names below
+(:data:`DEVICE_SCOPES`).  A scope only adds metadata: the name reaches
+each HLO op's ``op_name`` and, on the chip, the ``tf_op`` stat of the
+op's events in the device trace, where device time is read by scope.
+XLA gives an op that it fuses from ops of two scopes the ``op_name`` of
+the fusion's root, so such an op counts under the root's scope.
 """
 from __future__ import annotations
 
 import functools
-import json
 import os
 import threading
 import time
 
-__all__ = ["enable", "disable", "enabled", "reset", "span", "traced",
-           "current_span_id", "open_spans", "finished_spans",
-           "to_chrome_trace", "export_chrome_trace", "tree_dump",
-           "SpanRecord"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["enable", "disable", "enabled", "active", "reset", "span",
+           "traced", "current_span_id", "open_spans", "finished_spans",
+           "tree_dump", "SpanRecord", "DEVICE_SCOPES", "SCOPE_WINDOW",
+           "SCOPE_COALESCED", "SCOPE_FALLBACK", "SCOPE_STAGE_B",
+           "SCOPE_FIXPOINT_CHECK"]
+
+# device scopes, one per launch kind of a sweep program (module docstring)
+SCOPE_WINDOW = "stage_a.window"        # window and stream tile loads
+SCOPE_COALESCED = "stage_a.coalesced"  # dense-slice loads
+SCOPE_FALLBACK = "stage_a.fallback"    # per-element gather + its reduce
+SCOPE_STAGE_B = "stage_b"              # the write-back, every form
+SCOPE_FIXPOINT_CHECK = "fixpoint.check"  # resident loop's equality/health
+DEVICE_SCOPES = (SCOPE_WINDOW, SCOPE_COALESCED, SCOPE_FALLBACK,
+                 SCOPE_STAGE_B, SCOPE_FIXPOINT_CHECK)
+
+# C++ flag read: is a profiler session collecting host annotations?
+_profiling = TraceAnnotation.is_enabled
 
 _enabled = os.environ.get("REPRO_TRACE", "").lower() not in (
     "", "0", "false", "off")
@@ -79,11 +107,13 @@ def _stack() -> list:
 class _Span:
     """A live (open) span; becomes a :class:`SpanRecord` on exit."""
 
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "start_ns")
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "start_ns",
+                 "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
+        self._annotation = None
 
     def set(self, **attrs) -> "_Span":
         self.attrs.update(attrs)
@@ -97,11 +127,16 @@ class _Span:
         stack = _stack()
         self.parent_id = stack[-1].span_id if stack else None
         stack.append(self)
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end_ns = time.perf_counter_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         stack = _stack()
         # tolerate imbalance (a leaked child) rather than corrupting the
         # stack: pop self specifically
@@ -140,25 +175,47 @@ class _NopSpan:
 _NOP = _NopSpan()
 
 
+class _AnnotationSpan:
+    """A span with tracing off while a profiler session collects: the
+    profiler's annotation alone, with no record."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, name: str):
+        self._annotation = TraceAnnotation(name)
+
+    def set(self, **attrs) -> "_AnnotationSpan":
+        return self
+
+    def __enter__(self) -> "_AnnotationSpan":
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
 def span(name: str, **attrs):
     """Open a span.  Use as ``with trace.span("plan.build", nnz=n) as sp:``
     and add result attributes via ``sp.set(...)`` before the block exits.
-    When tracing is disabled this returns a shared no-op singleton."""
+    With tracing disabled this returns a shared no-op singleton, or,
+    while a profiler session collects, the profiler's annotation alone."""
     if not _enabled:
-        return _NOP
+        return _AnnotationSpan(name) if _profiling() else _NOP
     return _Span(name, attrs)
 
 
 def traced(name: str, **static_attrs):
     """Decorator form of :func:`span` for functions whose whole body is
     one region (validators, app constructors).  The disabled path is a
-    single module-global check before delegating."""
+    module-global check and a profiler flag read before delegating."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            if not _enabled:
+            if not (_enabled or _profiling()):
                 return fn(*args, **kwargs)
-            with _Span(name, dict(static_attrs)):
+            with span(name, **static_attrs):
                 return fn(*args, **kwargs)
         return wrapper
     return deco
@@ -177,6 +234,12 @@ def disable() -> None:
 
 def enabled() -> bool:
     return _enabled
+
+
+def active() -> bool:
+    """Would :func:`span` record or annotate?  Hot paths test this before
+    they build a span's attributes."""
+    return _enabled or _profiling()
 
 
 def reset() -> None:
@@ -209,43 +272,7 @@ def finished_spans() -> list[SpanRecord]:
         return list(_finished)
 
 
-# -------------------------------------------------------------- exports
-def _jsonable(v):
-    if isinstance(v, (str, int, float, bool)) or v is None:
-        return v
-    try:  # numpy scalars
-        return v.item()
-    except AttributeError:
-        return str(v)
-
-
-def to_chrome_trace() -> dict:
-    """Chrome/Perfetto trace-event JSON: one ``ph: "X"`` complete event
-    per finished span (load the file at ui.perfetto.dev or
-    chrome://tracing)."""
-    pid = os.getpid()
-    events = []
-    for rec in finished_spans():
-        events.append({
-            "name": rec.name,
-            "cat": rec.name.split(".", 1)[0],
-            "ph": "X",
-            "ts": rec.start_ns / 1e3,          # microseconds
-            "dur": rec.duration_ns / 1e3,
-            "pid": pid,
-            "tid": rec.thread_id,
-            "args": {k: _jsonable(v) for k, v in rec.attrs.items()},
-        })
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def export_chrome_trace(path: str) -> str:
-    with open(path, "w") as f:
-        json.dump(to_chrome_trace(), f, indent=1, sort_keys=True)
-        f.write("\n")
-    return path
-
-
+# --------------------------------------------------------------- export
 def tree_dump() -> str:
     """Plain-text span tree (per thread, chronological)."""
     recs = finished_spans()
@@ -259,7 +286,7 @@ def tree_dump() -> str:
     lines: list[str] = []
 
     def walk(rec: SpanRecord, depth: int) -> None:
-        attrs = " ".join(f"{k}={_jsonable(v)}" for k, v in rec.attrs.items())
+        attrs = " ".join(f"{k}={v}" for k, v in rec.attrs.items())
         lines.append(f"{'  ' * depth}{rec.name}  "
                      f"{rec.duration_ns / 1e6:.3f}ms"
                      f"{('  [' + attrs + ']') if attrs else ''}")

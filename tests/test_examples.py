@@ -42,20 +42,24 @@ def test_example_runs_clean(name):
 def test_telemetry_example_writes_valid_artifacts(tmp_path):
     # telemetry.py takes its artifact paths as argv so the test (and CI)
     # control where the trace/report land.
+    import glob
     import json
-    trace_path = tmp_path / "trace.json"
+    from jax.profiler import ProfileData
+    trace_dir = tmp_path / "trace"
     report_path = tmp_path / "report.json"
-    proc = _run_example("telemetry.py", str(trace_path), str(report_path))
+    proc = _run_example("telemetry.py", str(trace_dir), str(report_path))
     assert proc.returncode == 0, (
         f"examples/telemetry.py failed (rc={proc.returncode})\n"
         f"--- stdout ---\n{proc.stdout[-2000:]}\n"
         f"--- stderr ---\n{proc.stderr[-2000:]}")
     assert "OK" in proc.stdout
-    payload = json.loads(trace_path.read_text())
-    assert payload["traceEvents"]
-    names = {ev["name"] for ev in payload["traceEvents"]}
-    assert {"app.spmv.build", "plan.build", "ir.lower",
-            "tune.autotune", "engine.execute"} <= names
+    (xplane,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                              / "*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(xplane).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"app.spmv.build", "plan.build", "ir.lower", "tune.autotune",
+            "engine.execute", "spmv.matvec"} <= names
     report = json.loads(report_path.read_text())
     assert report["launches"] and report["totals"]["flops"] > 0
 

@@ -10,20 +10,29 @@ The contracts pinned here:
 * disabled tracing produces ZERO spans and its no-op machinery costs
   under 1% of a 1M-nnz plan build (the pinned perf bound, generous);
 * a tracing-enabled ``backend="auto"`` SpMV build produces a span tree
-  covering build -> validate -> lower(per-pass) -> tune -> execute and
-  exports valid Chrome/Perfetto trace-event JSON;
+  covering build -> validate -> lower(per-pass) -> tune -> execute, and
+  under a JAX profiler session the same spans land in the profile as
+  host events, beside the device operations;
+* with tracing off, a profiler session alone still sees the hot-path
+  spans (matvec, executor call, BFS steps), and with neither, ``span()``
+  is the shared no-op;
+* every executor build reports its nonzeros per launch kind
+  (``engine.nnz.*``, summing to the plan's nnz) and its build time;
 * ``app.report()`` returns a serializable RunReport with per-launch
   flops/bytes attribution and per-pass launch deltas;
 * bench provenance drift fails ``check_regression`` with the distinct
   exit code 4 unless ``--allow-env-drift``.
 """
 import errno
+import glob
 import json
+import os
 import time
 
 import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.core.apps import PageRank, SpMV
 from repro.core.plan import build_plan
@@ -41,6 +50,16 @@ def _clean_trace():
     yield
     trace.disable()
     trace.reset()
+
+
+def _host_event_names(trace_dir) -> set:
+    """Names of the host events in the one profile under ``trace_dir``."""
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    return {ev.name for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
 
 
 def _coo(n=60, nnz=400, seed=0):
@@ -84,6 +103,59 @@ def test_disabled_tracing_adds_zero_spans():
     assert trace.current_span_id() is None
 
 
+def test_disabled_span_is_shared_noop():
+    assert not trace.active()
+    a, b = trace.span("a", x=1), trace.span("b")
+    assert a is b
+    with a as sp:
+        assert sp.set(y=2) is sp
+    assert trace.finished_spans() == []
+    assert trace.open_spans() == []
+
+
+def test_profiler_session_sees_hot_path_spans(tmp_path):
+    """Tracing off: a profiler session alone puts the program's hot-path
+    spans in the profile, and no span record is kept."""
+    from repro.core.graphs import BFS
+    rows, cols, vals, shape = _coo()
+    sp = SpMV.from_coo(rows, cols, vals, shape)
+    bfs = BFS.from_edges(np.array([0, 1, 2, 3]), np.array([1, 2, 3, 0]), 4)
+    with jax.profiler.trace(str(tmp_path)):
+        assert trace.active()
+        jax.block_until_ready(sp.matvec(np.zeros(shape[1], np.float32)))
+        assert bfs.run(0).tolist() == [0, 1, 2, 3]
+    assert not trace.active()
+    host = _host_event_names(tmp_path)
+    for name in ("spmv.matvec", "engine.execute", "bfs.run", "bfs.init",
+                 "graphs.converge", "graphs.converge.sync", "bfs.fetch"):
+        assert name in host, f"missing host event {name}"
+    assert trace.finished_spans() == []
+
+
+@pytest.mark.parametrize("variant", [
+    dict(backend="jax"), dict(backend="jax", fused=False),
+    dict(backend="jax", coalesce=True), dict(backend="segsum"),
+    dict(backend="pallas"), dict(backend="pallas", coalesce=True)])
+def test_engine_nnz_gauges_sum_to_plan_nnz(variant):
+    from repro.sparse import generators as G
+    m = G.banded(n=300, band=5) if variant.get("coalesce") else \
+        G.power_law(n=400, avg_deg=6)
+    builds = (metrics.histogram_value("engine.build_seconds")
+              or {"count": 0})["count"]
+    app = SpMV.from_coo(np.asarray(m.rows), np.asarray(m.cols),
+                        np.asarray(m.vals), m.shape, lane_width=32,
+                        **variant)
+    nnz = {k: metrics.gauge_value(f"engine.nnz.{k}")
+           for k in ("window", "coalesced", "fallback")}
+    assert sum(nnz.values()) == app.plan.nnz
+    assert metrics.histogram_value("engine.build_seconds")["count"] == \
+        builds + 1
+    if variant["backend"] == "segsum":
+        assert nnz["fallback"] == app.plan.nnz
+    if variant.get("coalesce"):
+        assert nnz["coalesced"] > 0
+
+
 def test_traced_decorator_disabled_is_passthrough():
     calls = []
 
@@ -103,8 +175,9 @@ def test_traced_decorator_disabled_is_passthrough():
 def test_auto_spmv_span_tree_covers_pipeline(tmp_path):
     trace.enable()
     rows, cols, vals, shape = _coo()
-    app = SpMV.from_coo(rows, cols, vals, shape, backend="auto")
-    app.matvec(np.zeros(shape[1], np.float32))
+    with jax.profiler.trace(str(tmp_path)):
+        app = SpMV.from_coo(rows, cols, vals, shape, backend="auto")
+        jax.block_until_ready(app.matvec(np.zeros(shape[1], np.float32)))
     names = {r.name for r in trace.finished_spans()}
     for required in ("app.spmv.build", "validate.coo", "plan.build",
                      "plan.binning", "ir.lower", "ir.pass.build",
@@ -131,18 +204,10 @@ def test_auto_spmv_span_tree_covers_pipeline(tmp_path):
     for r in pass_spans:
         assert "launches_before" in r.attrs and "launches_after" in r.attrs
 
-    # the chrome-trace export round-trips as valid JSON with the
-    # required trace-event fields
-    path = tmp_path / "trace.json"
-    trace.export_chrome_trace(str(path))
-    payload = json.loads(path.read_text())
-    events = payload["traceEvents"]
-    assert events
-    for ev in events:
-        for field in ("name", "cat", "ph", "ts", "dur", "pid", "tid"):
-            assert field in ev
-        assert ev["ph"] == "X"
-        assert ev["dur"] >= 0
+    # the profiler's trace holds every recorded span as a host event,
+    # on the clock of the device trace
+    host = _host_event_names(tmp_path)
+    assert names <= host, f"spans missing from the profile: {names - host}"
     # the tree dump renders every record
     dump = trace.tree_dump()
     assert "app.spmv.build" in dump and "ir.lower" in dump
