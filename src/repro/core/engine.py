@@ -189,7 +189,9 @@ def segmented_reduce(term: jnp.ndarray, seg: jnp.ndarray, op_flag: int,
         # program (elementwise ops cannot be reassociated by XLA), 2N work
         # instead of the ladder's N log N, and for power-of-two widths its
         # root is bit-identical to the masked ladder's head lane.  The
-        # Pallas kernel keeps the true native reduction.
+        # Pallas kernel keeps the true native reduction on the chip; in
+        # interpret mode it runs this tree as a lane butterfly
+        # (``common.segmented_reduce_lanes``), combine for combine.
         total = _halving_tree(term, op, identity)
         return term.at[:, 0].set(total[:, 0])
     trailing = ((0, 0),) * (term.ndim - 2)
@@ -485,7 +487,9 @@ def _stage_meta(plan: BlockPlan, launches: list[ir.Launch]) -> dict:
 def _record_nnz(trees) -> None:
     """Set the ``engine.nnz.{window,coalesced,fallback}`` gauges: the
     valid (unpadded) nonzeros of the trees' launches of each kind.  The
-    segsum form runs every block through its one per-element gather."""
+    segsum form runs every block through its one per-element gather.
+    ``engine.nnz.window_resident`` restarts at 0: the Pallas stage A sets
+    it when its program is traced, once the views' bytes are known."""
     nnz = {"window": 0, "coalesced": 0, "fallback": 0}
     for tree in trees:
         valid = tree.plan.valid
@@ -495,6 +499,7 @@ def _record_nnz(trees) -> None:
             nnz[kind] += int(valid[launch.start:launch.stop].sum())
     for kind, n in nnz.items():
         _metrics.set_gauge(f"engine.nnz.{kind}", n)
+    _metrics.set_gauge("engine.nnz.window_resident", 0)
 
 
 @_trace.traced("engine.build_sweeper")

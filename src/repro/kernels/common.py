@@ -58,10 +58,11 @@ def resolve_interpret(interpret: bool | None) -> bool:
 def permute_tiles(tiles, slot: jnp.ndarray, offset: jnp.ndarray
                   ) -> jnp.ndarray:
     """Gather-replacement permute (paper Fig. 6: permutation + select):
-    ``M`` window tiles of shape ``(1, N, ...)`` -> the ``(1, N, ...)`` lane
-    vector whose lane ``j`` is word ``offset[j]`` of tile ``slot[j]``.
+    ``M`` window tiles of shape ``(rows, N, ...)``, one block per row ->
+    the ``(rows, N, ...)`` lanes whose lane ``j`` of row ``r`` is word
+    ``offset[r, j]`` of row ``r`` of tile ``slot[r, j]``.
 
-    ``slot``/``offset`` are ``(1, N)`` int32.  Each tile is permuted
+    ``slot``/``offset`` are ``(rows, N)`` int32.  Each tile is permuted
     inside the lane axis (``take_along_axis`` — an in-register lane
     shuffle on TPU, no memory gather) and the ``M`` permuted tiles are
     merged by a select chain on ``slot``.  Every lane returns the selected
@@ -104,6 +105,23 @@ def permute_onehot(windows: jnp.ndarray, slot: jnp.ndarray,
     return permute_tiles(tiles, slot, offset)[0]
 
 
+def round_term(term: jnp.ndarray, zero) -> jnp.ndarray:
+    """The combine's lanes, rounded to their dtype before the ladder reads
+    them — the in-kernel twin of ``engine.combine_rounded``, for interpret
+    mode.  That mode runs the body as XLA-CPU code, where LLVM may
+    contract a product with the ladder's sum into one fused multiply-add
+    depending on the slab shape; passing float bits through an integer XOR
+    with ``zero`` (a runtime scalar, always 0, that the compiler cannot
+    fold) ends every product at a rounded word.  Integer terms pass
+    through."""
+    if not jnp.issubdtype(term.dtype, jnp.floating):
+        return term
+    bits = jnp.dtype(f"int{8 * term.dtype.itemsize}")
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(term, bits) ^ zero.astype(bits),
+        term.dtype)
+
+
 def shift_lanes(a: jnp.ndarray, d: int, fill) -> jnp.ndarray:
     """``out[:, j] = a[:, j + d]`` for ``j < N - d``, ``fill`` beyond —
     a lane rotation (``pltpu.roll``) plus a lane mask, the form Mosaic
@@ -116,16 +134,32 @@ def shift_lanes(a: jnp.ndarray, d: int, fill) -> jnp.ndarray:
 
 
 def segmented_reduce_lanes(term: jnp.ndarray, seg: jnp.ndarray,
-                           op_flag: int, reduce: str) -> jnp.ndarray:
-    """(1, N, ...) lane vector -> (1, N, ...) with each segment head holding
-    the full segment reduction.  ``op_flag`` is static (one kernel
-    specialization per pattern class — the paper's per-flag code
-    generation).  ``seg`` is always (1, N) and broadcasts over trailing
-    lane axes.  Shift pads use the dtype-aware identity (DESIGN.md §3a)."""
+                           op_flag: int, reduce: str,
+                           butterfly: bool = False) -> jnp.ndarray:
+    """(rows, N, ...) lanes, one block per row -> the same shape with each
+    segment head holding the full segment reduction.  ``op_flag`` is
+    static (one kernel specialization per pattern class — the paper's
+    per-flag code generation).  ``seg`` is always (rows, N) and broadcasts
+    over trailing lane axes.  Shift pads use the dtype-aware identity
+    (DESIGN.md §3a).
+
+    ``FULL_REDUCE`` is the architecture-native lane reduction, or with
+    ``butterfly`` a lane butterfly (rotate + combine) whose lane 0 is the
+    XLA form's pairwise halving tree, combine for combine: interpret mode
+    takes it, because XLA-CPU picks a native reduce's order per slab
+    shape (1 vs R rows a step); the TPU's native reduce gives the same
+    bits at every slab height, and 5-6% less stage-A time on a fused
+    mixed launch (v5e, PERF.md)."""
     op, _, full = REDUCE_FNS[reduce]
     identity = reduce_identity_for(reduce, term.dtype)
     if op_flag == FULL_REDUCE:
-        total = full(term, axis=1, keepdims=True)
+        if butterfly:
+            total, d = term, 1
+            while d < term.shape[1]:
+                total = op(total, shift_lanes(total, d, identity))
+                d *= 2
+        else:
+            total = full(term, axis=1, keepdims=True)
         lane = jax.lax.broadcasted_iota(jnp.int32, term.shape[:2], 1)
         return jnp.where(expand_trailing(lane == 0, term.ndim), total, term)
     for k in range(op_flag):

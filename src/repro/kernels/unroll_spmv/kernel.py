@@ -2,16 +2,16 @@
 
 One ``pallas_call`` per launch — a pattern class in per-class mode, or the
 whole vload section in fused mode (the grid spans every vload block).  Per
-grid step the kernel
+block the kernel
 
-  1. receives the launch's ``ls`` windows of each gathered array as VMEM
-     tiles — the window *index* is runtime data (scalar-prefetched
-     ``window_ids``), so the HBM->VMEM DMAs are dynamic but tile-granular
-     and pipelined across grid steps by the Pallas scheduler.  This is the
-     paper's ``vload`` group replacing the per-element ``gather``.  In
-     fused mode ``ls`` is the section-wide max: slots beyond a block's own
-     window count repeat the last valid window id (legal DMA, never
-     selected by the lane permutation).
+  1. reads the launch's ``ls`` windows of each gathered array as lane
+     tiles — the window *index* is runtime data (``window_ids`` in SMEM):
+     a dynamic row load out of the view held in VMEM (resident form) or a
+     dynamic tile DMA from HBM pipelined across grid steps (per-tile
+     form).  This is the paper's ``vload`` group replacing the
+     per-element ``gather``.  In fused mode ``ls`` is the section-wide
+     max: slots beyond a block's own window count repeat the last valid
+     window id (a legal load, never selected by the lane permutation).
   2. applies the static per-lane permutation + select: an in-register
      lane gather per window tile merged by a select chain on the lane's
      slot (paper Fig. 6: permutation + select instructions),
@@ -22,8 +22,13 @@ grid step the kernel
      native full reduction for single-segment blocks — bitwise-identical
      to the per-class launch of the same block (DESIGN.md §3).
 
-Outputs the (1, N, ...) post-reduce lane vector; the merged write-back
-(Fig. 4) happens outside (stage B) on the compressed head stream.
+Outputs the post-reduce lane vector of each block; the merged write-back
+(Fig. 4) happens outside (stage B) on the compressed head stream.  On the
+TPU every form gives the same bits at any slab height.  In interpret mode
+a single-segment block's native reduction is a lane butterfly whose lane
+0 is the XLA emitter's pairwise halving tree, and float terms are rounded
+before the ladder (``common.round_term``), so there too every form is
+bitwise equal to the others, and to the jax backend.
 
 Rank polymorphism (DESIGN.md §13): gathered views may carry trailing lane
 axes — ``(W, N, D)`` for SpMM rows of B — which ride through the window
@@ -31,11 +36,15 @@ DMAs, the lane permute and the shift ladder unchanged; lane metadata
 (slot/offset/segment) stays 2-D and broadcasts, the same
 ``_expand_trailing`` rule the XLA emitter applies.
 
-Three lowering forms share the ladder body:
+Four lowering forms share the ladder body:
 
-  * ``class_stage_a`` — TPU window form (``PrefetchScalarGridSpec``, one
-    block per grid step; ``meta_prefetch`` widens the metadata DMA tiles).
-    This is also the portable ``interpret=True`` CI form.
+  * ``resident_stage_a`` — TPU window form for views that fit VMEM
+    (``resident_steps``): the whole view in VMEM, a window a dynamic row
+    load, a fixed granule of blocks per grid step.  Also the portable
+    ``interpret=True`` CI form.
+  * ``class_stage_a`` — TPU window form for larger views
+    (``PrefetchScalarGridSpec``, one block per grid step, each window a
+    tile DMA; ``meta_prefetch`` widens the metadata DMA tiles).
   * ``coalesced_stage_a`` — the dense-slice form for
     ``ir.coalesce_gathers`` launches: per block one unaligned slice of
     ``lane_width`` elements, built from the two aligned lane tiles that
@@ -47,18 +56,27 @@ Three lowering forms share the ladder body:
     window tiles are fetched with in-kernel dynamic ``pl.ds`` loads from
     the full view; ``rows_per_step`` rows per program.
 
-VMEM budget per step: (ls * n_gathered + n_elementwise + 4) lane tiles of
-N*prod(trailing) words — a few KB at N=128 scalar lanes (the coalesced
-form: two tiles per gathered array and row).  Nothing of the size of the
-gathered array is held in VMEM.  Every per-block operand is laid out with
-the block axis in front of two whole dims (``(W, 1, N, ...)`` views,
+VMEM budget: the resident form holds the launch's gathered views whole
+while their footprint under Mosaic's (8, 128) tiling is at most
+``RESIDENT_VIEW_BYTES`` (32 MiB: x of 8e6 float32 rows at N = 128), plus
+double-buffered ``(R, N)`` step blocks and ``(ls, 8, N, ...)`` window
+scratch within ``RESIDENT_STEP_BYTES``; ``vmem_limit_bytes`` is raised to
+cover both.  A step's window-id and flag blocks sit in SMEM, within twice
+``PREFETCH_WORDS``.  The choice reads only the bytes of the views and of
+a step.
+The per-tile form, and the coalesced form, hold
+(ls * n_gathered + n_elementwise + 4) lane tiles of N*prod(trailing)
+words a step — a few KB at N = 128 — and nothing of the size of the
+gathered array.  Their per-block operands are laid out with the block
+axis in front of two whole dims (``(W, 1, N, ...)`` views,
 ``(Bc // p, p, N)`` metadata), so each block's last two dims equal the
-array's, which Mosaic's (8, 128) tiling rule accepts at any ``p``.
-Scalar-prefetched operands are chunked to fit SMEM (``PREFETCH_WORDS``).
+array's, which the tiling rule accepts at any ``p``; their
+scalar-prefetched operands are chunked to fit SMEM (``PREFETCH_WORDS``).
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable
 
 import jax
@@ -85,6 +103,29 @@ def _largest_divisor(b: int, r: int) -> int:
 # ``pallas_call`` inside a ``lax.map``) plus one tail call — blocks are
 # independent, so no block's reduction tree changes.
 PREFETCH_WORDS = 1 << 15
+
+# The resident window form holds every gathered view of a launch in VMEM
+# (128 MiB on v5e) while its footprint under Mosaic's tiling stays within
+# RESIDENT_VIEW_BYTES; the per-step buffers get up to RESIDENT_STEP_BYTES
+# more, so a kernel asks for at most 72 MiB.
+RESIDENT_VIEW_BYTES = 32 << 20
+RESIDENT_STEP_BYTES = 32 << 20
+# A group's window-row copies are unrolled up to this many (static sublane
+# stores, 13% less kernel time than a loop over the rows on HPCG-104's ls
+# of 9, on v5e); a wider fused section loops over its rows instead, which
+# keeps the program small.
+UNROLL_COPIES = 128
+
+
+def _vmem_bytes(shape: tuple, dtype) -> int:
+    """VMEM footprint of an array under Mosaic's tiling: the last two
+    dims pad to (sublane tile, 128 lanes); leading dims multiply."""
+    itemsize = jnp.dtype(dtype).itemsize
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub = 8 * max(1, 4 // itemsize)
+    rows = -(-shape[-2] // sub) * sub
+    lanes = -(-shape[-1] // 128) * 128
+    return math.prod(shape[:-2]) * rows * lanes * itemsize
 
 
 def _chunk_blocks(bc: int, words_per_block: int, step: int) -> int:
@@ -118,21 +159,32 @@ def _chunked(call, bc: int, chunk: int, per_block: list, widths: list):
 
 
 def _combine_lanes(win_vals: dict, elem_vals: dict, combine: Callable,
-                   seg: jnp.ndarray, op: int, mixed, reduce: str):
-    """Shared ladder tail on ``(1, N, ...)`` lane vectors: broadcast
-    elementwise lanes up to the gathered rank (§8), combine, shift-reduce,
-    and resolve the fused-mixed native-reduction select.  ``mixed`` is the
-    per-block flag value (a traced scalar) or None."""
+                   seg: jnp.ndarray, op: int, mixed, reduce: str, zero):
+    """Shared ladder tail on ``(rows, N, ...)`` lane slabs, one block per
+    row: broadcast elementwise lanes up to the gathered rank (§8), combine,
+    shift-reduce, and resolve the fused-mixed native-reduction select.
+    ``mixed`` is the per-block flag (a traced scalar, or a ``(rows, N)``
+    slab holding each row's flag) or None.  ``zero`` is None on the chip;
+    in interpret mode it is a runtime 0 for :func:`common.round_term`
+    (the min of a window id or slice start, never negative, and 0), and
+    the full reduction runs as the halving-tree butterfly."""
     vals = dict(win_vals)
     rank = max((v.ndim for v in vals.values()), default=2)
     for e, v in elem_vals.items():
         vals[e] = common.expand_trailing(v, rank)
     term = combine(vals)
-    red = common.segmented_reduce_lanes(term, seg, op, reduce)
+    exact = zero is not None
+    if exact:
+        term = common.round_term(term, zero)
+    red = common.segmented_reduce_lanes(term, seg, op, reduce,
+                                        butterfly=exact)
     if mixed is not None:
-        native = common.segmented_reduce_lanes(term, seg,
-                                               common.FULL_REDUCE, reduce)
-        red = jnp.where(mixed != 0, native, red)
+        native = common.segmented_reduce_lanes(
+            term, seg, common.FULL_REDUCE, reduce, butterfly=exact)
+        pick = mixed != 0
+        if pick.ndim:
+            pick = common.expand_trailing(pick, red.ndim)
+        red = jnp.where(pick, native, red)
     return red
 
 
@@ -153,7 +205,7 @@ def _tiles(view: jnp.ndarray) -> jnp.ndarray:
 def _stage_a_body(base_ref, win_ref, flag_ref, *refs, combine: Callable,
                   gathered: tuple, elementwise: tuple, ls: int, op: int,
                   stream: bool, mixed: bool, reduce: str, out_dtype,
-                  meta_prefetch: int):
+                  meta_prefetch: int, interpret: bool):
     """Kernel body. ``refs`` layout:
     [g0_win0..g0_win{ls-1}, g1_win0.., ...] + [elem...] +
     [slot, offset, seg] + [out]."""
@@ -182,8 +234,208 @@ def _stage_a_body(base_ref, win_ref, flag_ref, *refs, combine: Callable,
                                                                off)
     elem_vals = {e: elem_refs[ei][...] for ei, e in enumerate(elementwise)}
     flag = flag_ref[pl.program_id(0)] if mixed else None
-    red = _combine_lanes(vals, elem_vals, combine, seg, op, flag, reduce)
+    red = _combine_lanes(vals, elem_vals, combine, seg, op, flag, reduce,
+                         jnp.minimum(win_ref[0], 0) if interpret else None)
     out_ref[...] = red.astype(out_dtype)
+
+
+def _resident_body(win_ref, *refs, combine: Callable, gathered: tuple,
+                   elementwise: tuple, ls: int, op: int, stream: bool,
+                   mixed: bool, reduce: str, out_dtype, rows: int,
+                   group: int, bc: int, interpret: bool):
+    """Resident-form body: ``rows`` blocks per grid step, ``group`` (8, a
+    vreg of blocks) at a time.  ``win_ref`` is this step's SMEM block of
+    window ids (``ls`` per block).  ``refs`` layout: [flags] (SMEM,
+    ``mixed`` only) + [view_g...] (whole views in VMEM) + [elem...] +
+    [slot, off] (not for ``stream``) + [seg] + [out] + [window scratch per
+    gathered array] + [flag scratch] (``mixed`` only).
+
+    Per group, each block's ``ls`` window rows are copied out of the
+    resident views into ``(ls, group, N, ...)`` scratch (unrolled up to
+    :data:`UNROLL_COPIES` copies), then the permute, the combine and the
+    ladder run on ``(group, N, ...)`` slabs — row by row the same ops as
+    the per-tile form.  The last step of a launch may
+    hold fewer than ``rows`` valid blocks: its groups stop at the last
+    valid one, whose window ids fill any rows past it (never written
+    back).  A one-step launch of Bc blocks, Bc not a multiple of 8, runs
+    its last group over rows ``[Bc - 8, Bc)``, recomputing a few rows."""
+    if mixed:
+        flag_ref, *refs = refs
+    n_g = len(gathered)
+    n_e = len(elementwise)
+    view_refs = refs[:n_g]
+    elem_refs = refs[n_g:n_g + n_e]
+    k = n_g + n_e
+    if not stream:
+        slot_ref, off_ref = refs[k:k + 2]
+        k += 2
+    seg_ref, out_ref = refs[k:k + 2]
+    scr_refs = refs[k + 2:k + 2 + n_g]
+    flag_scr = refs[k + 2 + n_g] if mixed else None
+    valid = jnp.minimum(rows, bc - pl.program_id(0) * rows)
+    zero = jnp.minimum(win_ref[0], 0) if interpret else None
+
+    def run_group(q, carry):
+        if rows % group:
+            base = jnp.minimum(q * group, rows - group)
+        else:
+            base = pl.multiple_of(q * group, group)
+
+        def gather_row(r, carry=None):
+            i = jnp.minimum(base + r, valid - 1)
+            for gi in range(n_g):
+                rest = (slice(None),) * (view_refs[gi].ndim - 1)
+                for j in range(ls):
+                    w = win_ref[i * ls + j]
+                    scr_refs[gi][(j, pl.ds(r, 1)) + rest] = \
+                        view_refs[gi][(pl.ds(w, 1),) + rest]
+            if mixed:
+                flag_scr[pl.ds(r, 1), :] = jnp.full(
+                    (1, flag_scr.shape[1]), flag_ref[i], jnp.int32)
+            return carry
+
+        if group * ls <= UNROLL_COPIES:
+            for r in range(group):
+                gather_row(r)
+        else:
+            jax.lax.fori_loop(0, group, gather_row, 0)
+        sl = pl.ds(base, group)
+        vals = {}
+        for gi, g in enumerate(gathered):
+            tiles = [scr_refs[gi][j] for j in range(ls)]
+            vals[g] = tiles[0] if stream else common.permute_tiles(
+                tiles, slot_ref[sl], off_ref[sl])
+        elem_vals = {e: elem_refs[ei][sl]
+                     for ei, e in enumerate(elementwise)}
+        flag = flag_scr[...] if mixed else None
+        red = _combine_lanes(vals, elem_vals, combine, seg_ref[sl], op,
+                             flag, reduce, zero)
+        out_ref[sl] = red.astype(out_dtype)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(valid, group), run_group, 0)
+
+
+def _resident_rows(bc: int, ls: int, mixed: bool) -> tuple[int, int]:
+    """``(rows, group)`` of the resident form: blocks per grid step and
+    per vreg-high slab.  A launch that fits one step runs as one step.
+    Otherwise ``rows`` is the step granule: the fewest blocks that make
+    whole sublane tiles of the ``(Bc, N)`` operands (8 rows, so they need
+    no relayout) and whole 1024-word tiles of the 1-D int32 window ids
+    (``rows * ls`` words) and flags (``rows`` words) in HBM, which the
+    SMEM blocks are cut from — 1024 for an odd ``ls`` or a mixed launch."""
+    rows = math.lcm(8, 1024 // math.gcd(ls, 1024), 1024 if mixed else 1)
+    if bc <= rows:
+        return bc, min(bc, 8)
+    return rows, 8
+
+
+def resident_steps(gathered_views: dict, *, blocks: int, ls: int,
+                   mixed: bool, stream: bool, elementwise: int, out_dtype,
+                   out_trailing: tuple = (), interpret: bool | None = None,
+                   platform: str | None = None) -> tuple | None:
+    """``(rows, group, vmem_limit_bytes)`` of the resident form for one
+    window launch, or None where the launch keeps the per-tile form: the
+    Triton lowering, views whose VMEM footprint passes
+    :data:`RESIDENT_VIEW_BYTES`, a step whose window-id and flag blocks
+    pass twice :data:`PREFETCH_WORDS` of SMEM (``ls`` over 63 at the
+    1024-block granule), or a step whose VMEM buffers pass
+    :data:`RESIDENT_STEP_BYTES` (wide trailing lane axes).  Decided from
+    the bytes of the input alone, so every app gets the same rule; the
+    Pallas stage A (``ops.make_stage_a``) picks the form by it, once per
+    launch, and sets the ``engine.nnz.window_resident`` gauge from it."""
+    interpret = common.resolve_interpret(interpret)
+    platform = platform or jax.default_backend()
+    if platform == "gpu" and not interpret:
+        return None
+    views = list(gathered_views.values())
+    view_bytes = sum(_vmem_bytes(v.shape, v.dtype) for v in views)
+    if view_bytes > RESIDENT_VIEW_BYTES:
+        return None
+    rows, group = _resident_rows(blocks, ls, mixed)
+    # SMEM of a step: its window-id and flag blocks, double-buffered,
+    # within half of v5e's 1 MiB (four per-tile prefetch chunks); at the
+    # 1024-block granule that admits ``ls`` up to 63, every fused section
+    # at the default window cut of N // 4
+    if rows * (ls + int(mixed)) > 2 * PREFETCH_WORDS:
+        return None
+    # VMEM of a step: double-buffered elementwise, metadata and output
+    # rows per block, and one group's window rows and flag rows
+    n = views[0].shape[1]
+    lane_row = _vmem_bytes((8, n), jnp.int32) // 8
+    per_block = 2 * lane_row * (elementwise + (1 if stream else 3))
+    per_block += 2 * _vmem_bytes((8, n) + tuple(out_trailing),
+                                 out_dtype) // 8
+    scratch = sum(ls * _vmem_bytes((8,) + v.shape[1:], v.dtype)
+                  for v in views) + (8 * lane_row if mixed else 0)
+    step = rows * per_block + scratch
+    if step > RESIDENT_STEP_BYTES:
+        return None
+    return rows, group, view_bytes + step + (8 << 20)
+
+
+def resident_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
+                     elem_blocks: dict, slot: jnp.ndarray,
+                     off: jnp.ndarray, seg: jnp.ndarray, *, steps: tuple,
+                     combine: Callable, gathered: tuple, elementwise: tuple,
+                     ls: int, op: int, stream: bool, reduce: str,
+                     full_flags: jnp.ndarray | None = None,
+                     out_dtype=jnp.float32, out_trailing: tuple = (),
+                     interpret: bool | None = None) -> jnp.ndarray:
+    """The window form with the gathered views resident in VMEM (same
+    contract as :func:`class_stage_a`; ``win_ids`` may be flat and
+    ``steps`` is what :func:`resident_steps` gave for the launch).
+
+    Each view is one whole-array VMEM operand, copied in once per call, so
+    a window costs a dynamic row load out of VMEM instead of a tile DMA
+    and a grid step.  ``R`` blocks share a grid step: their elementwise,
+    metadata and output rows move as ``(R, N)`` blocks of the ``(Bc, N)``
+    arrays, their window ids and flags as SMEM blocks of the step, so one
+    call covers the launch with no prefetch chunking.  ``R`` is the step
+    granule of :func:`_resident_rows`, fixed by the tiles of the step's
+    blocks; ``meta_prefetch`` and ``rows_per_step`` have no part in this
+    form.  When ``R`` does not divide Bc the last step is partial: Pallas
+    clips its reads and writes to the arrays, and the kernel gathers only
+    its valid blocks, so no block is padded or dropped.  Rank-2 views stay ``(W, N)``, one window a row;
+    views with trailing lane axes keep their ``(W, N, ...)`` shape."""
+    interpret = common.resolve_interpret(interpret)
+    bc, n = seg.shape
+    mixed = full_flags is not None
+    rows, group, vmem_limit = steps
+    views = [gathered_views[g] for g in gathered]
+    body = functools.partial(_resident_body, combine=combine,
+                             gathered=gathered, elementwise=elementwise,
+                             ls=ls, op=op, stream=stream, mixed=mixed,
+                             reduce=reduce, out_dtype=out_dtype, rows=rows,
+                             group=group, bc=bc, interpret=interpret)
+    z = len(out_trailing)
+    in_specs = [pl.BlockSpec((rows * ls,), lambda b: (b,),
+                             memory_space=pltpu.SMEM)]
+    operands = [jnp.asarray(win_ids, jnp.int32).reshape(-1)]
+    if mixed:
+        in_specs.append(pl.BlockSpec((rows,), lambda b: (b,),
+                                     memory_space=pltpu.SMEM))
+        operands.append(jnp.asarray(full_flags, jnp.int32))
+    for v in views:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
+        operands.append(v)
+    metas = (seg,) if stream else (slot, off, seg)
+    for a in [elem_blocks[e] for e in elementwise] + list(metas):
+        in_specs.append(pl.BlockSpec((rows, n), lambda b: (b, 0)))
+        operands.append(a)
+    scratch = [pltpu.VMEM((ls, group) + v.shape[1:], v.dtype)
+               for v in views]
+    if mixed:
+        scratch.append(pltpu.VMEM((group, n), jnp.int32))
+    return pl.pallas_call(
+        body, grid=(pl.cdiv(bc, rows),), in_specs=in_specs,
+        out_specs=pl.BlockSpec((rows, n) + out_trailing,
+                               lambda b: (b, 0) + (0,) * z),
+        out_shape=jax.ShapeDtypeStruct((bc, n) + out_trailing, out_dtype),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(*operands)
 
 
 def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
@@ -196,7 +448,11 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
                   interpret: bool | None = None,
                   meta_prefetch: int = 1,
                   platform: str | None = None) -> jnp.ndarray:
-    """Launch stage A for one pattern class / fused section.
+    """Launch stage A for one pattern class / fused section in the
+    per-tile form: one block per grid step, its ``ls`` window tiles DMA'd
+    from HBM.  The form for views over the resident budget
+    (:func:`resident_steps` is None); the rest run
+    :func:`resident_stage_a`.
 
     win_ids        (Bc, ls) int32 — scalar-prefetched window indices
     gathered_views g -> (W, N, ...) lane-tile view of the dense array
@@ -229,7 +485,7 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
                              gathered=gathered, elementwise=elementwise,
                              ls=ls, op=op, stream=stream, mixed=mixed,
                              reduce=reduce, out_dtype=out_dtype,
-                             meta_prefetch=p)
+                             meta_prefetch=p, interpret=interpret)
     z = len(out_trailing)
 
     in_specs = []
@@ -280,7 +536,7 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
 def _coalesced_body(base_ref, start_ref, flag_ref, *refs, combine: Callable,
                     gathered: tuple, elementwise: tuple, op: int,
                     mixed: bool, reduce: str, out_dtype, has_off: bool,
-                    rows: int, n: int):
+                    rows: int, n: int, interpret: bool):
     """``refs`` layout: [lo/hi tile pair per row, per gathered array] +
     [elem...] + [off?, seg] + [out].  Per row: the two aligned lane tiles
     that hold the slice ``[st, st + N)`` are rotated by ``st % N`` and
@@ -298,6 +554,7 @@ def _coalesced_body(base_ref, start_ref, flag_ref, *refs, combine: Callable,
     out_ref = rest[-1]
     zero_slot = jnp.zeros((1, n), jnp.int32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    zero = jnp.minimum(start_ref[0], 0) if interpret else None
     for i in range(rows):
         b = pl.program_id(0) * rows + i
         rem = start_ref[b] % n
@@ -320,7 +577,7 @@ def _coalesced_body(base_ref, start_ref, flag_ref, *refs, combine: Callable,
         seg = seg_ref[i:i + 1]
         flag = flag_ref[b] if mixed else None
         red = _combine_lanes(vals, elem_vals, combine, seg, op, flag,
-                             reduce)
+                             reduce, zero)
         out_ref[i:i + 1] = red.astype(out_dtype)
 
 
@@ -360,7 +617,7 @@ def coalesced_stage_a(starts: jnp.ndarray, gathered_views: dict,
                              gathered=gathered, elementwise=elementwise,
                              op=op, mixed=mixed, reduce=reduce,
                              out_dtype=out_dtype, has_off=has_off,
-                             rows=r, n=n)
+                             rows=r, n=n, interpret=interpret)
     z = len(out_trailing)
     in_specs = []
     operands = []
@@ -411,7 +668,8 @@ def coalesced_stage_a(starts: jnp.ndarray, gathered_views: dict,
 # --------------------------------------------------------- GPU (Triton)
 def _gpu_body(*refs, combine: Callable, gathered: tuple,
               elementwise: tuple, ls: int, op: int, stream: bool,
-              mixed: bool, reduce: str, out_dtype, rows: int):
+              mixed: bool, reduce: str, out_dtype, rows: int,
+              interpret: bool):
     """``refs`` layout: [win, flag] + [view_g...] + [elem...] +
     [slot, off, seg] + [out].  No scalar prefetch on Triton: window tiles
     are fetched with dynamic ``pl.ds`` row loads from the full view."""
@@ -422,6 +680,7 @@ def _gpu_body(*refs, combine: Callable, gathered: tuple,
     elem_refs = refs[2 + n_g: 2 + n_g + n_e]
     slot_ref, off_ref, seg_ref = refs[2 + n_g + n_e: 2 + n_g + n_e + 3]
     out_ref = refs[-1]
+    zero = jnp.minimum(win_ref[0, 0], 0) if interpret else None
     for i in range(rows):
         vals = {}
         for gi, g in enumerate(gathered):
@@ -435,7 +694,7 @@ def _gpu_body(*refs, combine: Callable, gathered: tuple,
                      for ei, e in enumerate(elementwise)}
         flag = flag_ref[i] if mixed else None
         red = _combine_lanes(vals, elem_vals, combine, seg_ref[i:i + 1],
-                             op, flag, reduce)
+                             op, flag, reduce, zero)
         out_ref[i:i + 1] = red.astype(out_dtype)
 
 
@@ -459,7 +718,8 @@ def gpu_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
     body = functools.partial(_gpu_body, combine=combine, gathered=gathered,
                              elementwise=elementwise, ls=ls, op=op,
                              stream=stream, mixed=mixed, reduce=reduce,
-                             out_dtype=out_dtype, rows=r)
+                             out_dtype=out_dtype, rows=r,
+                             interpret=interpret)
     in_specs = [pl.BlockSpec((r, ls), lambda b: (b, 0)),
                 pl.BlockSpec((r,), lambda b: (b,))]
     operands = [jnp.asarray(win_ids, jnp.int32), full_flags]

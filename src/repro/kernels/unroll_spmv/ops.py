@@ -26,7 +26,15 @@ this emitter unchanged.
 interpret mode only on CPU or when explicitly requested).
 ``kernel_params`` carries the tuned per-launch kernel knobs
 (:class:`repro.tune.space.Candidate`): ``rows_per_step`` for the
-dense-slice/Triton forms, ``meta_prefetch`` for the TPU window form.
+dense-slice form, ``meta_prefetch`` for the per-tile TPU window form.
+The resident window form takes neither: its step is fixed by the tiles of
+its blocks.
+
+Each window launch takes the resident form where ``kernel.resident_steps``
+says its gathered views and a step fit VMEM and SMEM, else the per-tile
+form; tracing ``stage_a`` makes that choice once per launch and sets the
+``engine.nnz.window_resident`` gauge from it: the valid nonzeros of the
+window launches that run the resident form.
 """
 from __future__ import annotations
 
@@ -37,7 +45,11 @@ from repro.core import engine as eng
 from repro.core import ir
 from repro.core.plan import BlockPlan
 from repro.kernels import common
-from repro.kernels.unroll_spmv.kernel import class_stage_a, coalesced_stage_a
+from repro.kernels.unroll_spmv.kernel import (class_stage_a,
+                                              coalesced_stage_a,
+                                              resident_stage_a,
+                                              resident_steps)
+from repro.obs import metrics as _metrics
 
 
 def _term_struct(seed, mutable, elem_dtypes):
@@ -89,13 +101,19 @@ def make_stage_a(plan: BlockPlan, elem_exec,
             cm["local"] = (None if launch.local_offset is None
                            else jnp.asarray(launch.local_offset, jnp.int32))
         else:
+            # flat: the kernels read window ids as 1-D scalar arrays, and a
+            # 1-D device layout keeps that reshape a no-op
             cm["win"] = jnp.asarray(
-                plan.window_ids[s][:, :max(launch.ls_flag, 1)], jnp.int32)
+                plan.window_ids[s][:, :max(launch.ls_flag, 1)].reshape(-1),
+                jnp.int32)
             cm["slot"] = jnp.asarray(plan.lane_slot[s], jnp.int32)
             cm["off"] = jnp.asarray(plan.lane_offset[s], jnp.int32)
         consts.append(cm)
+    valid = [int(plan.valid[launch.start:launch.stop].sum())
+             for launch in launches]
 
-    def launch_lanes(launch, cm, views, mutable, out_dtype, out_trailing):
+    def launch_lanes(launch, cm, views, mutable, out_dtype, out_trailing,
+                     steps):
         elem_blocks = cm["elem"]
         if launch.gather == ir.FALLBACK and seed.gather_index is not None:
             # native gather path (XLA) + in-XLA segmented reduce
@@ -122,25 +140,43 @@ def make_stage_a(plan: BlockPlan, elem_exec,
                 reduce=seed.reduce, full_flags=cm["full"],
                 out_dtype=out_dtype, out_trailing=out_trailing,
                 interpret=interpret, rows_per_step=rows_per_step)
-        return class_stage_a(
-            cm["win"], views, elem_blocks, cm["slot"], cm["off"],
-            cm["seg"], combine=seed.combine, gathered=seed.gathered,
-            elementwise=seed.elementwise, ls=max(launch.ls_flag, 1),
-            op=launch.op_flag, stream=launch.stream, reduce=seed.reduce,
-            full_flags=cm["full"], out_dtype=out_dtype,
-            out_trailing=out_trailing, interpret=interpret,
-            meta_prefetch=meta_prefetch)
+        kw = dict(combine=seed.combine, gathered=seed.gathered,
+                  elementwise=seed.elementwise, ls=max(launch.ls_flag, 1),
+                  op=launch.op_flag, stream=launch.stream,
+                  reduce=seed.reduce, full_flags=cm["full"],
+                  out_dtype=out_dtype, out_trailing=out_trailing,
+                  interpret=interpret)
+        if steps is not None:
+            return resident_stage_a(cm["win"], views, elem_blocks,
+                                    cm["slot"], cm["off"], cm["seg"],
+                                    steps=steps, **kw)
+        win = cm["win"].reshape(cm["seg"].shape[0], -1)
+        return class_stage_a(win, views, elem_blocks, cm["slot"], cm["off"],
+                             cm["seg"], meta_prefetch=meta_prefetch, **kw)
 
     def stage_a(consts, mutable):
         views = {g: eng._pad_gathered(plan, jnp.asarray(mutable[g]))
                  for g in seed.gathered}
         out_dtype, out_trailing = _term_struct(seed, mutable, elem_dtypes)
         parts = []
-        for launch, cm in zip(launches, consts):
+        resident = 0
+        for launch, cm, n_valid in zip(launches, consts, valid):
+            # a window launch's form follows the bytes of its views and
+            # step, known once the views are
+            steps = None
+            if launch.gather in (ir.WINDOW, ir.STREAM):
+                steps = resident_steps(
+                    views, blocks=launch.stop - launch.start,
+                    ls=max(launch.ls_flag, 1), mixed=cm["full"] is not None,
+                    stream=launch.stream, elementwise=len(seed.elementwise),
+                    out_dtype=out_dtype, out_trailing=out_trailing,
+                    interpret=interpret)
+                resident += n_valid if steps is not None else 0
             # each launch's ops run under its kind's device scope
             with eng.launch_scope(launch):
                 parts.append(launch_lanes(launch, cm, views, mutable,
-                                          out_dtype, out_trailing))
+                                          out_dtype, out_trailing, steps))
+        _metrics.set_gauge("engine.nnz.window_resident", resident)
         if not parts:      # empty plan (nnz == 0): no launches, no lanes
             return jnp.zeros((0, plan.lane_width) + out_trailing, out_dtype)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)

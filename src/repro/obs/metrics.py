@@ -7,7 +7,10 @@ the ones the caches and degradation paths never had:
 * ``plan.builds`` / ``plan.build_seconds`` — feature-analysis runs
 * ``engine.build_seconds`` — executor builds (IR lowering, reorder,
   device staging); gauges ``engine.nnz.{window,coalesced,fallback}`` —
-  the valid nonzeros of the last built executor's launches of each kind
+  the valid nonzeros of the last built executor's launches of each kind —
+  and ``engine.nnz.window_resident``, those of its window launches that
+  run with the gathered views resident in VMEM (set when the Pallas
+  program is traced)
 * ``plan_cache.{hit,miss,corrupt,write_failed,store}`` — planio rungs
 * ``tune_cache.{hit,miss,corrupt,write_failed,store}`` — tuner cache
 * ``tune.measurements`` / ``tune.candidate_us`` — measured rounds and

@@ -24,7 +24,9 @@ on the current platform/seed:
   for ``pallas`` candidates, and ``kernel_prefetch`` only where the
   lowering has scalar prefetch (TPU / interpret; the Triton form reads
   metadata through full-view refs, so the knob would be a silent no-op
-  on GPU and is rejected rather than measured twice).
+  on GPU and is rejected rather than measured twice).  Both steer only
+  the per-tile window form and the coalesced form: a window launch whose
+  views fit VMEM runs the resident form, which takes neither.
 
 ``coalesce`` is a real axis for both lane-granular emitters now that the
 Pallas lowering consumes ``coalesce_gathers``-rewritten launches

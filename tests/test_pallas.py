@@ -263,6 +263,87 @@ def test_kernel_params_bitwise_stable(coalesce):
                                       err_msg=f"kr{rows}/kp{prefetch}")
 
 
+# ---------------------------------------------- resident window form
+def _wide(n_cols, out_len=40, per_row=12, seed=5):
+    """A short matrix over many columns: each row's nonzeros sit in one
+    stretch of 16 columns placed anywhere, so its blocks take the window
+    form over a large gathered view (an SpMM B of ``n_cols`` rows)."""
+    rng = np.random.default_rng(seed)
+    base = rng.integers(0, n_cols - 16, out_len)
+    rows = np.repeat(np.arange(out_len), per_row)
+    cols = np.concatenate([b + rng.choice(16, per_row, replace=False)
+                           for b in base])
+    return G.COOMatrix("wide", rows, cols, np.ones(rows.size, np.float32),
+                       (out_len, n_cols))
+
+
+RESIDENT_CASES = {
+    # name: (matrix, lane width, reduce, dtype, trailing D, resident)
+    # power_law(2048) fuses into one mixed window launch (per-block
+    # native-reduce flags); dense(48) lowers to a stream launch
+    "f32_add_fused_mixed": (lambda: G.power_law(2048, 6), 16, "add",
+                            np.float32, None, True),
+    "stream": (lambda: G.dense(48), 16, "add", np.float32, None, True),
+    "i32_min": (lambda: G.power_law(2048, 6), 16, "min", np.int32, None,
+                True),
+    # 5119 mixed window blocks: 5 resident steps of 1024, the last
+    # partial; the per-tile form runs 3 prefetch chunks and a tail
+    "tail": (lambda: G.banded(4096, 5), 8, "add", np.float32, None, True),
+    # 498 blocks of 31 windows: one step whose last group overlaps the one
+    # before it (498 is no multiple of 8), its row copies in a loop
+    # (8 x 31 > kernel.UNROLL_COPIES)
+    "wide_section": (lambda: G.power_law(4096, 16), 128, "add", np.float32,
+                     None, True),
+    # SpMM views: D = 4 pads to 128 lanes in VMEM, so 70000 rows of B
+    # (1.1 MB) take 35 MB there, over the budget; 600 rows fit
+    "spmm_over_budget": (lambda: _wide(70000), 16, "add", np.float32, 4,
+                         False),
+    "spmm_resident": (lambda: _wide(600), 16, "add", np.float32, 4, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_resident_form_bitwise(case, monkeypatch):
+    """The window kernel holds the gathered views in VMEM when their
+    Mosaic footprint fits ``RESIDENT_VIEW_BYTES`` and runs many blocks a
+    grid step; the result is bitwise equal to the per-tile form (the
+    budget forced to -1) and to the jax backend, and the
+    ``engine.nnz.window_resident`` gauge names the form that ran."""
+    from repro.kernels.unroll_spmv import kernel
+    from repro.obs import metrics
+    make, lane, reduce, dtype, d, resident = RESIDENT_CASES[case]
+    m = make()
+    plan = _plan_for(m, lane=lane, reduce=reduce)
+    vals, x = _spmv_problem(m, dtype, seed_int=3)
+    if d is not None:
+        x = np.random.default_rng(4).standard_normal(
+            (m.shape[1], d)).astype(dtype)
+    ident = reduce_identity_for(reduce, np.dtype(dtype))
+    y0 = jnp.full((m.shape[0],) + x.shape[1:], ident, dtype)
+    launches = ir.lower(plan, backend="pallas").launches
+    window_nnz = sum(int(plan.valid[lc.start:lc.stop].sum())
+                     for lc in launches if lc.gather in (ir.WINDOW, ir.STREAM))
+    assert window_nnz > 0
+
+    def go(backend):
+        run = eng.make_executor(plan, {"value": vals}, backend=backend,
+                                interpret=True)
+        return np.asarray(run({"x": jnp.asarray(x)}, y0))
+
+    y = go("pallas")
+    assert (metrics.gauge_value("engine.nnz.window_resident")
+            == (window_nnz if resident else 0))
+    assert metrics.gauge_value("engine.nnz.window") == window_nnz
+    monkeypatch.setattr(kernel, "RESIDENT_VIEW_BYTES", -1)
+    if case == "tail":
+        monkeypatch.setattr(kernel, "PREFETCH_WORDS", 1 << 12)
+    y_tiles = go("pallas")
+    assert metrics.gauge_value("engine.nnz.window_resident") == 0
+    np.testing.assert_array_equal(y.view(np.int32), y_tiles.view(np.int32))
+    np.testing.assert_array_equal(y.view(np.int32),
+                                  go("jax").view(np.int32))
+
+
 # ------------------------------------------------------- tuning surface
 def test_candidate_space_kernel_axes():
     """Accelerator spaces expose the kernel-param axes; GPU rejects the

@@ -7,6 +7,13 @@ budget.  These tests compile the stage-A kernels at real lane width
 with no interpret flag, plus whole executors, so every such refusal shows
 up here at no chip time.
 
+The window kernel has two forms, chosen from the bytes of the view and of
+a step (``kernel.resident_steps``): a view within
+``kernel.RESIDENT_VIEW_BYTES`` (2^15 windows of f32 or i32, 16 MB)
+compiles the resident form, held whole in VMEM; a view over it (2^17
+windows, 64 MB), or a step whose window ids pass the SMEM budget (an odd
+``ls`` over 63), compiles the per-tile form.
+
 The topology is described only inside a module fixture (never at import):
 one process at a time may load the TPU library, and every xdist worker
 imports this file.
@@ -22,12 +29,16 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import engine as eng
 from repro.core.plan import CostModel, build_plan
 from repro.core.seed import spmv_seed
-from repro.kernels.unroll_spmv.kernel import class_stage_a, coalesced_stage_a
+from repro.kernels.unroll_spmv.kernel import (class_stage_a,
+                                              coalesced_stage_a,
+                                              resident_stage_a,
+                                              resident_steps)
 from repro.sparse import generators as G
 
 N = 128
 BLOCKS = 1 << 19
 WINDOWS = 1 << 15
+WINDOWS_OVER = 1 << 17      # a view over the resident budget
 
 
 @pytest.fixture(scope="module")
@@ -69,29 +80,68 @@ WINDOW_CASES = {
     "i32_min_native": (jnp.int32, "min", _bfs_combine, ("level",), (),
                        2, -1, False),
 }
+WIDE_CASES = {
+    # a fused section with a window cut over N // 4: at the 1024-block
+    # step, 63 windows (64 SMEM words a block with the flag) are the most
+    # the resident form takes; 65 run per tile
+    "f32_add_fused_ls63": (jnp.float32, "add", _spmv_combine, ("x",),
+                           ("value",), 63, 7, True),
+    "f32_add_fused_ls65": (jnp.float32, "add", _spmv_combine, ("x",),
+                           ("value",), 65, 7, True),
+}
 
 
-@pytest.mark.parametrize("case", sorted(WINDOW_CASES))
-@pytest.mark.parametrize("meta_prefetch", [1, 8])
-def test_window_kernel_compiles_for_v5e(one_chip, case, meta_prefetch):
-    dt, red, comb, g, el, ls, op, mixed = WINDOW_CASES[case]
+def _compile_window(one_chip, case, windows, **params):
+    """Compile the form ``kernel.resident_steps`` picks for the case, as
+    the Pallas stage A does; True where that is the resident form."""
+    dt, red, comb, g, el, ls, op, mixed = {**WINDOW_CASES,
+                                           **WIDE_CASES}[case]
+    steps = resident_steps(
+        {g[0]: jax.ShapeDtypeStruct((windows, N), dt)}, blocks=BLOCKS, ls=ls,
+        mixed=mixed, stream=ls == 1, elementwise=len(el), out_dtype=dt,
+        interpret=False, platform="tpu")
 
     def stage_a(win, view, elem, slot, off, seg, flags):
-        return class_stage_a(
-            win, {g[0]: view}, {e: elem for e in el}, slot, off, seg,
-            combine=comb, gathered=g, elementwise=el, ls=ls, op=op,
-            stream=ls == 1, reduce=red,
-            full_flags=flags if mixed else None, out_dtype=dt,
-            interpret=False, platform="tpu", meta_prefetch=meta_prefetch)
+        kw = dict(combine=comb, gathered=g, elementwise=el, ls=ls, op=op,
+                  stream=ls == 1, reduce=red,
+                  full_flags=flags if mixed else None, out_dtype=dt,
+                  interpret=False)
+        args = (win, {g[0]: view}, {e: elem for e in el}, slot, off, seg)
+        if steps is not None:
+            return resident_stage_a(*args, steps=steps, **kw)
+        return class_stage_a(*args, platform="tpu", **params, **kw)
 
     i32 = jnp.int32
     compiled = jax.jit(stage_a).lower(
-        _sds((BLOCKS, ls), i32, one_chip), _sds((WINDOWS, N), dt, one_chip),
+        _sds((BLOCKS, ls), i32, one_chip), _sds((windows, N), dt, one_chip),
         _sds((BLOCKS, N), jnp.float32, one_chip),
         _sds((BLOCKS, N), i32, one_chip), _sds((BLOCKS, N), i32, one_chip),
         _sds((BLOCKS, N), i32, one_chip), _sds((BLOCKS,), i32, one_chip),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return steps is not None
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+@pytest.mark.parametrize("meta_prefetch", [1, 8])
+def test_window_kernel_compiles_for_v5e(one_chip, case, meta_prefetch):
+    """The per-tile form: a view over the resident budget."""
+    assert not _compile_window(one_chip, case, WINDOWS_OVER,
+                               meta_prefetch=meta_prefetch)
+
+
+@pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+def test_resident_window_kernel_compiles_for_v5e(one_chip, case):
+    """The resident form: a 16 MB view held in VMEM, many blocks a step."""
+    assert _compile_window(one_chip, case, WINDOWS)
+
+
+@pytest.mark.parametrize("case,resident", [("f32_add_fused_ls63", True),
+                                           ("f32_add_fused_ls65", False)])
+def test_wide_window_kernel_compiles_for_v5e(one_chip, case, resident):
+    """A 16 MB view with ``ls`` at the SMEM budget's edge: the resident
+    form up to it, the per-tile form past it."""
+    assert _compile_window(one_chip, case, WINDOWS) is resident
 
 
 @pytest.mark.parametrize("reduce", ["add", "min"])
