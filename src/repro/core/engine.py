@@ -57,6 +57,8 @@ double-buffer in place instead of allocating a fresh output per call.
 """
 from __future__ import annotations
 
+import functools
+import math
 import time
 from typing import Mapping
 
@@ -303,19 +305,30 @@ def _gather_launch_values(plan: BlockPlan, launch: ir.Launch, s: slice,
 
 
 def _stage_a_jax(plan: BlockPlan, meta, elem_exec, mutable,
-                 launches: list[ir.Launch], co_meta: dict) -> jnp.ndarray:
+                 launches: list[ir.Launch], co_meta: dict,
+                 blocks: dict | None = None) -> jnp.ndarray:
     """Walk the lowered launch list; return the (B, N, ...) post-reduce
-    lane matrix in exec-block order.  Mixed native/ladder sections never
-    occur here — ``fuse_sections`` merges only equal-op classes on the
-    XLA backend, so per-block full-reduce selection is a Pallas concern
-    (``ops.make_stage_a``)."""
+    lane matrix in exec-block order — or, with ``blocks`` (a row
+    partition, :func:`_run_partitions`), the lanes of launch ``i``'s
+    blocks at positions ``blocks[i]``, for the launches it names.  Mixed
+    native/ladder sections never occur here — ``fuse_sections`` merges
+    only equal-op classes on the XLA backend, so per-block full-reduce
+    selection is a Pallas concern (``ops.make_stage_a``)."""
     seed = plan.seed
     parts = []
     for i, launch in enumerate(launches):
-        s = slice(launch.start, launch.stop)
+        co = co_meta.get(i)
+        if blocks is None:
+            s = slice(launch.start, launch.stop)
+        elif i in blocks:
+            s = launch.start + blocks[i]
+            if co is not None:
+                co = {k: None if v is None else v[blocks[i]]
+                      for k, v in co.items()}
+        else:
+            continue
         with launch_scope(launch):
-            vals = _gather_launch_values(plan, launch, s, meta, mutable,
-                                         co_meta.get(i))
+            vals = _gather_launch_values(plan, launch, s, meta, mutable, co)
             rank = max((v.ndim for v in vals.values()), default=2)
             for e in seed.elementwise:
                 vals[e] = _expand_trailing(elem_exec[e][s], rank)
@@ -339,11 +352,20 @@ def _stage_b(plan: BlockPlan, meta, lanes: jnp.ndarray,
     end (DESIGN.md §3).  ``depth`` is the static tree depth covering the
     longest run (:func:`head_write_meta`)."""
     hv = lanes.reshape((-1,) + lanes.shape[2:])[meta["head_pos_rowsorted"]]
-    seed = plan.seed
-    seg = meta["head_row_seg"]
+    hv = _head_tree(hv, meta["head_row_seg"], depth, plan.seed.reduce)
+    return _write_rows(out_init, meta["head_unique_rows"],
+                       hv[meta["head_run_starts"]], plan.seed.reduce)
+
+
+def _head_tree(hv: jnp.ndarray, seg: jnp.ndarray, depth: int,
+               reduce: str) -> jnp.ndarray:
+    """The write-back's log-step tree over row-sorted heads: after
+    ``depth`` steps each row's first head holds the row's combined
+    value.  A head combines only with heads of its own ``seg``, so any
+    slice that holds whole runs gives their rows the same bits."""
     from repro.core.seed import REDUCE_OPS
-    op, _ = REDUCE_OPS[seed.reduce]
-    identity = reduce_identity_for(seed.reduce, hv.dtype)
+    op, _ = REDUCE_OPS[reduce]
+    identity = reduce_identity_for(reduce, hv.dtype)
     trailing = ((0, 0),) * (hv.ndim - 1)
     for k in range(depth):
         d = 1 << k
@@ -352,15 +374,20 @@ def _stage_b(plan: BlockPlan, meta, lanes: jnp.ndarray,
         seg_shift = jnp.pad(seg[d:], (0, d), constant_values=_SEG_PAD)
         hv = jnp.where(_expand_trailing(seg == seg_shift, hv.ndim),
                        op(hv, shifted), hv)
-    vals = hv[meta["head_run_starts"]]
-    rows = meta["head_unique_rows"]
-    if seed.reduce == "add":
-        return out_init.at[rows].add(vals)
-    if seed.reduce == "mul":
-        return out_init.at[rows].multiply(vals)
-    if seed.reduce == "max":
-        return out_init.at[rows].max(vals)
-    return out_init.at[rows].min(vals)
+    return hv
+
+
+def _write_rows(out: jnp.ndarray, rows: jnp.ndarray, vals: jnp.ndarray,
+                reduce: str, mode=None) -> jnp.ndarray:
+    """Fold each row's value into ``out`` with the reduce, one write a
+    row."""
+    if reduce == "add":
+        return out.at[rows].add(vals, mode=mode)
+    if reduce == "mul":
+        return out.at[rows].multiply(vals, mode=mode)
+    if reduce == "max":
+        return out.at[rows].max(vals, mode=mode)
+    return out.at[rows].min(vals, mode=mode)
 
 
 def head_write_meta(plan: BlockPlan) -> dict:
@@ -418,6 +445,122 @@ def _stage_b_dense(plan: BlockPlan, meta, lanes: jnp.ndarray,
         return jnp.maximum(out_init, acc[:n_out])
     acc = jnp.full(shape, identity, flat.dtype).at[rows].min(flat)
     return jnp.minimum(out_init, acc[:n_out])
+
+
+def term_struct(seed, mutable, elem_dtypes) -> tuple:
+    """``(dtype, trailing axes)`` of the seed's combine for these inputs:
+    the lanes' structure — int32 for the graph semirings, ``(D,)``
+    trailing for SpMM (DESIGN.md §8)."""
+    specs = {}
+    for g in seed.gathered:
+        a = jnp.asarray(mutable[g])
+        specs[g] = jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype)
+    rank = max((s.ndim for s in specs.values()), default=1)
+    for e in seed.elementwise:
+        specs[e] = jax.ShapeDtypeStruct((1,) * rank, elem_dtypes[e])
+    out = jax.eval_shape(seed.combine, specs)
+    return out.dtype, out.shape[1:]
+
+
+def identity_out(seed, out_len: int, mutable, elem_dtypes) -> jnp.ndarray:
+    """The reduce identity in the shape and dtype of a product's output:
+    ``out_len`` rows with the combine's trailing axes."""
+    dtype, trailing = term_struct(seed, mutable, elem_dtypes)
+    return jnp.full((out_len,) + trailing,
+                    reduce_identity_for(seed.reduce, dtype), dtype)
+
+
+def _fills_identity(run, seed, out_len: int, elem_dtypes):
+    """``run`` that takes ``out_init=None`` as the reduce identity, made
+    inside the program, so a wide product holds one output on the
+    device, not two.  It keeps ``run``'s name, and so the jitted
+    program's."""
+    @functools.wraps(run)
+    def apply(c, mutable, out_init):
+        if out_init is None:
+            out_init = identity_out(seed, out_len, mutable, elem_dtypes)
+        return run(c, mutable, out_init)
+    return apply
+
+
+def _lane_partitions(plan: BlockPlan, rows: ir.RowOrder | None, mutable,
+                     elem_dtypes) -> ir.LanePartitions | None:
+    """The row partitions this product runs in (None: one piece), from
+    the bytes of its lanes; sets the ``engine.lane_partitions`` and
+    ``engine.lane_bytes`` gauges (the largest partition's stream) when
+    the program is traced."""
+    dtype, trailing = term_struct(plan.seed, mutable, elem_dtypes)
+    lane = (plan.lane_width * math.prod(trailing)
+            * jnp.dtype(dtype).itemsize)
+    parts = rows.partitions(lane) if rows is not None else None
+    _metrics.set_gauge("engine.lane_partitions",
+                       parts.count if parts else 1)
+    _metrics.set_gauge("engine.lane_bytes", parts.lane_bytes if parts
+                       else plan.num_blocks * lane)
+    return parts
+
+
+def _run_partitions(plan: BlockPlan, rows: ir.RowOrder,
+                    parts: ir.LanePartitions, meta, stage_a, mutable,
+                    out_init: jnp.ndarray, depth: int) -> jnp.ndarray:
+    """Stage A and stage B of each row partition in turn, in one
+    ``fori_loop`` (DESIGN.md §8).  ``stage_a(mutable, blocks, counts)``
+    runs, of each launch ``l`` in ``blocks``, the blocks at launch
+    positions ``blocks[l]``, the first ``counts[l]`` of them real, and
+    returns their lanes in launch order.  Partitions are padded to equal
+    sizes: no head reads a pad block, and the per-tile window kernel
+    skips them (hub rows put most of a launch's blocks in a few
+    partitions); pad heads and pad rows are masked, their writes
+    dropped.  Each row's heads sit in one partition and run the same
+    tree and the same single write as in one piece, so the result is the
+    one-piece result bit for bit."""
+    n = plan.lane_width
+    live = [l for l, m in enumerate(parts.block_max) if m]
+    offset = np.zeros(len(parts.block_max), np.int64)
+    offset[live] = np.cumsum([0] + [parts.block_max[l] for l in live])[:-1]
+    orders = {l: jnp.pad(meta["row_order"][rows.starts[l]:rows.starts[l]
+                                            + len(rows.first[l])],
+                         (0, parts.block_max[l])) for l in live}
+    block_lo = jnp.asarray(parts.block_lo, jnp.int32)
+    count = jnp.asarray(np.maximum(parts.block_hi - parts.block_lo, 0),
+                        jnp.int32)
+    # row-order stream position -> position in the partition's lanes
+    base = jnp.asarray(np.asarray(rows.starts)[None, :] + parts.block_lo
+                       - offset[None, :], jnp.int32)
+    head_lo = jnp.asarray(parts.head_lo, jnp.int32)
+    run_lo = jnp.asarray(parts.run_lo, jnp.int32)
+    hmax, umax = parts.head_max, parts.run_max
+    heads = {"pos": jnp.pad(meta["head_rowpos"], (0, hmax)),
+             "seg": jnp.pad(meta["head_row_seg"], (0, hmax)),
+             "start": jnp.pad(meta["head_run_starts"], (0, umax)),
+             "row": jnp.pad(meta["head_unique_rows"], (0, umax))}
+    reduce = plan.seed.reduce
+
+    def body(k, out):
+        blocks = {l: jax.lax.dynamic_slice(orders[l], (block_lo[k, l],),
+                                           (parts.block_max[l],))
+                  for l in live}
+        lanes = stage_a(mutable, blocks, {l: count[k, l] for l in live})
+        with jax.named_scope(_trace.SCOPE_STAGE_B):
+            h0 = head_lo[k]
+            ok = jnp.arange(hmax) < head_lo[k + 1] - h0
+            q = jax.lax.dynamic_slice(heads["pos"], (h0,), (hmax,))
+            r = q // n
+            launch = sum((r >= s).astype(jnp.int32) for s in rows.starts[1:])
+            pos = jnp.where(ok, (r - base[k][launch]) * n + q % n, 0)
+            hv = lanes.reshape((-1,) + lanes.shape[2:])[pos]
+            seg = jnp.where(
+                ok, jax.lax.dynamic_slice(heads["seg"], (h0,), (hmax,)), -1)
+            hv = _head_tree(hv, seg, depth, reduce)
+            u0 = run_lo[k]
+            uok = jnp.arange(umax) < run_lo[k + 1] - u0
+            start = jax.lax.dynamic_slice(heads["start"], (u0,), (umax,))
+            row = jax.lax.dynamic_slice(heads["row"], (u0,), (umax,))
+            return _write_rows(out, jnp.where(uok, row, plan.out_len),
+                               hv[jnp.where(uok, start - h0, 0)], reduce,
+                               mode="drop")
+
+    return jax.lax.fori_loop(0, parts.count, body, out_init)
 
 
 def reorder_static(plan: BlockPlan, static_data: Mapping[str, np.ndarray]
@@ -556,7 +699,7 @@ def _emit_sweeper(plan, static_data, backend, interpret, fused, stage_b,
     if elem_exec is None:
         elem_exec = reorder_static(plan, static_data)
     elem_exec = dict(elem_exec)
-    wb_meta, depth = {}, 0
+    wb_meta, depth, rows = {}, 0, None
     if tree.stage_b == "dense":
         wb_meta["lane_rows"] = jnp.asarray(dense_head_rows(plan))
         write_back = _stage_b_dense
@@ -564,8 +707,16 @@ def _emit_sweeper(plan, static_data, backend, interpret, fused, stage_b,
         wb_meta = head_write_meta(plan)
         depth = wb_meta.pop("head_tree_depth")
         write_back = _stage_b
+        if tree.launches:
+            # what a lane-wide product's row partitions are cut from
+            rows = ir.RowOrder(tree)
+            wb_meta["row_order"] = jnp.asarray(rows.order)
+            wb_meta["head_rowpos"] = jnp.asarray(rows.head_rowpos)
     else:
         write_back = None            # "fold": segsum stage A+B are one op
+    elem_dtypes = {e: elem_exec[e].dtype for e in seed.elementwise}
+    fills = functools.partial(_fills_identity, seed=seed,
+                              out_len=plan.out_len, elem_dtypes=elem_dtypes)
 
     if backend == "jax":
         launches = tree.launches
@@ -580,10 +731,17 @@ def _emit_sweeper(plan, static_data, backend, interpret, fused, stage_b,
                   "elem": elem_exec, "co": co_meta}
 
         def run(c, mutable, out_init):
+            parts = _lane_partitions(plan, rows, mutable, elem_dtypes)
+            if parts is not None:
+                return _run_partitions(
+                    plan, rows, parts, c["meta"],
+                    lambda mut, blocks, _counts: _stage_a_jax(
+                        plan, c["meta"], c["elem"], mut, launches, c["co"],
+                        blocks), mutable, out_init, depth)
             lanes = _stage_a_jax(plan, c["meta"], c["elem"], mutable,
                                  launches, c["co"])
             return write_back(plan, c["meta"], lanes, out_init, depth)
-        return Sweep(run, consts, tree)
+        return Sweep(fills(run), consts, tree)
 
     if backend == "segsum":
         # CPU-optimal configuration of the same plan: the Data Transfer
@@ -635,7 +793,7 @@ def _emit_sweeper(plan, static_data, backend, interpret, fused, stage_b,
                                  num_segments=plan.out_len + 1)
             with jax.named_scope(_trace.SCOPE_STAGE_B):
                 return fold(out_init, red[:plan.out_len])
-        return Sweep(run_ss, consts, tree)
+        return Sweep(fills(run_ss), consts, tree)
 
     if backend == "pallas":
         from repro.kernels import common as kcommon
@@ -649,9 +807,16 @@ def _emit_sweeper(plan, static_data, backend, interpret, fused, stage_b,
         consts = {"meta": wb_meta, "stage_a": stage_consts}
 
         def run_pl(c, mutable, out_init):
+            parts = _lane_partitions(plan, rows, mutable, elem_dtypes)
+            if parts is not None:
+                return _run_partitions(
+                    plan, rows, parts, c["meta"],
+                    lambda mut, blocks, counts: stage_a(
+                        c["stage_a"], mut, blocks, counts),
+                    mutable, out_init, depth)
             lanes = stage_a(c["stage_a"], mutable)
             return write_back(plan, c["meta"], lanes, out_init, depth)
-        return Sweep(run_pl, consts, tree)
+        return Sweep(fills(run_pl), consts, tree)
 
     raise ValueError(f"unknown backend {backend!r}")
 
@@ -664,7 +829,9 @@ def make_executor(plan: BlockPlan, static_data: Mapping[str, np.ndarray],
                   donate: bool = False, coalesce: bool = False,
                   tree: ir.CodeTree | None = None,
                   kernel_params: Mapping[str, int] | None = None):
-    """Build a jitted executor ``fn(mutable: dict, out_init) -> out``.
+    """Build a jitted executor ``fn(mutable: dict, out_init) -> out``;
+    ``out_init=None`` folds into the reduce identity, made inside the
+    program.
 
     ``static_data`` holds the seed's *elementwise* (immutable, nnz-aligned)
     arrays in original order; they are reordered once here (Data Transfer)
@@ -871,7 +1038,11 @@ def make_sharded_executor(parts, static_data, mesh, *,
                           out_specs=_PS(axis))(c, mutable, padded)
         return unpad_rows(y, widths)
 
-    run = _executor(Sweep(run_full, consts), donate,
+    seed = parts[0].tree.plan.seed
+    elem_dtypes = {e: jax.dtypes.canonicalize_dtype(
+        np.asarray(static_data[e]).dtype) for e in seed.elementwise}
+    run = _executor(Sweep(_fills_identity(run_full, seed, sum(widths),
+                                          elem_dtypes), consts), donate,
                     backend=parts[0].tree.backend, shards=k)
     run.parts = parts
     run.mesh = mesh

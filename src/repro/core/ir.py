@@ -650,6 +650,132 @@ def _partition_plan_impl(tree: CodeTree, plan: BlockPlan, b: int, n: int,
     return out
 
 
+# ------------------------------------------------------- lane partitions
+# A product whose lane stream (every block's N lanes with their trailing
+# axes, in the term's dtype) would pass this many bytes runs as
+# consecutive row partitions inside its one program (DESIGN.md §8); at
+# or below it, as one piece.  The stream and the fallback's gathered
+# terms live side by side with a few copies of their size, so 768 MiB
+# keeps a 256-wide SpMM over the 6.9e7 nonzeros of a scale-21 Kronecker
+# graph (PERF.md) under 14.5 GB of a v5e chip's 16, with its operand and
+# two outputs held too; every D = 1 cell stays one piece (scale 21 is
+# 268 MB).
+LANE_STREAM_BYTES = 768 << 20
+
+
+def fits_one_piece(num_blocks: int, lane_bytes: int) -> bool:
+    """Whether a lane stream of ``num_blocks`` blocks of ``lane_bytes``
+    each runs as one piece."""
+    return num_blocks * lane_bytes <= LANE_STREAM_BYTES
+
+
+@dataclasses.dataclass(frozen=True)
+class LanePartitions:
+    """Row partitions ``[cuts[k], cuts[k + 1])`` of one lowered tree.
+
+    Partition ``k`` runs, of launch ``l``, the blocks at positions
+    ``block_lo[k, l]`` to ``block_hi[k, l]`` of that launch's row order
+    (:class:`RowOrder`), padded to ``block_max[l]``; its heads are
+    ``head_lo[k]`` to ``head_lo[k + 1]`` of the row-sorted heads
+    (padded to ``head_max``) and its distinct rows ``run_lo[k]`` to
+    ``run_lo[k + 1]`` (padded to ``run_max``).  ``lane_bytes`` is the
+    padded stream one partition holds."""
+    cuts: np.ndarray          # (P + 1,) row cuts
+    block_lo: np.ndarray      # (P, L)
+    block_hi: np.ndarray      # (P, L)
+    block_max: tuple
+    head_lo: np.ndarray       # (P + 1,)
+    head_max: int
+    run_lo: np.ndarray        # (P + 1,)
+    run_max: int
+    lane_bytes: int
+
+    @property
+    def count(self) -> int:
+        return len(self.cuts) - 1
+
+
+class RowOrder:
+    """The row structure of one lowered tree that row partitions are cut
+    from; none of it depends on the lane width.
+
+    ``order`` holds, over each launch's exec range, that launch's block
+    positions sorted by the first and then the last row their heads
+    write, so the blocks a row range needs — those writing a row in it —
+    lie in one contiguous run of it (for row-major input exactly; in
+    general within the run up to the last block that starts in the
+    range, from the first whose running last row reaches it).  A block
+    that writes rows on both sides of a cut runs in both partitions, to
+    the same bits.  ``head_rowpos`` is each head's lane position in that
+    row-ordered stream, for the heads in the write-back's row-sorted
+    order (:func:`repro.core.engine.head_write_meta`)."""
+
+    def __init__(self, tree: CodeTree):
+        plan = tree.plan
+        n, b = plan.lane_width, plan.num_blocks
+        self.out_len = plan.out_len
+        self.num_blocks = b
+        self.starts = [launch.start for launch in tree.launches]
+        first, last = _block_row_spans(plan)     # out_len, -1: no heads
+        last = np.where(last < 0, plan.out_len, last)
+        order = np.zeros(b, np.int64)
+        rank = np.zeros(b, np.int64)
+        self.first, self.last = [], []
+        for launch in tree.launches:
+            s = slice(launch.start, launch.stop)
+            o = np.lexsort((last[s], first[s]))
+            order[s] = o
+            rank[launch.start + o] = np.arange(launch.start, launch.stop)
+            self.first.append(first[s][o])
+            self.last.append(np.maximum.accumulate(last[s][o]))
+        hord = np.argsort(plan.head_rows, kind="stable")
+        hp = plan.head_pos[hord]
+        self.order = order.astype(np.int32)
+        self.head_rowpos = (rank[hp // n] * n + hp % n).astype(np.int32)
+        self.head_rows = plan.head_rows[hord]
+        self.run_rows = np.unique(self.head_rows)
+        # blocks by the row their heads start at, below each row cut
+        self.load = np.concatenate([[0], np.cumsum(np.bincount(
+            first[first < plan.out_len], minlength=plan.out_len))])
+
+    def partitions(self, lane_bytes: int) -> LanePartitions | None:
+        """The row partitions of a product whose block lanes take
+        ``lane_bytes`` each, or None where it runs as one piece.  The
+        count starts at the fewest that could fit
+        :data:`LANE_STREAM_BYTES` and grows until each partition's
+        padded stream does (or each partition is one row), partitions
+        balanced by the blocks whose heads start in them."""
+        if fits_one_piece(self.num_blocks, lane_bytes):
+            return None
+        count = min(self.out_len, -(-self.num_blocks * lane_bytes
+                                    // LANE_STREAM_BYTES))
+        while True:
+            parts = self._table(count, lane_bytes)
+            if (parts.lane_bytes <= LANE_STREAM_BYTES
+                    or count >= self.out_len):
+                return parts
+            count = min(self.out_len, count + max(1, count // 8))
+
+    def _table(self, count: int, lane_bytes: int) -> LanePartitions:
+        targets = self.load[-1] * np.arange(1, count) / count
+        cuts = np.unique(np.concatenate(
+            [[0], np.searchsorted(self.load, targets), [self.out_len]]))
+        block_lo = np.stack([np.searchsorted(k, cuts[:-1])
+                             for k in self.last], axis=1)
+        block_hi = np.stack([np.searchsorted(k, cuts[1:])
+                             for k in self.first], axis=1)
+        block_max = tuple(int(m) for m in
+                          np.maximum(block_hi - block_lo, 0).max(0))
+        head_lo = np.searchsorted(self.head_rows, cuts)
+        run_lo = np.searchsorted(self.run_rows, cuts)
+        return LanePartitions(
+            cuts=cuts, block_lo=block_lo, block_hi=block_hi,
+            block_max=block_max,
+            head_lo=head_lo, head_max=int(np.diff(head_lo).max()),
+            run_lo=run_lo, run_max=int(np.diff(run_lo).max()),
+            lane_bytes=sum(block_max) * lane_bytes)
+
+
 def coalesced_fraction(tree: CodeTree) -> float:
     """Share of nnz served by dense-slice loads after lowering — the
     benchmark-visible reach of :func:`coalesce_gathers` (BENCH_spmv.json

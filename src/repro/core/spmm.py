@@ -156,11 +156,9 @@ class SpMM:
 
     def matmat(self, bmat: jnp.ndarray,
                y_init: jnp.ndarray | None = None) -> jnp.ndarray:
-        if y_init is None:
-            from repro.core.seed import reduce_identity_for
-            y_init = jnp.full((self.shape[0], bmat.shape[1]),
-                              reduce_identity_for(self.reduce, bmat.dtype),
-                              bmat.dtype)
+        """``Y = A @ bmat`` folded into ``y_init``.  Without ``y_init``
+        the reduce identity is made inside the product's program, so a
+        wide product holds one ``Y`` on the device, not two."""
         return self._run({"x": bmat}, y_init)
 
     def report(self):

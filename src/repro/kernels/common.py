@@ -17,6 +17,8 @@ slot/offset/segment metadata stays 2-D and broadcasts — the same
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
@@ -63,7 +65,7 @@ def permute_tiles(tiles, slot: jnp.ndarray, offset: jnp.ndarray
     ``offset[r, j]`` of row ``r`` of tile ``slot[r, j]``.
 
     ``slot``/``offset`` are ``(rows, N)`` int32.  Each tile is permuted
-    inside the lane axis (``take_along_axis`` — an in-register lane
+    inside the lane axis (a batched lane gather — an in-register lane
     shuffle on TPU, no memory gather) and the ``M`` permuted tiles are
     merged by a select chain on ``slot``.  Every lane returns the selected
     word bit for bit, for every dtype (no arithmetic touches the payload,
@@ -72,24 +74,52 @@ def permute_tiles(tiles, slot: jnp.ndarray, offset: jnp.ndarray
 
     Rank rule: trailing axes ride along unchanged — every lane selects a
     whole ``(...,)`` value row (SpMM fetches rows of B), and the 2-D lane
-    metadata broadcasts over them."""
-    ndim = tiles[0].ndim
-    idx = jnp.broadcast_to(expand_trailing(offset.astype(jnp.int32), ndim),
-                           tiles[0].shape)
-    slot = expand_trailing(slot.astype(jnp.int32), ndim)
+    metadata broadcasts over them.  With trailing axes the tiles are
+    turned on their side (:func:`to_side`) and permuted by the 2-D lane
+    gather, the only one Mosaic lowers."""
+    if tiles[0].ndim > 2:
+        d = math.prod(tiles[0].shape[2:])
+        out = permute_tiles([to_side(t) for t in tiles],
+                            side_meta(slot, d), side_meta(offset, d))
+        return from_side(out, tiles[0].shape)
+    idx = jnp.broadcast_to(offset.astype(jnp.int32), tiles[0].shape)
+    slot = slot.astype(jnp.int32)
     out = jnp.zeros(tiles[0].shape, tiles[0].dtype)
     for w, tile in enumerate(tiles):
         out = jnp.where(slot == w, _lane_gather(tile, idx), out)
     return out
 
 
+def to_side(a: jnp.ndarray) -> jnp.ndarray:
+    """``(rows, N, ...)`` lanes with trailing value axes of ``D`` words
+    -> ``(rows * D, N)``: each value column of each block a lane row of
+    its own, so lanes are the minor axis again and the 2-D lane
+    permutes, rolls and reductions apply to every width, column by
+    column."""
+    rows, n = a.shape[:2]
+    d = math.prod(a.shape[2:])
+    return jnp.swapaxes(a.reshape(rows, n, d), 1, 2).reshape(rows * d, n)
+
+
+def side_meta(m: jnp.ndarray, d: int) -> jnp.ndarray:
+    """``(rows, N)`` lane metadata -> ``(rows * d, N)``, repeated for each
+    of a block's ``d`` value columns (the layout of :func:`to_side`)."""
+    rows, n = m.shape
+    return jnp.broadcast_to(m[:, None, :], (rows, d, n)).reshape(rows * d, n)
+
+
+def from_side(a: jnp.ndarray, shape: tuple) -> jnp.ndarray:
+    """The inverse of :func:`to_side`, back to ``shape``."""
+    rows, n = shape[:2]
+    d = math.prod(shape[2:])
+    return jnp.swapaxes(a.reshape(rows, d, n), 1, 2).reshape(shape)
+
+
 def _lane_gather(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """``out[r, j, ...] = x[r, idx[r, j, ...], ...]`` with in-bounds
-    indices.  The 2-D case is spelled as the batched lane gather Mosaic
-    lowers to ``tpu.dynamic_gather`` (``jnp.take_along_axis`` picks
-    another form when the leading dim is 1)."""
-    if x.ndim != 2:
-        return jnp.take_along_axis(x, idx, axis=1)
+    """``out[r, j] = x[r, idx[r, j]]`` with in-bounds indices, spelled as
+    the batched lane gather Mosaic lowers to ``tpu.dynamic_gather``
+    (``jnp.take_along_axis`` picks another form when the leading dim is
+    1)."""
     dnums = jax.lax.GatherDimensionNumbers(
         offset_dims=(), collapsed_slice_dims=(1,), start_index_map=(1,),
         operand_batching_dims=(0,), start_indices_batching_dims=(0,))

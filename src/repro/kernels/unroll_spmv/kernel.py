@@ -159,7 +159,8 @@ def _chunked(call, bc: int, chunk: int, per_block: list, widths: list):
 
 
 def _combine_lanes(win_vals: dict, elem_vals: dict, combine: Callable,
-                   seg: jnp.ndarray, op: int, mixed, reduce: str, zero):
+                   seg: jnp.ndarray, op: int, mixed, reduce: str, zero,
+                   side: int = 0):
     """Shared ladder tail on ``(rows, N, ...)`` lane slabs, one block per
     row: broadcast elementwise lanes up to the gathered rank (§8), combine,
     shift-reduce, and resolve the fused-mixed native-reduction select.
@@ -167,7 +168,16 @@ def _combine_lanes(win_vals: dict, elem_vals: dict, combine: Callable,
     slab holding each row's flag) or None.  ``zero`` is None on the chip;
     in interpret mode it is a runtime 0 for :func:`common.round_term`
     (the min of a window id or slice start, never negative, and 0), and
-    the full reduction runs as the halving-tree butterfly."""
+    the full reduction runs as the halving-tree butterfly.  ``side`` is
+    ``D`` where the gathered lanes come on their side (:func:`_permute`):
+    the metadata is repeated to match and the result stays on its
+    side."""
+    if side:
+        seg = common.side_meta(seg, side)
+        elem_vals = {e: common.side_meta(v, side)
+                     for e, v in elem_vals.items()}
+        if mixed is not None and mixed.ndim:
+            mixed = common.side_meta(mixed, side)
     vals = dict(win_vals)
     rank = max((v.ndim for v in vals.values()), default=2)
     for e, v in elem_vals.items():
@@ -188,6 +198,27 @@ def _combine_lanes(win_vals: dict, elem_vals: dict, combine: Callable,
     return red
 
 
+def _side(shape: tuple) -> int:
+    """The value columns ``D`` of lanes with trailing axes, 0 without."""
+    return math.prod(shape[2:]) if len(shape) > 2 else 0
+
+
+def _permute(tiles: list, slot, off, stream: bool):
+    """One gathered array's lanes from its window tiles: the permute,
+    or the first tile for ``stream``.  Tiles with trailing lane axes
+    (SpMM's ``(N, D)`` lanes) are turned on their side
+    (``common.to_side``) and stay so: Mosaic lowers the lane permute,
+    rolls and masks in 2-D only, and on its side each value column takes
+    the ops of a ``D = 1`` lane row, in the same order, which is what the
+    XLA emitter computes per column."""
+    d = _side(tiles[0].shape)
+    if d:
+        tiles = [common.to_side(t) for t in tiles]
+        if not stream:
+            slot, off = common.side_meta(slot, d), common.side_meta(off, d)
+    return tiles[0] if stream else common.permute_tiles(tiles, slot, off)
+
+
 def _rows(a: jnp.ndarray, p: int) -> jnp.ndarray:
     """``(Bc, N, ...) -> (Bc // p, p, N, ...)``: per-block lane rows laid
     out so a ``(None, p, N, ...)`` block's last two dims are whole array
@@ -202,14 +233,28 @@ def _tiles(view: jnp.ndarray) -> jnp.ndarray:
 
 
 # ------------------------------------------------------- TPU window form
-def _stage_a_body(base_ref, win_ref, flag_ref, *refs, combine: Callable,
+def _stage_a_body(base_ref, win_ref, flag_ref, *refs, pads: bool, **kw):
+    """Kernel body. ``refs`` layout:
+    [g0_win0..g0_win{ls-1}, g1_win0.., ...] + [elem...] +
+    [slot, offset, seg] + [out].  With ``pads`` the steps from
+    ``base_ref[1] - base_ref[0]`` on are pads and run nothing."""
+    # the step index is read out here: interpret mode binds it only at
+    # the body's top level, not inside ``pl.when``
+    b = pl.program_id(0)
+    step = functools.partial(_stage_a_step, b, win_ref, flag_ref, *refs,
+                             **kw)
+    if pads:
+        pl.when(b < base_ref[1] - base_ref[0])(step)
+    else:
+        step()
+
+
+def _stage_a_step(b, win_ref, flag_ref, *refs, combine: Callable,
                   gathered: tuple, elementwise: tuple, ls: int, op: int,
                   stream: bool, mixed: bool, reduce: str, out_dtype,
                   meta_prefetch: int, interpret: bool):
-    """Kernel body. ``refs`` layout:
-    [g0_win0..g0_win{ls-1}, g1_win0.., ...] + [elem...] +
-    [slot, offset, seg] + [out]."""
-    del base_ref                 # consumed by the index maps only
+    """Step ``b``'s block of the per-tile form (refs as
+    :func:`_stage_a_body`)."""
     n_g = len(gathered)
     n_e = len(elementwise)
     win_refs = refs[: n_g * ls]
@@ -222,7 +267,7 @@ def _stage_a_body(base_ref, win_ref, flag_ref, *refs, combine: Callable,
     else:
         # metadata arrives in (meta_prefetch, N) tiles — fewer, larger
         # DMAs; this step's row is selected dynamically inside VMEM
-        i = pl.program_id(0) % meta_prefetch
+        i = b % meta_prefetch
         slot = slot_ref[pl.ds(i, 1)]
         off = off_ref[pl.ds(i, 1)]
         seg = seg_ref[pl.ds(i, 1)]
@@ -230,12 +275,15 @@ def _stage_a_body(base_ref, win_ref, flag_ref, *refs, combine: Callable,
     vals = {}
     for gi, g in enumerate(gathered):
         tiles = [win_refs[gi * ls + k][...] for k in range(ls)]
-        vals[g] = tiles[0] if stream else common.permute_tiles(tiles, slot,
-                                                               off)
+        vals[g] = _permute(tiles, slot, off, stream)
     elem_vals = {e: elem_refs[ei][...] for ei, e in enumerate(elementwise)}
-    flag = flag_ref[pl.program_id(0)] if mixed else None
+    flag = flag_ref[b] if mixed else None
+    side = _side(out_ref.shape)
     red = _combine_lanes(vals, elem_vals, combine, seg, op, flag, reduce,
-                         jnp.minimum(win_ref[0], 0) if interpret else None)
+                         jnp.minimum(win_ref[0], 0) if interpret else None,
+                         side)
+    if side:
+        red = common.from_side(red, out_ref.shape)
     out_ref[...] = red.astype(out_dtype)
 
 
@@ -274,6 +322,7 @@ def _resident_body(win_ref, *refs, combine: Callable, gathered: tuple,
     flag_scr = refs[k + 2 + n_g] if mixed else None
     valid = jnp.minimum(rows, bc - pl.program_id(0) * rows)
     zero = jnp.minimum(win_ref[0], 0) if interpret else None
+    side = _side(out_ref.shape)
 
     def run_group(q, carry):
         if rows % group:
@@ -303,13 +352,15 @@ def _resident_body(win_ref, *refs, combine: Callable, gathered: tuple,
         vals = {}
         for gi, g in enumerate(gathered):
             tiles = [scr_refs[gi][j] for j in range(ls)]
-            vals[g] = tiles[0] if stream else common.permute_tiles(
-                tiles, slot_ref[sl], off_ref[sl])
+            vals[g] = _permute(tiles, None if stream else slot_ref[sl],
+                               None if stream else off_ref[sl], stream)
         elem_vals = {e: elem_refs[ei][sl]
                      for ei, e in enumerate(elementwise)}
         flag = flag_scr[...] if mixed else None
         red = _combine_lanes(vals, elem_vals, combine, seg_ref[sl], op,
-                             flag, reduce, zero)
+                             flag, reduce, zero, side)
+        if side:
+            red = common.from_side(red, (group,) + out_ref.shape[1:])
         out_ref[sl] = red.astype(out_dtype)
         return carry
 
@@ -447,7 +498,8 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
                   out_dtype=jnp.float32, out_trailing: tuple = (),
                   interpret: bool | None = None,
                   meta_prefetch: int = 1,
-                  platform: str | None = None) -> jnp.ndarray:
+                  platform: str | None = None,
+                  live: jnp.ndarray | None = None) -> jnp.ndarray:
     """Launch stage A for one pattern class / fused section in the
     per-tile form: one block per grid step, its ``ls`` window tiles DMA'd
     from HBM.  The form for views over the resident budget
@@ -465,6 +517,11 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
                    is the largest divisor of Bc — a tuned kernel param)
     platform       lowering form override; default ``jax.default_backend()``
                    (gpu -> Triton form, otherwise TPU/interpret form)
+    live           traced count of the leading blocks that are real, or
+                   None (all are): the rest are a row partition's pads,
+                   which no head reads (``engine._run_partitions``).  A
+                   pad step keeps the last real block's indices, so no
+                   DMA runs, and skips the body; its lanes stay unwritten
     returns        (Bc, N, ...) post-reduce lane matrix
     """
     interpret = common.resolve_interpret(interpret)
@@ -485,8 +542,17 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
                              gathered=gathered, elementwise=elementwise,
                              ls=ls, op=op, stream=stream, mixed=mixed,
                              reduce=reduce, out_dtype=out_dtype,
-                             meta_prefetch=p, interpret=interpret)
+                             meta_prefetch=p, interpret=interpret,
+                             pads=live is not None)
     z = len(out_trailing)
+    if live is None:
+        def at(b, base):
+            return b
+    else:
+        def at(b, base):
+            # a pad step keeps the chunk's last real block (its first
+            # where it has none)
+            return jnp.minimum(b, jnp.maximum(base[1] - base[0] - 1, 0))
 
     in_specs = []
     operands = []
@@ -495,28 +561,32 @@ def class_stage_a(win_ids: jnp.ndarray, gathered_views: dict,
         tz = view.ndim - 3
         for k in range(ls):
             def im(b, base, w, f, k=k, tz=tz):
-                return (w[b * ls + k], 0, 0) + (0,) * tz
+                return (w[at(b, base) * ls + k], 0, 0) + (0,) * tz
             in_specs.append(pl.BlockSpec((None,) + view.shape[1:], im))
             operands.append(view)
     for e in elementwise:
-        in_specs.append(pl.BlockSpec((None, 1, n),
-                                     lambda b, base, w, f: (base[0] + b, 0,
-                                                            0)))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, n),
+            lambda b, base, w, f: (base[0] + at(b, base), 0, 0)))
         operands.append(_rows(elem_blocks[e], 1))
     for meta in (slot, off, seg):
         in_specs.append(pl.BlockSpec(
             (None, p, n),
-            lambda b, base, w, f, p=p: ((base[0] + b) // p, 0, 0)))
+            lambda b, base, w, f, p=p: ((base[0] + at(b, base)) // p, 0,
+                                        0)))
         operands.append(_rows(meta, p))
 
     def call(n_blocks, base, win, flags):
+        if live is not None:
+            base = jnp.concatenate(
+                [base, jnp.reshape(live, (1,)).astype(jnp.int32)])
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_blocks,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
                 (None, 1, n) + out_trailing,
-                lambda b, base, w, f: (b, 0, 0) + (0,) * z),
+                lambda b, base, w, f: (at(b, base), 0, 0) + (0,) * z),
         )
         out = pl.pallas_call(
             body, grid_spec=grid_spec,

@@ -38,7 +38,6 @@ window launches that run the resident form.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import engine as eng
@@ -52,20 +51,18 @@ from repro.kernels.unroll_spmv.kernel import (class_stage_a,
 from repro.obs import metrics as _metrics
 
 
-def _term_struct(seed, mutable, elem_dtypes):
-    """Shape/dtype of the seed's combine expression for these inputs — the
-    kernel's lane/output structure: dtype (int32 for the graph semirings;
-    the old hard-coded float32 silently corrupted large int values) AND
-    trailing lane axes (SpMM's ``(N, D)`` lanes, DESIGN.md §8)."""
-    specs = {}
-    for g in seed.gathered:
-        a = jnp.asarray(mutable[g])
-        specs[g] = jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype)
-    rank = max((s.ndim for s in specs.values()), default=1)
-    for e in seed.elementwise:
-        specs[e] = jax.ShapeDtypeStruct((1,) * rank, elem_dtypes[e])
-    out = jax.eval_shape(seed.combine, specs)
-    return out.dtype, out.shape[1:]
+def _select_blocks(cm: dict, ids: jnp.ndarray) -> dict:
+    """A launch's staged operands for its blocks at positions ``ids`` (a
+    row partition's, ``engine._run_partitions``)."""
+    out = dict(cm)
+    for k, v in cm.items():
+        if k == "elem":
+            out[k] = {e: a[ids] for e, a in v.items()}
+        elif k == "win":
+            out[k] = v.reshape(cm["seg"].shape[0], -1)[ids].reshape(-1)
+        elif v is not None and k != "zero":
+            out[k] = v[ids]
+    return out
 
 
 def make_stage_a(plan: BlockPlan, elem_exec,
@@ -75,7 +72,12 @@ def make_stage_a(plan: BlockPlan, elem_exec,
     """Stage each launch's kernel operands on the device and return
     ``(consts, stage_a)``: ``stage_a(consts, mutable) -> (B, N, ...)``
     lanes in exec-block order.  ``consts`` is passed in by the jitted
-    caller, never closed over (see :class:`repro.core.engine.Sweep`)."""
+    caller, never closed over (see :class:`repro.core.engine.Sweep`).
+    ``stage_a(consts, mutable, blocks, counts)`` runs, of each launch
+    ``i`` in ``blocks``, only its blocks at positions ``blocks[i]``, of
+    which the first ``counts[i]`` are real and the rest pads (a row
+    partition, ``engine._run_partitions``): the per-tile window form
+    skips the pads."""
     seed = plan.seed
     interpret = common.resolve_interpret(interpret)
     kp = kernel_params or {}
@@ -113,7 +115,7 @@ def make_stage_a(plan: BlockPlan, elem_exec,
              for launch in launches]
 
     def launch_lanes(launch, cm, views, mutable, out_dtype, out_trailing,
-                     steps):
+                     steps, live):
         elem_blocks = cm["elem"]
         if launch.gather == ir.FALLBACK and seed.gather_index is not None:
             # native gather path (XLA) + in-XLA segmented reduce
@@ -152,21 +154,28 @@ def make_stage_a(plan: BlockPlan, elem_exec,
                                     steps=steps, **kw)
         win = cm["win"].reshape(cm["seg"].shape[0], -1)
         return class_stage_a(win, views, elem_blocks, cm["slot"], cm["off"],
-                             cm["seg"], meta_prefetch=meta_prefetch, **kw)
+                             cm["seg"], meta_prefetch=meta_prefetch,
+                             live=live, **kw)
 
-    def stage_a(consts, mutable):
+    def stage_a(consts, mutable, blocks=None, counts=None):
         views = {g: eng._pad_gathered(plan, jnp.asarray(mutable[g]))
                  for g in seed.gathered}
-        out_dtype, out_trailing = _term_struct(seed, mutable, elem_dtypes)
+        out_dtype, out_trailing = eng.term_struct(seed, mutable,
+                                                  elem_dtypes)
         parts = []
         resident = 0
-        for launch, cm, n_valid in zip(launches, consts, valid):
+        for i, (launch, cm, n_valid) in enumerate(zip(launches, consts,
+                                                       valid)):
+            if blocks is not None and i not in blocks:
+                continue
+            bc = launch.stop - launch.start if blocks is None \
+                else blocks[i].shape[0]
             # a window launch's form follows the bytes of its views and
             # step, known once the views are
             steps = None
             if launch.gather in (ir.WINDOW, ir.STREAM):
                 steps = resident_steps(
-                    views, blocks=launch.stop - launch.start,
+                    views, blocks=bc,
                     ls=max(launch.ls_flag, 1), mixed=cm["full"] is not None,
                     stream=launch.stream, elementwise=len(seed.elementwise),
                     out_dtype=out_dtype, out_trailing=out_trailing,
@@ -174,8 +183,11 @@ def make_stage_a(plan: BlockPlan, elem_exec,
                 resident += n_valid if steps is not None else 0
             # each launch's ops run under its kind's device scope
             with eng.launch_scope(launch):
-                parts.append(launch_lanes(launch, cm, views, mutable,
-                                          out_dtype, out_trailing, steps))
+                if blocks is not None:
+                    cm = _select_blocks(cm, blocks[i])
+                parts.append(launch_lanes(
+                    launch, cm, views, mutable, out_dtype, out_trailing,
+                    steps, None if counts is None else counts[i]))
         _metrics.set_gauge("engine.nnz.window_resident", resident)
         if not parts:      # empty plan (nnz == 0): no launches, no lanes
             return jnp.zeros((0, plan.lane_width) + out_trailing, out_dtype)

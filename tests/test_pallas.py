@@ -239,6 +239,48 @@ def test_gpu_form_bitwise_vs_tpu_form():
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+# --------------------------------------------------- per-tile pad steps
+@pytest.mark.parametrize("meta_prefetch,prefetch_words", [(1, 1 << 15),
+                                                          (4, 64)])
+def test_per_tile_form_skips_pads_and_keeps_real_blocks(
+        meta_prefetch, prefetch_words, monkeypatch):
+    """``live`` blocks of a launch are real and the rest pads (a row
+    partition's): every real block's lanes are those of the launch run
+    whole, bit for bit, in one prefetch chunk or several."""
+    from repro.kernels.unroll_spmv import kernel
+    monkeypatch.setattr(kernel, "PREFETCH_WORDS", prefetch_words)
+    m = G.banded(512, 5)
+    plan = _plan_for(m)
+    seed = plan.seed
+    launch = next(l for l in ir.lower(plan, backend="pallas",
+                                      fused=True).launches
+                  if l.gather != ir.FALLBACK)
+    s = slice(launch.start, launch.stop)
+    ls = max(launch.ls_flag, 1)
+    mask = launch.full_mask
+    vals, x = _spmv_problem(m, np.float32)
+    args = (jnp.asarray(plan.window_ids[s][:, :ls], jnp.int32),
+            {"x": eng._pad_gathered(plan, jnp.asarray(x))},
+            {"value": eng.reorder_elementwise(plan, vals)[s]},
+            jnp.asarray(plan.lane_slot[s], jnp.int32),
+            jnp.asarray(plan.lane_offset[s], jnp.int32),
+            jnp.asarray(plan.seg_ids[s], jnp.int32))
+    kw = dict(combine=seed.combine, gathered=seed.gathered,
+              elementwise=seed.elementwise, ls=ls, op=launch.op_flag,
+              stream=launch.stream, reduce=seed.reduce,
+              full_flags=None if mask is None else jnp.asarray(mask,
+                                                               jnp.int32),
+              out_dtype=jnp.float32, out_trailing=(), interpret=True,
+              meta_prefetch=meta_prefetch)
+    bc = launch.stop - launch.start
+    assert bc > 8
+    whole = np.asarray(kernel.class_stage_a(*args, **kw))
+    for live in (0, 1, bc // 2 + 1, bc):
+        out = np.asarray(kernel.class_stage_a(*args, live=jnp.int32(live),
+                                              **kw))
+        np.testing.assert_array_equal(out[:live], whole[:live])
+
+
 # -------------------------------------------------- kernel-param stability
 @pytest.mark.parametrize("coalesce", [False, True])
 def test_kernel_params_bitwise_stable(coalesce):

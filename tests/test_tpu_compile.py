@@ -27,12 +27,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import engine as eng
+from repro.core import ir
 from repro.core.plan import CostModel, build_plan
 from repro.core.seed import spmv_seed
 from repro.kernels.unroll_spmv.kernel import (class_stage_a,
                                               coalesced_stage_a,
                                               resident_stage_a,
                                               resident_steps)
+from repro.obs import metrics
 from repro.sparse import generators as G
 
 N = 128
@@ -144,6 +146,50 @@ def test_wide_window_kernel_compiles_for_v5e(one_chip, case, resident):
     assert _compile_window(one_chip, case, WINDOWS) is resident
 
 
+# SpMM lanes of D = 256 float32 words (OGB's GCN hidden width): the
+# per-tile form over a 2 GiB view (2^21 rows of H, the scale-21 cell's)
+# and the resident form over a 16 MB one; fused mixed sections
+D_WIDE = 256
+WIDE_LANE_CASES = {
+    # (blocks, windows, ls)
+    "per_tile": (4096, 1 << 14, 32),
+    "resident": (64, 128, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WIDE_LANE_CASES))
+def test_window_kernel_with_trailing_lanes_compiles_for_v5e(one_chip, case):
+    """The lane permute, ladder and native reduce of ``(rows, N, D)``
+    tiles lower, on their side, in both window forms."""
+    blocks, windows, ls = WIDE_LANE_CASES[case]
+    dt = jnp.float32
+    steps = resident_steps(
+        {"x": jax.ShapeDtypeStruct((windows, N, D_WIDE), dt)},
+        blocks=blocks, ls=ls, mixed=True, stream=False, elementwise=1,
+        out_dtype=dt, out_trailing=(D_WIDE,), interpret=False,
+        platform="tpu")
+    assert (steps is not None) is (case == "resident")
+
+    def stage_a(win, view, elem, slot, off, seg, flags):
+        kw = dict(combine=_spmv_combine, gathered=("x",),
+                  elementwise=("value",), ls=ls, op=7, stream=False,
+                  reduce="add", full_flags=flags, out_dtype=dt,
+                  out_trailing=(D_WIDE,), interpret=False)
+        args = (win, {"x": view}, {"value": elem}, slot, off, seg)
+        if steps is not None:
+            return resident_stage_a(*args, steps=steps, **kw)
+        return class_stage_a(*args, platform="tpu", **kw)
+
+    i32 = jnp.int32
+    compiled = jax.jit(stage_a).lower(
+        _sds((blocks, ls), i32, one_chip),
+        _sds((windows, N, D_WIDE), dt, one_chip),
+        _sds((blocks, N), dt, one_chip), _sds((blocks, N), i32, one_chip),
+        _sds((blocks, N), i32, one_chip), _sds((blocks, N), i32, one_chip),
+        _sds((blocks,), i32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("reduce", ["add", "min"])
 @pytest.mark.parametrize("strided,rows_per_step", [(False, 1), (True, 1),
                                                    (True, 4)])
@@ -194,6 +240,51 @@ def test_spmv_executor_compiles_for_v5e(one_chip, powerlaw_plan, backend,
         _sds((m.shape[0],), jnp.float32, one_chip)).compile()
     if backend == "pallas":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_partitioned_spmm_executor_compiles_for_v5e(one_chip, powerlaw_plan,
+                                                   monkeypatch):
+    """A product of D = 256 lanes over the lane budget: the executor runs
+    it in row partitions (one ``fori_loop``, a window launch and the
+    fallback per partition), and the program's scratch stays within a
+    few partitions' lane streams."""
+    m, plan = powerlaw_plan
+    monkeypatch.setattr(ir, "LANE_STREAM_BYTES", 64 << 20)
+    run = eng.make_executor(plan, {"value": m.vals}, backend="pallas",
+                            interpret=False)
+    consts = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), run.sweep_body.consts)
+    compiled = run.jitted.lower(
+        consts, {"x": _sds((m.shape[1], D_WIDE), jnp.float32, one_chip)},
+        _sds((m.shape[0], D_WIDE), jnp.float32, one_chip)).compile()
+    assert metrics.gauge_value("engine.lane_partitions") > 1
+    lane_bytes = metrics.gauge_value("engine.lane_bytes")
+    assert lane_bytes <= ir.LANE_STREAM_BYTES
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * lane_bytes
+
+
+def test_partitioned_spmm_executor_skips_pads_per_tile_for_v5e(
+        one_chip, powerlaw_plan, monkeypatch):
+    """The same partitioned product with the window launch in the
+    per-tile form, whose steps past a partition's real blocks keep the
+    last real block's indices and skip the body: the clamped index maps
+    and the guarded body lower."""
+    from repro.kernels.unroll_spmv import kernel
+    m, plan = powerlaw_plan
+    monkeypatch.setattr(ir, "LANE_STREAM_BYTES", 64 << 20)
+    monkeypatch.setattr(kernel, "RESIDENT_VIEW_BYTES", 0)
+    run = eng.make_executor(plan, {"value": m.vals}, backend="pallas",
+                            interpret=False)
+    consts = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip), run.sweep_body.consts)
+    compiled = run.jitted.lower(
+        consts, {"x": _sds((m.shape[1], D_WIDE), jnp.float32, one_chip)},
+        _sds((m.shape[0], D_WIDE), jnp.float32, one_chip)).compile()
+    assert metrics.gauge_value("engine.lane_partitions") > 1
+    assert metrics.gauge_value("engine.nnz.window") > 0
+    assert metrics.gauge_value("engine.nnz.window_resident") == 0
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_plan_arrays_are_operands_not_constants(powerlaw_plan):
