@@ -631,8 +631,9 @@ def _record_nnz(trees) -> None:
     """Set the ``engine.nnz.{window,coalesced,fallback}`` gauges: the
     valid (unpadded) nonzeros of the trees' launches of each kind.  The
     segsum form runs every block through its one per-element gather.
-    ``engine.nnz.window_resident`` restarts at 0: the Pallas stage A sets
-    it when its program is traced, once the views' bytes are known."""
+    ``engine.nnz.window_resident`` and ``engine.nnz.fallback_vmem``
+    restart at 0: the Pallas stage A sets them when its program is
+    traced, once the views' bytes and the lanes' shape are known."""
     nnz = {"window": 0, "coalesced": 0, "fallback": 0}
     for tree in trees:
         valid = tree.plan.valid
@@ -643,6 +644,7 @@ def _record_nnz(trees) -> None:
     for kind, n in nnz.items():
         _metrics.set_gauge(f"engine.nnz.{kind}", n)
     _metrics.set_gauge("engine.nnz.window_resident", 0)
+    _metrics.set_gauge("engine.nnz.fallback_vmem", 0)
 
 
 @_trace.traced("engine.build_sweeper")

@@ -56,6 +56,11 @@ Four lowering forms share the ladder body:
     window tiles are fetched with in-kernel dynamic ``pl.ds`` loads from
     the full view; ``rows_per_step`` rows per program.
 
+A fifth kernel, ``fallback_stage_a``, runs the gather fallback's ladder
+and full reduce alone, for lanes with trailing axes: the XLA gather and
+combine give its term, and each block passes through VMEM once, lanes
+on the sublanes (``fallback_rows``).
+
 VMEM budget: the resident form holds the launch's gathered views whole
 while their footprint under Mosaic's (8, 128) tiling is at most
 ``RESIDENT_VIEW_BYTES`` (32 MiB: x of 8e6 float32 rows at N = 128), plus
@@ -84,6 +89,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.seed import reduce_identity_for
 from repro.kernels import common
 
 
@@ -733,6 +739,191 @@ def coalesced_stage_a(starts: jnp.ndarray, gathered_views: dict,
     return _chunked(call, bc, chunk,
                     [jnp.asarray(starts, jnp.int32),
                      jnp.asarray(full_flags, jnp.int32)], [1, 1])
+
+
+# ------------------------------------------- fallback lanes (trailing D)
+# The fallback kernel moves each step's term and lanes in and out
+# double-buffered: its blocks a step are the most, in whole groups of 8,
+# whose four buffers fit FALLBACK_STEP_BYTES.
+FALLBACK_STEP_BYTES = 32 << 20
+FALLBACK_KERNEL = "fallback_lanes"
+
+
+def fallback_rows(term_shape: tuple, dtype, interpret: bool | None = None,
+                  platform: str | None = None) -> int | None:
+    """Blocks a grid step of :func:`fallback_stage_a` for a fallback
+    term of ``term_shape`` ``(Bc, N, ...)``, or None where the launch
+    keeps the XLA ladder: lanes without trailing axes (``D = 1``, whose
+    gathered array XLA may keep in VMEM beside the reduce), a lane width
+    off the 8-row sublane tile, and the Triton lowering.  Decided from
+    the term's shape alone."""
+    interpret = common.resolve_interpret(interpret)
+    platform = platform or jax.default_backend()
+    bc, n = term_shape[:2]
+    if (not _side(term_shape) or n % 8
+            or (platform == "gpu" and not interpret)):
+        return None
+    block = _vmem_bytes((n, _side(term_shape)), dtype)
+    per_block = 4 * block + 2 * _vmem_bytes((8, n), jnp.int32) // 8
+    rows = max(8, FALLBACK_STEP_BYTES // per_block // 8 * 8)
+    return bc if bc <= rows else rows
+
+
+def _ladder_bits(seg: jnp.ndarray, op: int) -> jnp.ndarray:
+    """``(rows, N)`` segment ids -> ``(rows, N)`` int32 whose bit ``k`` at
+    lane ``j`` says that lane ``j + 2^k`` is in lane ``j``'s segment: the
+    masks of the ``op`` ladder steps, packed (the shifted ids are the
+    ladder's, :data:`common.SEG_PAD` past the end)."""
+    bits = jnp.zeros(seg.shape, jnp.int32)
+    for k in range(op):
+        same = seg == common.shift_lanes(seg, 1 << k, common.SEG_PAD)
+        bits = bits | (same.astype(jnp.int32) << k)
+    return bits
+
+
+def _shift_rows(t: jnp.ndarray, d: int) -> jnp.ndarray:
+    """``out[j] = t[j + d]`` for ``j < N - d``; the last ``d`` rows wrap
+    round (a sublane rotation) and no result reads them."""
+    return pltpu.roll(t, t.shape[0] - d, 0)
+
+
+def _rows_ladder(t: jnp.ndarray, bits: jnp.ndarray, op: int, fn):
+    """The ladder of one ``(N, D)`` block, lanes on the rows: step ``k``
+    combines row ``j`` with row ``j + 2^k`` where bit ``k`` of
+    ``bits[j]`` (an ``(N, 1)`` column) is set — ``segmented_reduce``'s
+    step, combine for combine.  A wrapped row is never selected: the
+    ladder's mask is false for the last ``2^k`` rows."""
+    for k in range(op):
+        t = jnp.where(((bits >> k) & 1) != 0, fn(t, _shift_rows(t, 1 << k)),
+                      t)
+    return t
+
+
+def _rows_total(t: jnp.ndarray, fn, identity) -> jnp.ndarray:
+    """``(N, D)`` -> ``(1, D)``, ``N`` a multiple of 8: the full reduction
+    of a block by ``engine._halving_tree``'s pairwise order, combine for
+    combine.  A rotate-and-combine butterfly inside each 8-row tile (its
+    first row then holds the tile's tree; no wrapped row reaches it),
+    then halving over the tiles, whose pairs are the tree's next levels
+    (an odd count takes the identity, as the tree's padding does)."""
+    for d in (1, 2, 4):
+        t = fn(t, _shift_rows(t, d))
+    tiles = [t[r:r + 8] for r in range(0, t.shape[0], 8)]
+    while len(tiles) > 1:
+        if len(tiles) % 2:
+            tiles.append(jnp.full(tiles[0].shape, identity, t.dtype))
+        tiles = [fn(a, b) for a, b in zip(tiles[0::2], tiles[1::2])]
+    return tiles[0][:1]
+
+
+def _fallback_body(base_ref, flag_ref, seg_ref, term_ref, out_ref, *,
+                   op: int, mixed: bool, reduce: str, rows: int,
+                   n_blocks: int):
+    """A step of ``rows`` blocks, 8 at a time: their ladder masks from one
+    ``(8, N)`` tile of segment ids, turned to ``(N, 8)`` columns, then per
+    block either the full reduce (``op`` is FULL_REDUCE, or the block's
+    flag is set) or the ladder, on the ``(N, D)`` block in VMEM with the
+    lanes on the sublanes.  Rows past the launch's last block run
+    nothing."""
+    del base_ref                 # consumed by the flags' chunking only
+    fn, _, _ = common.REDUCE_FNS[reduce]
+    identity = reduce_identity_for(reduce, term_ref.dtype)
+    step = pl.program_id(0)
+    valid = jnp.minimum(rows, n_blocks - step * rows)
+    group = min(rows, 8)
+
+    def run_group(q, carry):
+        if rows % group:
+            base = jnp.minimum(q * group, rows - group)
+        else:
+            base = pl.multiple_of(q * group, group)
+        cols = None
+        if op != common.FULL_REDUCE:
+            cols = _ladder_bits(seg_ref[pl.ds(base, group)], op).T
+
+        for g in range(group):
+            i = base + g
+
+            def full(i=i):
+                t = term_ref[i]
+                out_ref[i] = t
+                out_ref[i, 0:1] = _rows_total(t, fn, identity)
+
+            def ladder(i=i, g=g):
+                out_ref[i] = _rows_ladder(term_ref[i], cols[:, g:g + 1],
+                                          op, fn)
+
+            def block(i=i):
+                if op == common.FULL_REDUCE:
+                    full()
+                elif not mixed:
+                    ladder()
+                else:
+                    flag = flag_ref[step * rows + i] != 0
+                    pl.when(flag)(full)
+                    pl.when(jnp.logical_not(flag))(ladder)
+
+            pl.when(i < valid)(block)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(valid, group), run_group, 0)
+
+
+def fallback_stage_a(term: jnp.ndarray, seg: jnp.ndarray, *, op: int,
+                     reduce: str, rows: int,
+                     full_flags: jnp.ndarray | None = None,
+                     interpret: bool | None = None) -> jnp.ndarray:
+    """The gather fallback's reduce for lanes with trailing axes, in one
+    pass: ``(Bc, N, ...)`` term (the XLA gather and combine's, rounded)
+    -> the same lanes :func:`repro.core.engine.segmented_reduce` gives,
+    with ``full_flags`` (``(Bc,)``, or None) selecting the full reduce
+    per block, bit for bit.
+
+    Each block moves through VMEM once, read and written, ``rows`` blocks
+    a grid step (:func:`fallback_rows`).  The block keeps its HBM layout,
+    lanes on the sublanes and the value columns on the lanes, so the
+    ladder's shifts are sublane rotations and no lane is transposed; only
+    the ``(8, N)`` segment ids of each 8 blocks turn to columns.  The full
+    reduce is ``engine._halving_tree``'s order, on the chip as in
+    interpret mode.  The flags are scalar-prefetched in chunks of whole
+    steps that fit SMEM, as the per-tile window form's are.  A last step
+    past the end of the launch runs its valid blocks; Pallas clips its
+    reads and writes."""
+    interpret = common.resolve_interpret(interpret)
+    bc, n = seg.shape
+    shape = term.shape
+    lanes = term.reshape(bc, n, _side(shape))
+    mixed = full_flags is not None
+    if full_flags is None:
+        full_flags = jnp.zeros((bc,), jnp.int32)
+    d = lanes.shape[2]
+
+    def call(n_blocks, base, flags):
+        body = functools.partial(_fallback_body, op=op, mixed=mixed,
+                                 reduce=reduce, rows=rows,
+                                 n_blocks=n_blocks)
+        at = lambda b, base, f: (base[0] // rows + b, 0)  # noqa: E731
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(n_blocks, rows),),
+            in_specs=[pl.BlockSpec((rows, n), at),
+                      pl.BlockSpec((rows, n, d), lambda *a: at(*a) + (0,))],
+            out_specs=pl.BlockSpec((rows, n, d),
+                                   lambda b, base, f: (b, 0, 0)),
+        )
+        step = 4 * rows * _vmem_bytes((n, d), term.dtype)
+        return pl.pallas_call(
+            body, grid_spec=grid_spec, name=FALLBACK_KERNEL,
+            out_shape=jax.ShapeDtypeStruct((n_blocks, n, d), term.dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=step + (8 << 20)),
+            interpret=interpret,
+        )(base, flags, seg, lanes)
+
+    chunk = _chunk_blocks(bc, 1, rows)
+    out = _chunked(call, bc, chunk, [jnp.asarray(full_flags, jnp.int32)],
+                   [1])
+    return out.reshape(shape)
 
 
 # --------------------------------------------------------- GPU (Triton)

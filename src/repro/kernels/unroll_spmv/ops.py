@@ -35,6 +35,14 @@ says its gathered views and a step fit VMEM and SMEM, else the per-tile
 form; tracing ``stage_a`` makes that choice once per launch and sets the
 ``engine.nnz.window_resident`` gauge from it: the valid nonzeros of the
 window launches that run the resident form.
+
+A fallback launch whose lanes have trailing axes (SpMM, ``D > 1``) keeps
+the XLA gather and combine and runs its ladder, full reduce and select in
+``kernel.fallback_stage_a``, one pass through VMEM
+(``kernel.fallback_rows``); ``D = 1`` lanes keep the XLA ladder, whose
+gathered array XLA may place in VMEM.  Tracing ``stage_a`` sets the
+``engine.nnz.fallback_vmem`` gauge: the valid nonzeros of the fallback
+launches that run the kernel.
 """
 from __future__ import annotations
 
@@ -46,6 +54,8 @@ from repro.core.plan import BlockPlan
 from repro.kernels import common
 from repro.kernels.unroll_spmv.kernel import (class_stage_a,
                                               coalesced_stage_a,
+                                              fallback_rows,
+                                              fallback_stage_a,
                                               resident_stage_a,
                                               resident_steps)
 from repro.obs import metrics as _metrics
@@ -118,13 +128,20 @@ def make_stage_a(plan: BlockPlan, elem_exec,
                      steps, live):
         elem_blocks = cm["elem"]
         if launch.gather == ir.FALLBACK and seed.gather_index is not None:
-            # native gather path (XLA) + in-XLA segmented reduce
+            # native gather path (XLA); the segmented reduce in XLA for
+            # D = 1 lanes, else in one pass of the fallback kernel
             vals = {g: jnp.asarray(mutable[g])[cm["gidx"]]
                     for g in seed.gathered}
             rank = max((v.ndim for v in vals.values()), default=2)
             for e in seed.elementwise:
                 vals[e] = eng._expand_trailing(elem_blocks[e], rank)
             term = eng.combine_rounded(seed, vals, cm["zero"])
+            if steps is not None:
+                # lanes with trailing axes: the ladder and the full
+                # reduce in one pass through VMEM
+                return fallback_stage_a(
+                    term, cm["seg"], op=launch.op_flag, reduce=seed.reduce,
+                    rows=steps, full_flags=cm["full"], interpret=interpret)
             red = eng.segmented_reduce(term, cm["seg"], launch.op_flag,
                                        seed.reduce)
             if cm["full"] is not None:
@@ -163,7 +180,7 @@ def make_stage_a(plan: BlockPlan, elem_exec,
         out_dtype, out_trailing = eng.term_struct(seed, mutable,
                                                   elem_dtypes)
         parts = []
-        resident = 0
+        resident = vmem_fallback = 0
         for i, (launch, cm, n_valid) in enumerate(zip(launches, consts,
                                                        valid)):
             if blocks is not None and i not in blocks:
@@ -171,7 +188,8 @@ def make_stage_a(plan: BlockPlan, elem_exec,
             bc = launch.stop - launch.start if blocks is None \
                 else blocks[i].shape[0]
             # a window launch's form follows the bytes of its views and
-            # step, known once the views are
+            # step, known once the views are; a fallback launch's, the
+            # shape of its lanes
             steps = None
             if launch.gather in (ir.WINDOW, ir.STREAM):
                 steps = resident_steps(
@@ -181,6 +199,12 @@ def make_stage_a(plan: BlockPlan, elem_exec,
                     out_dtype=out_dtype, out_trailing=out_trailing,
                     interpret=interpret)
                 resident += n_valid if steps is not None else 0
+            elif (launch.gather == ir.FALLBACK
+                  and seed.gather_index is not None):
+                steps = fallback_rows(
+                    (bc, plan.lane_width) + out_trailing, out_dtype,
+                    interpret=interpret)
+                vmem_fallback += n_valid if steps is not None else 0
             # each launch's ops run under its kind's device scope
             with eng.launch_scope(launch):
                 if blocks is not None:
@@ -189,6 +213,7 @@ def make_stage_a(plan: BlockPlan, elem_exec,
                     launch, cm, views, mutable, out_dtype, out_trailing,
                     steps, None if counts is None else counts[i]))
         _metrics.set_gauge("engine.nnz.window_resident", resident)
+        _metrics.set_gauge("engine.nnz.fallback_vmem", vmem_fallback)
         if not parts:      # empty plan (nnz == 0): no launches, no lanes
             return jnp.zeros((0, plan.lane_width) + out_trailing, out_dtype)
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)

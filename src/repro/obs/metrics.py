@@ -9,8 +9,10 @@ the ones the caches and degradation paths never had:
   device staging); gauges ``engine.nnz.{window,coalesced,fallback}`` —
   the valid nonzeros of the last built executor's launches of each kind —
   and ``engine.nnz.window_resident``, those of its window launches that
-  run with the gathered views resident in VMEM (set when the Pallas
-  program is traced)
+  run with the gathered views resident in VMEM, and
+  ``engine.nnz.fallback_vmem``, those of its fallback launches whose
+  ladder runs in one pass through VMEM (lanes with trailing axes; both
+  set when the Pallas program is traced)
 * ``plan_cache.{hit,miss,corrupt,write_failed,store}`` — planio rungs
 * ``tune_cache.{hit,miss,corrupt,write_failed,store}`` — tuner cache
 * ``tune.measurements`` / ``tune.candidate_us`` — measured rounds and

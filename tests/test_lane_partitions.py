@@ -17,7 +17,7 @@ sys.path.insert(0, str(ROOT))
 
 from bench.generators import gcn_kronecker  # noqa: E402
 from repro.core import ir  # noqa: E402
-from repro.core.plan import build_plan  # noqa: E402
+from repro.core.plan import CostModel, build_plan  # noqa: E402
 from repro.core.seed import spmv_seed  # noqa: E402
 from repro.core.spmm import SpMM  # noqa: E402
 from repro.kernels.unroll_spmv import kernel  # noqa: E402
@@ -51,9 +51,9 @@ def _h(graph, d):
         (graph.shape[1], d)).astype(np.float32)
 
 
-def _product(graph, h, backend, reduce="add"):
+def _product(graph, h, backend, reduce="add", cost=None):
     app = SpMM.from_coo(graph.rows, graph.cols, graph.vals, graph.shape,
-                        backend=backend, reduce=reduce)
+                        backend=backend, reduce=reduce, cost=cost)
     return np.asarray(app.matmat(jnp.asarray(h)))
 
 
@@ -71,18 +71,37 @@ def test_spmm_agrees_with_a_float64_product(graph, backend, d):
     assert err <= SPMV_ERR / 10, err
 
 
-@pytest.mark.parametrize("backend,d,reduce", [
-    ("jax", 8, "add"), ("jax", 8, "min"), ("jax", 256, "add"),
-    ("pallas", 8, "add"), ("pallas", 256, "add"), ("pallas", 8, "max")])
+@pytest.mark.parametrize("backend,d,reduce,cut", [
+    pytest.param(*case, None, id="-".join(map(str, case))) for case in [
+        ("jax", 8, "add"), ("jax", 8, "min"), ("jax", 256, "add"),
+        ("pallas", 8, "add"), ("pallas", 256, "add"), ("pallas", 8, "max")]
+] + [pytest.param("pallas", 256, "add", 2, id="pallas-256-add-fallback")])
 def test_row_partitions_are_bitwise_the_one_piece_product(
-        graph, plan, backend, d, reduce, monkeypatch):
+        graph, plan, backend, d, reduce, cut, monkeypatch):
+    """``cut`` sends the blocks over that many windows to the gather
+    fallback, whose launch is then padded in some partitions (none fall
+    back at this scale otherwise): its lanes come from the fallback
+    kernel, pads and all."""
     h = _h(graph, d)
-    whole = _product(graph, h, backend, reduce)
+    cost = None if cut is None else CostModel(max_windows_replace=cut)
+    whole = _product(graph, h, backend, reduce, cost)
     assert metrics.gauge_value("engine.lane_partitions") == 1
     monkeypatch.setattr(ir, "LANE_STREAM_BYTES", _budget(plan, d))
-    parts = _product(graph, h, backend, reduce)
+    parts = _product(graph, h, backend, reduce, cost)
     assert metrics.gauge_value("engine.lane_partitions") > 2
     assert np.array_equal(whole.view(np.int32), parts.view(np.int32))
+    if cut is not None:
+        fallback = metrics.gauge_value("engine.nnz.fallback")
+        assert metrics.gauge_value("engine.nnz.fallback_vmem") == fallback
+        cut_plan = build_plan(spmv_seed(), {"row": graph.rows,
+                                            "col": graph.cols},
+                              graph.shape[0], graph.shape[1], cost=cost)
+        tree = ir.lower(cut_plan, backend="pallas")
+        lanes = ir.RowOrder(tree).partitions(cut_plan.lane_width * d * 4)
+        l = [i for i, launch in enumerate(tree.launches)
+             if launch.gather == ir.FALLBACK][0]
+        real = lanes.block_hi[:, l] - lanes.block_lo[:, l]
+        assert fallback > 0 and np.any(real < lanes.block_max[l])
 
 
 @pytest.mark.parametrize("d", [8, 256])

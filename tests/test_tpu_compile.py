@@ -14,11 +14,18 @@ compiles the resident form, held whole in VMEM; a view over it (2^17
 windows, 64 MB), or a step whose window ids pass the SMEM budget (an odd
 ``ls`` over 63), compiles the per-tile form.
 
+The gather fallback's kernel for lanes with trailing axes compiles at
+D = 256 over a row partition's 4029 blocks, alone and inside a
+partitioned GCN executor.
+
 The topology is described only inside a module fixture (never at import):
 one process at a time may load the TPU library, and every xdist worker
 imports this file.
 """
+import json
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,16 +33,19 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import engine as eng
-from repro.core import ir
-from repro.core.plan import CostModel, build_plan
-from repro.core.seed import spmv_seed
-from repro.kernels.unroll_spmv.kernel import (class_stage_a,
-                                              coalesced_stage_a,
-                                              resident_stage_a,
-                                              resident_steps)
-from repro.obs import metrics
-from repro.sparse import generators as G
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+from bench.generators import gcn_kronecker  # noqa: E402
+from repro.core import engine as eng  # noqa: E402
+from repro.core import ir  # noqa: E402
+from repro.core.plan import CostModel, build_plan  # noqa: E402
+from repro.core.seed import spmv_seed  # noqa: E402
+from repro.kernels.unroll_spmv.kernel import (  # noqa: E402
+    FALLBACK_KERNEL, class_stage_a, coalesced_stage_a, fallback_rows,
+    fallback_stage_a, resident_stage_a, resident_steps)
+from repro.obs import metrics  # noqa: E402
+from repro.sparse import generators as G  # noqa: E402
 
 N = 128
 BLOCKS = 1 << 19
@@ -216,6 +226,37 @@ def test_dense_slice_kernel_compiles_for_v5e(one_chip, reduce, strided,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# a row partition's fallback launch in the scale-21 GCN cell: padded to
+# its largest partition's 4029 blocks (3 * 17 * 79, so the last step is
+# ragged at any step size in groups of 8)
+FALLBACK_BLOCKS = 4029
+
+
+@pytest.mark.parametrize("reduce,op,mixed", [("add", 7, True),
+                                             ("min", 7, True),
+                                             ("add", -1, False)])
+def test_fallback_kernel_compiles_for_v5e(one_chip, reduce, op, mixed):
+    """The fallback's ladder, full reduce and select on ``(N, D)`` blocks
+    in VMEM, lanes on the sublanes, within its VMEM limit."""
+    dt = jnp.float32 if reduce == "add" else jnp.int32
+    rows = fallback_rows((FALLBACK_BLOCKS, N, D_WIDE), dt, interpret=False,
+                         platform="tpu")
+    assert FALLBACK_BLOCKS % rows
+
+    def stage_a(term, seg, flags):
+        return fallback_stage_a(term, seg, op=op, reduce=reduce, rows=rows,
+                                full_flags=flags if mixed else None,
+                                interpret=False)
+
+    i32 = jnp.int32
+    compiled = jax.jit(stage_a).lower(
+        _sds((FALLBACK_BLOCKS, N, D_WIDE), dt, one_chip),
+        _sds((FALLBACK_BLOCKS, N), i32, one_chip),
+        _sds((FALLBACK_BLOCKS,), i32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert FALLBACK_KERNEL in compiled.as_text()
+
+
 @pytest.fixture(scope="module")
 def powerlaw_plan():
     m = G.power_law(1 << 15, 16)
@@ -285,6 +326,42 @@ def test_partitioned_spmm_executor_skips_pads_per_tile_for_v5e(
     assert metrics.gauge_value("engine.nnz.window") > 0
     assert metrics.gauge_value("engine.nnz.window_resident") == 0
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kronecker_plan(scale):
+    config = json.loads(
+        (ROOT / "bench" / "configs" / "kron-s21-gcn256.json").read_text())
+    g = gcn_kronecker.make(dict(config, scale=scale))
+    return g, build_plan(spmv_seed(), {"row": g.rows, "col": g.cols},
+                         g.shape[0], g.shape[1])
+
+
+def test_partitioned_gcn_executor_runs_the_fallback_kernel_for_v5e(
+        one_chip, monkeypatch):
+    """The GCN product at D = 256 on a scale-13 Kronecker graph, whose
+    skewed rows fall back as at scale 21, in row partitions: the
+    fallback's lanes come from the fallback kernel, and the D = 1
+    product on the same plan holds no such kernel."""
+    g, plan = _kronecker_plan(13)
+    monkeypatch.setattr(ir, "LANE_STREAM_BYTES", 64 << 20)
+    texts = {}
+    for trailing in ((), (D_WIDE,)):
+        run = eng.make_executor(plan, {"value": g.vals}, backend="pallas",
+                                interpret=False)
+        consts = jax.tree.map(
+            lambda a: _sds(a.shape, a.dtype, one_chip),
+            run.sweep_body.consts)
+        texts[trailing] = run.jitted.lower(
+            consts,
+            {"x": _sds((g.shape[1],) + trailing, jnp.float32, one_chip)},
+            _sds((g.shape[0],) + trailing, jnp.float32,
+                 one_chip)).compile().as_text()
+    assert metrics.gauge_value("engine.lane_partitions") > 1
+    fallback = metrics.gauge_value("engine.nnz.fallback")
+    assert metrics.gauge_value("engine.nnz.fallback_vmem") == fallback > 0
+    assert FALLBACK_KERNEL in texts[(D_WIDE,)]
+    assert FALLBACK_KERNEL not in texts[()]
+    assert "tpu_custom_call" in texts[()]
 
 
 def test_plan_arrays_are_operands_not_constants(powerlaw_plan):
