@@ -417,16 +417,3 @@ def bench_plan_build(nnz: int = 1_000_000, out_len: int = 100_000,
         })
     return rows
 
-
-# -------------------------------------------------- MoE dispatch (beyond)
-def bench_moe_dispatch() -> list[tuple]:
-    from repro.models.moe import dispatch_pattern_stats
-    rng = np.random.default_rng(0)
-    out = []
-    for t, e, k in [(4096, 8, 2), (8192, 64, 8), (16384, 128, 8)]:
-        eidx = rng.integers(0, e, size=(t, k)).astype(np.int32)
-        st = dispatch_pattern_stats(eidx, lane_width=128)
-        ls1 = st["ls_hist"].get(1, 0.0) + st["ls_hist"].get(2, 0.0)
-        out.append((f"moe_dispatch_T{t}_E{e}_k{k}",
-                    st["mean_windows"], ls1))
-    return out

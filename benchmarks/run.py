@@ -1,10 +1,10 @@
 """Benchmark harness entry point — one function per paper table.
 
 Prints ``name,us_per_call,derived`` CSV rows (derived = speedup vs the
-baseline where applicable), then the roofline table if dry-run artifacts
-exist.  ``--json PATH`` additionally writes the machine-readable perf
-trajectory (backend x dataset x fused/per-class ``us_per_call`` plus
-plan-build seconds) — the file checked in as ``BENCH_spmv.json``.
+baseline where applicable).  ``--json PATH`` additionally writes the
+machine-readable perf trajectory (backend x dataset x fused/per-class
+``us_per_call`` plus plan-build seconds) — the file checked in as
+``BENCH_spmv.json``.
 
 ``python -m benchmarks.run [--scale full] [--pallas] [--tuned]
 [--tune-cache DIR] [--json out.json]``
@@ -270,22 +270,9 @@ def main() -> None:
               f"build={r['build_s']}s;seed_style={r['seed_style_build_s']}s;"
               f"cache_warm={warm if warm is not None else 'n/a'}s")
 
-    # ---- beyond-paper: MoE dispatch pattern opportunity
-    for name, mean_w, ls12 in T.bench_moe_dispatch():
-        print(f"{name},0,mean_windows={mean_w:.2f};frac_ls<=2={ls12:.2f}")
-
     if args.json:
         _write_json(args.json, "bench_spmv.v1", args.scale,
                     exec_rows + build_rows + pallas_rows)
-
-    # ---- roofline table from dry-run artifacts (if present)
-    try:
-        from benchmarks import roofline
-        rows = roofline.load_all()
-        if rows:
-            print(f"roofline_cells,{len(rows)},see EXPERIMENTS.md")
-    except Exception as e:  # pragma: no cover
-        print(f"roofline_skipped,0,{e}", file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -27,6 +27,8 @@ import numpy as np
 from repro.core import engine as eng
 from repro.core import validate as validation
 from repro.core.graphs import check_auto_kwargs
+from repro.core.mesh import (make_shard_mesh, resolve_shard_mesh,
+                             row_sharding)
 from repro.core.plan import BlockPlan, CostModel, build_plan
 from repro.core.seed import pagerank_seed, spmv_seed
 from repro.obs import trace as _trace
@@ -133,7 +135,6 @@ class SpMV:
                 from repro.tune import autotune
                 shard_counts = None
                 if shards is not None:
-                    from repro.launch.mesh import make_shard_mesh
                     make_shard_mesh(int(shards))   # validate, with recipe
                     shard_counts = tuple(sorted({1, int(shards)}))
                 dt = vals.dtype if np.issubdtype(vals.dtype, np.inexact) \
@@ -153,7 +154,6 @@ class SpMV:
                           mesh=getattr(run, "mesh", None),
                           _shard_parts=tuple(getattr(run, "parts", ())))
             else:
-                from repro.launch.mesh import resolve_shard_mesh
                 mesh, num_shards = resolve_shard_mesh(mesh, shards)
                 cost = cost or CostModel(lane_width=lane_width)
                 plan = _plan(seed, access, shape[0], shape[1], cost,
@@ -331,7 +331,6 @@ class PageRank:
                     tune_cache_dir=tune_cache_dir,
                     plan_cache_dir=plan_cache_dir)
             else:
-                from repro.launch.mesh import resolve_shard_mesh
                 mesh, num_shards = resolve_shard_mesh(mesh, shards)
                 cost = cost or CostModel(lane_width=lane_width)
                 plan = _plan(seed, access, num_nodes, num_nodes, cost,
@@ -402,7 +401,6 @@ class PageRank:
         :func:`engine.tree_sum` over the SAME reassembled full vector on
         every device (identical combine order to :meth:`_step`), never a
         psum of per-shard partial sums."""
-        from repro.launch.sharding import row_sharding
         parts = self._shard_parts
         widths, s = eng.shard_widths(parts)
         n = self.num_nodes

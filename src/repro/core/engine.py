@@ -68,6 +68,7 @@ import numpy as np
 
 from repro.core import feature_table as ft
 from repro.core import ir
+from repro.core.mesh import dp_axes
 from repro.core.plan import BlockPlan
 from repro.core.seed import (CodeSeed, reduce_identity_for,
                              reference_execute)
@@ -917,13 +918,12 @@ from jax.sharding import PartitionSpec as _PS
 
 def _shard_axis(mesh) -> str:
     """The mesh axis shard rows ride on — the data-parallel axis."""
-    from repro.launch.mesh import dp_axes
     dp = dp_axes(mesh)
     if len(dp) != 1:
         raise ValueError(
             f"sharded execution needs exactly one data axis in the mesh "
             f"(axes {mesh.axis_names}, data axes {dp}); build one with "
-            "repro.launch.mesh.make_shard_mesh(shards)")
+            "repro.core.mesh.make_shard_mesh(shards)")
     if int(np.prod([mesh.shape[a] for a in mesh.axis_names
                     if a not in dp])) != 1:
         raise ValueError(

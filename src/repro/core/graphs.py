@@ -45,6 +45,7 @@ import numpy as np
 from repro.core import engine as eng
 from repro.core import ir
 from repro.core import validate as validation
+from repro.core.mesh import resolve_shard_mesh, row_sharding
 from repro.core.plan import BlockPlan, CostModel, build_plan
 from repro.core.seed import CodeSeed
 from repro.obs import metrics as _metrics
@@ -368,7 +369,6 @@ class _FixpointApp:
         flags match exactly."""
         fn = self._resident.get("shard")
         if fn is None:
-            from repro.launch.sharding import row_sharding
             step = eng.make_sharded_fixpoint_step(
                 self._shard_parts, self._static, self.mesh, self._state_key)
             widths, s = step.widths, step.padded_width
@@ -632,7 +632,6 @@ class BFS(_FixpointApp):
                 app = cls(plan=plan, num_nodes=num_nodes, _run=run,
                           _state_key="level", tuning=tuning, driver=driver)
             else:
-                from repro.launch.mesh import resolve_shard_mesh
                 mesh, num_shards = resolve_shard_mesh(mesh, shards)
                 cost = cost or CostModel(lane_width=lane_width)
                 plan = _build(seed, access, num_nodes, num_nodes, cost,
@@ -753,7 +752,6 @@ class SSSP(_FixpointApp):
                 app = cls(plan=plan, num_nodes=num_nodes, _run=run,
                           _state_key="dist", tuning=tuning, driver=driver)
             else:
-                from repro.launch.mesh import resolve_shard_mesh
                 mesh, num_shards = resolve_shard_mesh(mesh, shards)
                 cost = cost or CostModel(lane_width=lane_width)
                 plan = _build(seed, access, num_nodes, num_nodes, cost,
@@ -853,7 +851,6 @@ class ConnectedComponents(_FixpointApp):
                 app = cls(plan=plan, num_nodes=num_nodes, _run=run,
                           _state_key="label", tuning=tuning, driver=driver)
             else:
-                from repro.launch.mesh import resolve_shard_mesh
                 mesh, num_shards = resolve_shard_mesh(mesh, shards)
                 cost = cost or CostModel(lane_width=lane_width)
                 plan = _build(seed, access, num_nodes, num_nodes, cost,

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.core import engine as eng
 from repro.core import validate as validation
+from repro.core.mesh import make_shard_mesh, resolve_shard_mesh
 from repro.core.plan import BlockPlan, CostModel
 from repro.core.seed import spmv_seed
 from repro.obs import trace as _trace
@@ -106,7 +107,6 @@ class SpMM:
                 from repro.tune import autotune, candidate_space
                 shard_counts = (1,)
                 if shards is not None:
-                    from repro.launch.mesh import make_shard_mesh
                     make_shard_mesh(int(shards))   # validate, with recipe
                     shard_counts = tuple(sorted({1, int(shards)}))
                 space = candidate_space(
@@ -128,7 +128,6 @@ class SpMM:
                           tuning=result, mesh=getattr(run, "mesh", None),
                           _shard_parts=tuple(getattr(run, "parts", ())))
             else:
-                from repro.launch.mesh import resolve_shard_mesh
                 mesh, num_shards = resolve_shard_mesh(mesh, shards)
                 cost = cost or CostModel(lane_width=lane_width)
                 plan = planio.cached_build_plan(seed, access,

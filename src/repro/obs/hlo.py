@@ -24,7 +24,6 @@ Pure text processing — no jax dependency.
 from __future__ import annotations
 
 import dataclasses
-import json
 import re
 
 _BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1,
@@ -331,8 +330,3 @@ def analyze_hlo(hlo: str) -> dict:
     return {"flops": fl, "memory_bytes": mb,
             "collectives": {**co, "total_bytes": co_total}}
 
-
-if __name__ == "__main__":
-    import sys
-    with open(sys.argv[1]) as f:
-        print(json.dumps(analyze_hlo(f.read()), indent=1))

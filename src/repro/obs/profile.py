@@ -10,11 +10,9 @@ Two complementary cost views, assembled into one :class:`RunReport`:
   accounting applied to the tree that actually executes, so fused /
   coalesced lowering decisions show up as byte-count deltas per leaf.
 * **Whole-program HLO totals** (:func:`hlo_cost`): the live executor's
-  optimized HLO run through :func:`repro.launch.hlo_analysis.analyze_hlo`
-  — the same static analyzer the dry-run roofline path uses, now wired
-  into the live pipeline.  ``None`` when the executor cannot be lowered
-  to HLO text (interpret mode, exotic runtimes); the analytic table
-  never depends on it.
+  optimized HLO run through :func:`repro.obs.hlo.analyze_hlo`.  ``None``
+  when the executor cannot be lowered to HLO text (interpret mode, exotic
+  runtimes); the analytic table never depends on it.
 
 ``build_report(app, ...)`` collects plan stats, pass provenance +
 per-pass launch deltas, tuning choice and ``picked_by``, validation and
@@ -25,6 +23,8 @@ from __future__ import annotations
 
 import dataclasses
 import json
+
+from repro.obs.hlo import analyze_hlo
 
 __all__ = ["RunReport", "launch_cost_table", "hlo_cost", "build_report"]
 
@@ -124,10 +124,9 @@ def launch_cost_table(tree) -> list[dict]:
 
 def hlo_cost(run, mutable: dict, out_init) -> dict | None:
     """Optimized-HLO FLOPs/bytes/collectives of the live executor via
-    :func:`repro.launch.hlo_analysis.analyze_hlo`.  ``None`` when the
+    :func:`repro.obs.hlo.analyze_hlo`.  ``None`` when the
     executor cannot produce HLO text — never raises."""
     import jax
-    from repro.launch.hlo_analysis import analyze_hlo
     lower = getattr(run, "lower", None) or jax.jit(run).lower
     try:
         hlo = lower(mutable, out_init).compile().as_text()

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import engine as eng
+from repro.core.mesh import make_shard_mesh
 from repro.core.seed import CodeSeed, reference_execute
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
@@ -107,7 +108,6 @@ def _default_exec_factory(plan, cand: Candidate, static_data, elem_exec):
         # seed the shard plans (each shard re-reorders the full static
         # arrays through its own sliced flat_perm)
         from repro.core import ir
-        from repro.launch.mesh import make_shard_mesh
         tree = ir.lower(plan, backend=cand.backend, fused=cand.fused,
                         stage_b=cand.stage_b, coalesce=cand.coalesce)
         parts = ir.partition_plan(tree, cand.shards)

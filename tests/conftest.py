@@ -13,11 +13,23 @@ guard), so this conftest degrades gracefully:
   to stderr and the process exits non-zero (coarser than pytest-timeout,
   which fails just the one test, but the diagnostic is the same).
 
-When pytest-timeout IS installed, this file does nothing.
+When pytest-timeout IS installed, the hang guard here does nothing.
+
+Before anything imports JAX, this file also asks XLA for 8 host (CPU)
+devices, so the sharded tests (``-m shard``, DESIGN.md §10) run in every
+tier-1 run instead of skipping.  Flags already in ``XLA_FLAGS`` are kept,
+and a device count already set there wins.  The flag acts only on the
+host platform; a run on an accelerator sees its own devices.
 """
 from __future__ import annotations
 
 import faulthandler
+import os
+
+_DEVICE_COUNT = "--xla_force_host_platform_device_count"
+if _DEVICE_COUNT not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"), f"{_DEVICE_COUNT}=8")))
 
 try:
     import pytest_timeout  # noqa: F401

@@ -27,7 +27,6 @@ import errno
 import glob
 import json
 import os
-import time
 
 import jax
 import numpy as np
@@ -35,8 +34,6 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.core.apps import PageRank, SpMV
-from repro.core.plan import build_plan
-from repro.core.seed import spmv_seed
 from repro.obs import metrics, trace
 from repro.obs.log import _parse_spec, get_logger
 from repro.testing import faults
@@ -448,34 +445,24 @@ def test_degradations_log_to_hierarchy(caplog):
 
 # ------------------------------------------------------ pinned overhead
 def test_disabled_tracing_overhead_under_one_percent():
-    """The no-op span machinery must cost <1% of a 1M-nnz plan build.
+    """Disabled tracing is free: with tracing off and no profiler
+    session, every span() call hands back the one shared no-op object
+    (no allocation, no clock read, no lock) and nothing is recorded.
 
-    An instrumented build makes O(10) span() calls and a few metric
-    increments; we time 10_000 disabled span entries (a 100x margin
-    over what a build issues) and require even THAT total to stay under
-    1% of the measured build time — a generous, machine-independent
-    pin of 'disabled is free'."""
+    An instrumented build makes O(10) span() calls; 10_000 here is a
+    100x margin over what a build issues.  The claim is checked by what
+    the disabled path does, not by a wall-clock ratio, which a loaded
+    machine cannot hold to."""
     assert not trace.enabled()
-    seed = spmv_seed()
-    nnz, out_len = 1_000_000, 100_000
-    rng = np.random.default_rng(0)
-    access = {"row": rng.integers(0, out_len, nnz),
-              "col": rng.integers(0, out_len, nnz)}
-    t0 = time.perf_counter()
-    plan = build_plan(seed, access, out_len, out_len)
-    build_s = time.perf_counter() - t0
-    assert plan.nnz == nnz
-
-    n_calls = 10_000
-    t0 = time.perf_counter()
-    for _ in range(n_calls):
-        with trace.span("noop", a=1):
-            pass
-    nop_s = time.perf_counter() - t0
+    assert not trace.active()
+    for _ in range(10_000):
+        sp = trace.span("noop", a=1)
+        assert sp is trace._NOP
+        with sp as inner:
+            assert inner is trace._NOP
+            assert inner.set(b=2) is trace._NOP
     assert trace.finished_spans() == []
-    assert nop_s < 0.01 * build_s, (
-        f"{n_calls} disabled spans took {nop_s:.4f}s vs build "
-        f"{build_s:.3f}s — no-op path is not free")
+    assert trace.open_spans() == []
 
 
 # ------------------------------------------- bench provenance + drift

@@ -363,6 +363,16 @@ def _small_spmv(tmp_path=None, **kw):
     return A, np.asarray(A.matvec(jnp.asarray(x)))
 
 
+def _small_spmv_dense():
+    """Float64 dense product of :func:`_small_spmv`'s matrix and vector."""
+    rng = np.random.default_rng(0)
+    rows, cols, vals = _coo(rng, 48, 48, 256)
+    x = rng.standard_normal(48).astype(np.float32)
+    dense = np.zeros((48, 48))
+    np.add.at(dense, (rows, cols), vals.astype(np.float64))
+    return dense @ x.astype(np.float64)
+
+
 class TestDegradationTrail:
     def test_nested_empty_collector_pops_itself_only(self):
         # regression: sinks were removed by equality, so an inner
@@ -482,7 +492,12 @@ class TestCacheDegradation:
         entries[0].write_text("{not json")
         with pytest.warns(RuntimeWarning, match="unreadable"):
             B, y2 = _small_spmv(backend="auto", tune_cache_dir=str(cache))
-        assert np.array_equal(y1, y2)
+        # the re-tune may pick another candidate (timing decides), whose
+        # bits may differ; each is held to the exact product
+        np.testing.assert_allclose(y2, _small_spmv_dense(),
+                                   rtol=1e-5, atol=1e-5)
+        if B.tuning.best == A.tuning.best:
+            assert np.array_equal(y1, y2)
         assert not B.tuning.cache_hit            # re-tuned for real
         assert any(e.layer == "tune_cache" and e.kind == "corrupt_entry"
                    for e in B.degradations)

@@ -1,100 +1,21 @@
-"""Serving-layer tests.
-
-Part 1 — LM engine: decode-with-cache must reproduce the full-sequence
-forward, per family.
-Part 2 — concurrent query serving (``repro.serve.query``, DESIGN.md
-§12): batched-vs-sequential bitwise equality, deadline/shed/breaker
-behavior under injected faults and latency, multi-threaded client
-stress, and the health-report schema.
+"""Concurrent query serving (``repro.serve.query``, DESIGN.md §12):
+batched-vs-sequential bitwise equality, deadline/shed/breaker behavior
+under injected faults and latency, multi-threaded client stress, and the
+health-report schema.
 """
 import threading
 import time
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import get_config
-from repro.models import lm, params as pr
-from repro.serve import engine
-
-ARCHS = ["granite_3_2b", "gemma3_27b", "h2o_danube_3_4b",
-         "qwen3_moe_235b_a22b", "rwkv6_3b", "zamba2_1p2b",
-         "whisper_small", "paligemma_3b"]
-
-
-def _batch(cfg, key, b, s):
-    batch = {"tokens": jax.random.randint(key, (b, s), 0, cfg.vocab_size)}
-    if cfg.family == "vlm":
-        batch["prefix_embeds"] = jax.random.normal(
-            key, (b, cfg.num_prefix, cfg.d_model), jnp.float32)
-    if cfg.family == "encdec":
-        batch["enc_frames"] = jax.random.normal(
-            key, (b, cfg.enc_len, cfg.d_model), jnp.float32)
-    return batch
-
-
-@pytest.mark.parametrize("arch", ARCHS)
-def test_decode_matches_forward(arch):
-    cfg = get_config(arch).reduced()
-    if cfg.family == "moe":
-        # capacity dropping couples tokens across positions (inherent to
-        # dropped MoE); decode==forward holds only in the dropless regime
-        cfg = cfg.replace(capacity_factor=float(cfg.num_experts))
-    key = jax.random.PRNGKey(1)
-    vals, _ = pr.materialize_init(lm.init_model, key, cfg)
-    b, s_total = 2, 12
-    s_prompt = 8
-    batch = _batch(cfg, key, b, s_total)
-
-    # full forward logits at every position
-    full_logits, _ = lm.forward(vals, cfg, batch)
-    full_logits = np.asarray(full_logits, np.float32)
-
-    # prefill on the prompt prefix, then decode the remaining tokens
-    pbatch = dict(batch, tokens=batch["tokens"][:, :s_prompt])
-    prefix_len = cfg.num_prefix if cfg.family == "vlm" else 0
-    max_len = s_total + prefix_len + 4
-    cache, last_logits = engine.prefill(vals, cfg, pbatch, max_len)
-    np.testing.assert_allclose(
-        np.asarray(last_logits[:, -1], np.float32),
-        full_logits[:, s_prompt - 1], rtol=2e-2, atol=2e-3)
-
-    logits_steps = []
-    for i in range(s_prompt, s_total):
-        tok = batch["tokens"][:, i:i + 1]
-        step_logits, cache = lm.decode_step(
-            vals, cfg, cache, tok, jnp.int32(i + prefix_len),
-            prefix_len=prefix_len)
-        logits_steps.append(np.asarray(step_logits[:, 0], np.float32))
-
-    for j, lg in enumerate(logits_steps):
-        np.testing.assert_allclose(
-            lg, full_logits[:, s_prompt + j], rtol=2e-2, atol=2e-3,
-            err_msg=f"{arch} step {j}")
-
-
-def test_generate_runs():
-    cfg = get_config("granite_3_2b").reduced()
-    key = jax.random.PRNGKey(0)
-    vals, _ = pr.materialize_init(lm.init_model, key, cfg)
-    batch = _batch(cfg, key, 2, 8)
-    toks, cache = engine.generate(vals, cfg, batch, steps=5, max_len=16)
-    assert toks.shape == (2, 5)
-    assert (np.asarray(toks) >= 0).all()
-    assert (np.asarray(toks) < cfg.vocab_size).all()
-
-
-# =====================================================================
-# Part 2 — concurrent query serving (repro.serve.query, DESIGN.md §12)
-# =====================================================================
-from repro.core import graphs as GR          # noqa: E402
-from repro.core.apps import SpMV             # noqa: E402
-from repro.obs import metrics as M           # noqa: E402
-from repro.serve import query as Q           # noqa: E402
-from repro.sparse import generators as G     # noqa: E402
-from repro.testing import faults             # noqa: E402
+from repro.core import graphs as GR
+from repro.core.apps import SpMV
+from repro.obs import metrics as M
+from repro.serve import query as Q
+from repro.sparse import generators as G
+from repro.testing import faults
 
 
 @pytest.fixture(scope="module")
